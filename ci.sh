@@ -667,7 +667,8 @@ run_experiments() {
 
 run_kernels() {
     # Kernel-surface smoke: interpret-mode parity for both Pallas kernel
-    # families (FE fused value+grad/HVP, RE batched Newton system), and a
+    # families (FE fused value+grad/HVP, RE batched Newton system), every
+    # pallas_call through the real TPU compiler ahead of time, and a
     # dead-code gate — the round-4 FE A/B DELETED the losing lowerings, so
     # their per-call tile_n override must stay gone from the public
     # signatures (no quietly resurrected code paths in ops/pallas_glm.py).
@@ -694,7 +695,8 @@ EOF
         tests/test_re_kernel.py::test_fused_newton_system_bitexact_unbatched_and_vmapped \
         "tests/test_re_kernel.py::test_solve_block_pallas_bitexact_mixed_geometries[False]" \
         tests/test_re_kernel.py::test_solve_block_bf16x_pinned_tolerance \
-        tests/test_re_kernel.py::test_zero_post_warmup_retraces
+        tests/test_re_kernel.py::test_zero_post_warmup_retraces \
+        tests/test_tpu_aot_compile.py
     echo "   kernels smoke OK"
 }
 

@@ -42,6 +42,7 @@ import signal
 import threading
 from typing import Dict
 
+from photon_tpu.utils.compile_cache import configure_compile_cache
 from photon_tpu.cli.common import (
     parse_coordinate_config,
     parse_feature_shard_config,
@@ -337,7 +338,9 @@ def run(args) -> dict:
 
 
 def main(argv=None):
-    summary = run(build_parser().parse_args(argv))
+    args = build_parser().parse_args(argv)
+    configure_compile_cache()
+    summary = run(args)
     print(json.dumps(summary))
 
 

@@ -36,6 +36,7 @@ import logging
 import os
 from typing import Dict
 
+from photon_tpu.utils.compile_cache import configure_compile_cache
 from photon_tpu.cli.common import (
     parse_coordinate_config,
     parse_feature_shard_config,
@@ -202,7 +203,9 @@ def run(args) -> Dict:
 
 
 def main(argv=None):
-    summary = run(build_parser().parse_args(argv))
+    args = build_parser().parse_args(argv)
+    configure_compile_cache()
+    summary = run(args)
     print(json.dumps(summary))
 
 

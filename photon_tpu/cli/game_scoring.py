@@ -16,6 +16,7 @@ from typing import Dict
 import jax.numpy as jnp
 import numpy as np
 
+from photon_tpu.utils.compile_cache import configure_compile_cache
 from photon_tpu.cli.common import (
     add_common_args,
     parse_feature_shard_config,
@@ -263,6 +264,7 @@ def run(args) -> Dict:
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    configure_compile_cache()
     print(json.dumps(run(args)))
 
 

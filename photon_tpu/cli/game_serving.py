@@ -52,6 +52,7 @@ import threading
 from http.server import ThreadingHTTPServer
 from typing import Optional
 
+from photon_tpu.utils.compile_cache import configure_compile_cache
 from photon_tpu.cli.common import setup_logging
 from photon_tpu.serve.admission import AdmissionConfig, parse_tenant_rates
 from photon_tpu.serve.batcher import BackpressureError, DeadlineExceededError
@@ -837,7 +838,9 @@ def run(args):
 
 
 def main(argv=None):
-    run(build_parser().parse_args(argv))
+    args = build_parser().parse_args(argv)
+    configure_compile_cache()
+    run(args)
 
 
 if __name__ == "__main__":

@@ -38,6 +38,7 @@ import os
 import threading
 from typing import Dict
 
+from photon_tpu.utils.compile_cache import configure_compile_cache
 from photon_tpu.cli.common import (
     parse_coordinate_config,
     setup_logging,
@@ -360,7 +361,9 @@ def run(args) -> Dict:
 
 
 def main(argv=None):
-    summary = run(build_parser().parse_args(argv))
+    args = build_parser().parse_args(argv)
+    configure_compile_cache()
+    summary = run(args)
     print(json.dumps(summary))
 
 

@@ -26,6 +26,7 @@ from typing import Dict
 import jax.numpy as jnp
 import numpy as np
 
+from photon_tpu.utils.compile_cache import configure_compile_cache
 from photon_tpu.cli.common import (
     add_common_args,
     parse_coordinate_config,
@@ -617,6 +618,7 @@ def _run_hyperparameter_tuning(args, estimator, results, batch, valid_batch, sui
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    configure_compile_cache()
     summary = run(args)
     print(json.dumps(summary["best"]))
 

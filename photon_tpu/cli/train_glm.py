@@ -21,6 +21,7 @@ from typing import Dict, List, Optional
 import jax.numpy as jnp
 import numpy as np
 
+from photon_tpu.utils.compile_cache import configure_compile_cache
 from photon_tpu.cli.common import add_validation_arg, setup_logging, task_of
 from photon_tpu.data.batch import LabeledBatch
 from photon_tpu.data.index_map import IndexMap
@@ -678,6 +679,7 @@ def run(args) -> Dict:
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    configure_compile_cache()
     from photon_tpu.utils.shutdown import GracefulShutdown, handle_termination
 
     try:

@@ -53,7 +53,7 @@ class GameTransformer:
             self.last_metrics: Optional[Dict[str, float]] = metrics
         return scores
 
-    def warm_up(self, template: GameBatch, row_buckets) -> int:
+    def warm_up(self, template: GameBatch, row_buckets, sharding=None) -> int:
         """Compile the scorer for every row-count bucket an online caller
         will dispatch on, up front — the serving engine's startup step that
         turns "at most one trace per bucket" into "ZERO traces after
@@ -63,13 +63,19 @@ class GameTransformer:
         layout; each bucket size pads it with inert rows (weight 0, entity
         -1 — data/padding.py) and scores it to completion. Tracing is
         shape-driven, so the dummy values never matter. Returns the number
-        of fresh traces (== number of previously-unseen bucket shapes)."""
-        import jax
+        of fresh traces (== number of previously-unseen bucket shapes).
 
+        ``sharding`` places each padded batch exactly as the caller will
+        place live ones. The jit cache keys on placement as well as shape: a
+        batch committed to a mesh (the serving engine replicates requests
+        over the device-sharded store's mesh) re-traces a scorer that was
+        warmed on an uncommitted single-device template."""
         from photon_tpu.data.padding import pad_game_batch
 
         before = self.trace_count
         for n in sorted(set(int(b) for b in row_buckets)):
             padded = pad_game_batch(template, n, xp=jnp)
+            if sharding is not None:
+                padded = jax.device_put(padded, sharding)
             jax.block_until_ready(self._score(self.model, padded))
         return self.trace_count - before

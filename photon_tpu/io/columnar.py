@@ -75,6 +75,35 @@ _lib = None
 _lib_failed = False
 
 
+def build_native_lib(force: bool = False) -> Optional[str]:
+    """Compile the C++ block decoder (g++ -O2 -shared) from the tracked
+    source. Returns the .so path, or None when no toolchain is available or
+    the build fails. ``force`` rebuilds over an existing binary — the way to
+    be sure the library in use comes from the source beside it (a copied
+    tree keeps no mtimes to compare)."""
+    so = os.path.abspath(_lib_path())
+    src = os.path.join(os.path.dirname(so), "avro_decode.cpp")
+    if os.path.exists(so) and not force:
+        return so
+    try:
+        subprocess.run(
+            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", so, src],
+            check=True, capture_output=True,
+        )
+    except (subprocess.CalledProcessError, FileNotFoundError):
+        return None
+    return so
+
+
+def decoder_status() -> str:
+    """Which Avro block decoder this process has used so far: ``native``
+    (the C++ library loaded), ``python`` (it failed to build or load and
+    reads fell back to the row codec) or ``unused``."""
+    if _lib is not None:
+        return "native"
+    return "python" if _lib_failed else "unused"
+
+
 def _load_lib():
     global _lib, _lib_failed
     if _lib is not None or _lib_failed:
@@ -103,12 +132,7 @@ def _load_lib():
             stacklevel=2,
         )
     if not os.path.exists(so) or (src_newer and rebuild_enabled):
-        try:
-            subprocess.run(
-                ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", so, src],
-                check=True, capture_output=True,
-            )
-        except (subprocess.CalledProcessError, FileNotFoundError):
+        if build_native_lib(force=True) is None:
             _lib_failed = True
             return None
     try:

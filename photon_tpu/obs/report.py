@@ -151,6 +151,18 @@ def _publish_solve_cache(reg) -> None:
         reg.gauge("solve_cache_traces_by_key", key=k).set(n)
 
 
+def _publish_avro_decoder(reg) -> None:
+    """Which Avro decoder the run read its data with (1 = the native C++
+    block decoder, 0 = the pure-Python fallback). Absent when the run
+    decoded nothing — so a timing can never be taken for the native
+    decoder's while the fallback did the work."""
+    from photon_tpu.io.columnar import decoder_status
+
+    status = decoder_status()
+    if status != "unused":
+        reg.gauge("avro_decoder_native").set(1 if status == "native" else 0)
+
+
 def _publish_tracker(reg, label: str, tracker: Dict[str, list]) -> None:
     """Optimizer outcomes → registry (iters histogram + convergence-reason
     counters), read from the finalize-time diagnostics."""
@@ -206,6 +218,7 @@ def collect_run_records(
 
     reg = registry()
     _publish_solve_cache(reg)
+    _publish_avro_decoder(reg)
 
     records: List[Dict[str, Any]] = [
         dict(

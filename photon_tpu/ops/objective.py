@@ -110,14 +110,7 @@ class GLMObjective:
     def _can_fuse(self, batch: LabeledBatch) -> bool:
         if not self.use_pallas:
             return False
-        from photon_tpu.ops.pallas_glm import MAX_FUSED_DIM, pallas_usable
-
-        # TPU-availability gate: when the pallas surface failed to import,
-        # fall back to the XLA two-pass path instead of dying at dispatch.
-        # (Off-TPU with a working import, the kernels run in interpreter
-        # mode — slow, but exactly what the CPU smoke tests exercise.)
-        if not pallas_usable():
-            return False
+        from photon_tpu.ops.pallas_glm import MAX_FUSED_DIM
 
         feats = batch.features
         if isinstance(feats, SparseFeatures) or feats.shape[1] > MAX_FUSED_DIM:

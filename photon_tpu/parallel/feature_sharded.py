@@ -42,12 +42,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# shard_map graduated from jax.experimental to the jax namespace; accept
-# whichever this build ships.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:  # pragma: no cover - depends on jax version
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from photon_tpu.data.batch import LabeledBatch, SparseFeatures
 from photon_tpu.ops.objective import GLMObjective
 from photon_tpu.optim.common import OptimizeResult, OptimizerConfig
@@ -176,7 +170,7 @@ def sparse_value_and_grad_feature_sharded(
         P(dp),                    # weight
     )
     factor_spec = (P(FEATURE_AXIS),) if factors is not None else ()
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         (lambda w, i, v, y, o, wt, f: local_fn(w, i, v, y, o, wt, f))
         if factors is not None
         else (lambda w, i, v, y, o, wt: local_fn(w, i, v, y, o, wt, None)),
@@ -246,7 +240,7 @@ def sparse_linearized_hvp_feature_sharded(
 
     row_specs = (P(dp, None), P(dp, None))  # indices, values
     factor_spec = (P(FEATURE_AXIS),) if factors is not None else ()
-    d2_shmapped = _shard_map(
+    d2_shmapped = jax.shard_map(
         (lambda w, i, v, y, o, wt, f: local_d2(w, i, v, y, o, wt, f))
         if factors is not None
         else (lambda w, i, v, y, o, wt: local_d2(w, i, v, y, o, wt, None)),
@@ -254,7 +248,7 @@ def sparse_linearized_hvp_feature_sharded(
         in_specs=(P(FEATURE_AXIS),) + row_specs + (P(dp), P(dp), P(dp)) + factor_spec,
         out_specs=P(dp),
     )
-    hv_shmapped = _shard_map(
+    hv_shmapped = jax.shard_map(
         (lambda v, i, vl, d2, f: local_hv(v, i, vl, d2, f))
         if factors is not None
         else (lambda v, i, vl, d2: local_hv(v, i, vl, d2, None)),

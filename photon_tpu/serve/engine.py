@@ -330,7 +330,10 @@ class ServingEngine:
             store.warm_uploads(self.max_batch)
             transformer = GameTransformer(store.scoring_model())
             template = self._template_batch(store)
-            traces = transformer.warm_up(template, bucket_grid(self.max_batch))
+            traces = transformer.warm_up(
+                template, bucket_grid(self.max_batch),
+                sharding=store.batch_sharding,
+            )
             registry().gauge("serve_warmup_traces").set(traces)
             return _State(store, transformer, version, transformer.trace_count)
 

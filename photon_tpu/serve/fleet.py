@@ -80,6 +80,7 @@ from photon_tpu.serve.frontend import (
 from photon_tpu.serve.routing import HashRing, route_key
 from photon_tpu.serve.store import StorePartition
 from photon_tpu.utils import faults
+from photon_tpu.utils.compile_cache import configure_compile_cache
 
 logger = logging.getLogger("photon_tpu")
 
@@ -261,6 +262,7 @@ def _replica_argparser() -> argparse.ArgumentParser:
 
 def replica_main(argv: Optional[Sequence[str]] = None) -> int:
     args = _replica_argparser().parse_args(argv)
+    configure_compile_cache()
     logging.basicConfig(
         level=logging.DEBUG if args.verbose else logging.INFO,
         format=f"%(asctime)s {args.replica_id} %(levelname)s %(message)s",
@@ -1070,6 +1072,26 @@ class ScorerFleet:
     def log_path(self, replica_id: str) -> str:
         return os.path.join(self.workdir, f"scorer-{replica_id}.log")
 
+    def _replica_environ(self, replica_id: str) -> Dict[str, str]:
+        """A replica inherits the caller's environment, its JAX platform
+        included — nothing here names a platform. On a chip host a chip
+        belongs to one process, so each replica is given its own through
+        ``replica_env`` (README, fleet runbook)."""
+        env = dict(os.environ)
+        if self.transport == "tcp" and self.secret:
+            env[FLEET_SECRET_ENV] = self.secret
+        # The replica must import photon_tpu no matter the caller's cwd:
+        # put the package's parent dir on its path explicitly.
+        import photon_tpu
+
+        pkg_root = os.path.dirname(os.path.dirname(photon_tpu.__file__))
+        parts = [pkg_root] + [
+            p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p
+        ]
+        env["PYTHONPATH"] = os.pathsep.join(dict.fromkeys(parts))
+        env.update(self.replica_env.get(replica_id, {}))
+        return env
+
     def _spawn(self, replica_id: str, ring_snapshot: dict) -> subprocess.Popen:
         cmd = [
             sys.executable, "-m", "photon_tpu.serve.fleet",
@@ -1091,20 +1113,7 @@ class ScorerFleet:
             cmd += ["--spool-dir", self.spool_base]
         if not self.compact_host:
             cmd += ["--no-compact-host"]
-        env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
-        if self.transport == "tcp" and self.secret:
-            env[FLEET_SECRET_ENV] = self.secret
-        # The replica must import photon_tpu no matter the caller's cwd:
-        # put the package's parent dir on its path explicitly.
-        import photon_tpu
-
-        pkg_root = os.path.dirname(os.path.dirname(photon_tpu.__file__))
-        parts = [pkg_root] + [
-            p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p
-        ]
-        env["PYTHONPATH"] = os.pathsep.join(dict.fromkeys(parts))
-        env.update(self.replica_env.get(replica_id, {}))
+        env = self._replica_environ(replica_id)
         log = open(self.log_path(replica_id), "ab")
         old_log = self._logs.pop(replica_id, None)
         if old_log is not None:

@@ -34,8 +34,8 @@ def _problem(n, d, seed=0, poisson=False):
     "loss,poisson", [(LogisticLoss, False), (PoissonLoss, True), (SquaredLoss, False)]
 )
 def test_fused_matches_autodiff(loss, poisson, monkeypatch):
-    n, d = 37, 13  # deliberately not tile/lane aligned
-    monkeypatch.setattr(pallas_glm, "DEFAULT_TILE_N", 8)  # multi-tile grid
+    n, d = 293, 13  # deliberately not tile/lane aligned
+    monkeypatch.setattr(pallas_glm, "DEFAULT_TILE_N", 128)  # multi-tile grid
     X, y, weight, offset, w = _problem(n, d, poisson=poisson)
     val, grad = fused_data_value_and_grad(
         loss, jnp.asarray(w), jnp.asarray(X), jnp.asarray(y),
@@ -48,14 +48,15 @@ def test_fused_matches_autodiff(loss, poisson, monkeypatch):
     np.testing.assert_allclose(np.asarray(grad), np.asarray(grad_ref), rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("tile_n", [8, 64, 4096])
+@pytest.mark.parametrize("tile_n", [8, 128, 4096])
 def test_fused_tile_height_invariance(tile_n, monkeypatch):
-    """Identical results at any tile height, including tile_n > n (the
-    n-cap clamps it) and the big default (grid-step amortization). The
-    height is a module constant since the round-4 A/B deleted the per-call
-    override — geometry varies via monkeypatch only."""
+    """Identical results at any tile height, including a request below one
+    lane row (clamped up to 128), tile_n > n (the n-cap clamps it) and the
+    big default (grid-step amortization). The height is a module constant
+    since the round-4 A/B deleted the per-call override — geometry varies
+    via monkeypatch only."""
     monkeypatch.setattr(pallas_glm, "DEFAULT_TILE_N", tile_n)
-    n, d = 200, 24
+    n, d = 300, 24
     X, y, weight, offset, w = _problem(n, d, seed=7)
     val, grad = fused_data_value_and_grad(
         LogisticLoss, jnp.asarray(w), jnp.asarray(X), jnp.asarray(y),
@@ -72,33 +73,55 @@ def test_fused_tile_height_invariance(tile_n, monkeypatch):
 
 def test_tile_geometry(monkeypatch):
     """The tall default must never cost real padding: tile height clamps
-    to the data, rebalances across the grid, and respects the VMEM cap."""
-    from photon_tpu.ops.pallas_glm import DEFAULT_TILE_N, _tile_geometry
+    to the data, prefers an exact divisor of n, otherwise rebalances across
+    the grid, and keeps EVERY block the call holds inside the VMEM budget
+    at the width VMEM gives it."""
+    from photon_tpu.ops.pallas_glm import (
+        _VMEM_BUDGET,
+        _VMEM_FIXED,
+        COL_VEC_BYTES,
+        DEFAULT_TILE_N,
+        ROW_VEC_BYTES,
+        _tile_geometry,
+        x_row_bytes,
+    )
 
     assert DEFAULT_TILE_N >= 4096  # the default really is tall
+    # A (tile_n, 1) column costs a full 128-lane row per sample — the
+    # accounting error that made the v5e compiler refuse d=256 (PR 21).
+    assert COL_VEC_BYTES == 128 * ROW_VEC_BYTES
 
-    # Small batch: one sublane-padded tile, NOT one 8192-row tile.
-    t, npad = _tile_geometry(100, 128, jnp.float32, DEFAULT_TILE_N)
-    assert t == 104 and npad == 104
+    def row_bytes(d_pad, dtype, n_vec=4):
+        return x_row_bytes(d_pad, dtype) + n_vec * ROW_VEC_BYTES
 
-    # n just past a tile multiple: rebalanced, padding ≤ sublane per tile
-    # (the un-rebalanced geometry would pad 8200 → 16384).
-    t, npad = _tile_geometry(8200, 128, jnp.float32, DEFAULT_TILE_N)
+    # Small batch: one lane-padded tile, NOT one 8192-row tile.
+    t, npad = _tile_geometry(100, DEFAULT_TILE_N, row_bytes(128, jnp.float32))
+    assert t == 128 and npad == 128
+
+    # n just past a tile multiple: rebalanced, padding < one lane row per
+    # tile (the un-rebalanced geometry would pad 8200 → 16384).
+    t, npad = _tile_geometry(8200, DEFAULT_TILE_N, row_bytes(128, jnp.float32))
     n_tiles = npad // t
-    assert npad - 8200 <= n_tiles * 8, (t, npad)
+    assert npad - 8200 < n_tiles * 128, (t, npad)
     assert npad < 8200 + 2 * 8192 - 8192, npad
 
-    # VMEM cap binds at wide d: tile*d_pad*itemsize stays within budget.
-    for dtype, sublane in [(jnp.float32, 8), (jnp.bfloat16, 16)]:
+    # The budget binds at wide d and counts the per-sample vectors too; a
+    # power-of-two n is tiled exactly (no padded copy of X in HBM).
+    for dtype in (jnp.float32, jnp.bfloat16):
         for d_pad in [128, 256, 2048, 4096]:
-            t, npad = _tile_geometry(1 << 21, d_pad, dtype, DEFAULT_TILE_N)
-            assert t * d_pad * jnp.dtype(dtype).itemsize <= 4 * 1024 * 1024
-            assert t % sublane == 0 and npad % t == 0
-            assert npad - (1 << 21) <= (npad // t) * sublane
+            rb = row_bytes(d_pad, dtype)
+            t, npad = _tile_geometry(1 << 21, DEFAULT_TILE_N, rb)
+            assert t * rb + _VMEM_FIXED <= _VMEM_BUDGET
+            assert t % 128 == 0 and npad == 1 << 21
+
+    # Column blocks (the RE Newton kernel) shorten the tile accordingly.
+    rb = x_row_bytes(128, jnp.float32) + 2 * COL_VEC_BYTES
+    t, npad = _tile_geometry(1 << 16, 1 << 16, rb, align=8)
+    assert t * rb + _VMEM_FIXED <= _VMEM_BUDGET and t % 8 == 0
 
     # Numerical parity at a rebalanced odd size spanning several tiles.
     monkeypatch.setattr(pallas_glm, "DEFAULT_TILE_N", 512)
-    n, d = 1030, 8
+    n, d = 1030, 8  # three 384-row tiles
     X, y, weight, offset, w = _problem(n, d, seed=11)
     val, grad = fused_data_value_and_grad(
         LogisticLoss, jnp.asarray(w), jnp.asarray(X), jnp.asarray(y),
@@ -188,7 +211,7 @@ def test_fused_return_margins():
     np.testing.assert_allclose(np.asarray(grad), np.asarray(grad2), rtol=1e-6)
 
 
-@pytest.mark.parametrize("tile_n", [8, 64, 4096])
+@pytest.mark.parametrize("tile_n", [8, 128, 4096])
 def test_fused_hvp_matches_dense_hessian(tile_n, monkeypatch):
     """fused_data_hvp == Xᵀ·diag(d2)·X·v at any tile height, non-aligned
     shapes included."""
@@ -196,7 +219,7 @@ def test_fused_hvp_matches_dense_hessian(tile_n, monkeypatch):
 
     monkeypatch.setattr(pallas_glm, "DEFAULT_TILE_N", tile_n)
     rng = np.random.default_rng(13)
-    n, d = 211, 19
+    n, d = 311, 19
     X = rng.normal(size=(n, d)).astype(np.float32)
     v = rng.normal(size=d).astype(np.float32)
     d2 = rng.uniform(0.05, 1.0, size=n).astype(np.float32)
@@ -217,20 +240,15 @@ def test_losing_lowerings_deleted():
         assert "tile_n" not in inspect.signature(fn).parameters
 
 
-def test_tpu_availability_gate_cpu_smoke(monkeypatch):
-    """Satellite: the pallas surface is gated on availability, not assumed.
-    On this CPU host the import succeeds (usable → interpret-mode smoke
-    below), full-speed availability is False, and a simulated import
-    failure downgrades ``use_pallas`` objectives to the XLA two-pass path
-    instead of dying at dispatch."""
-    from photon_tpu.ops import pallas_glm
-
-    assert pallas_glm.pallas_usable()  # import worked in this jax build
+def test_interpret_mode_is_cpu_only_and_explicit():
+    """Pallas is part of the installed JAX: no import gate, no silent return
+    to XLA. Off-TPU ``pallas_available()`` is False (production call sites
+    such as re_kernel="auto" then choose XLA) and the kernels interpret
+    only because ``interpret`` resolves from the backend."""
     assert not pallas_glm.pallas_available()  # no TPU backend here
-    pallas_glm._require_pallas()  # usable → no raise
+    assert not hasattr(pallas_glm, "pallas_usable")
+    assert not hasattr(pallas_glm, "_require_pallas")
 
-    # Interpret-mode smoke: the fused kernel EXECUTES on CPU and matches
-    # the autodiff objective (the contract pallas_usable promises).
     n, d = 32, 6
     X, y, weight, offset, w = _problem(n, d, seed=23)
     batch = LabeledBatch(
@@ -246,19 +264,8 @@ def test_tpu_availability_gate_cpu_smoke(monkeypatch):
     np.testing.assert_allclose(
         np.asarray(grad), np.asarray(grad_ref), rtol=1e-4, atol=1e-5
     )
-
-    # Simulated import failure: _can_fuse gates off, value_and_grad falls
-    # back (and stays correct); the explicit kernel entry points raise a
-    # descriptive error instead of an AttributeError on a None module.
-    monkeypatch.setattr(
-        pallas_glm, "_PALLAS_IMPORT_ERROR", ImportError("no pallas")
-    )
-    obj_p = GLMObjective(loss=LogisticLoss, use_pallas=True)
-    assert not obj_p._can_fuse(batch)
-    v, g = obj_p.value_and_grad(jnp.asarray(w), batch)
-    np.testing.assert_allclose(float(v), float(val_ref), rtol=1e-6)
-    with pytest.raises(RuntimeError, match="pallas is unavailable"):
-        pallas_glm._require_pallas()
+    # A use_pallas objective on a fusible batch always takes the kernel.
+    assert GLMObjective(loss=LogisticLoss, use_pallas=True)._can_fuse(batch)
 
 
 def test_linearized_hvp_fused_route_matches_fallback():

@@ -1,0 +1,106 @@
+"""Every ``pallas_call``, compiled ahead of time for a TPU v5e — on the CPU.
+
+``jax.experimental.topologies.get_topology_desc`` describes a v5e:2x2 host
+with no chip attached, and lowering against a ``ShapeDtypeStruct`` placed
+on one of its devices runs the real TPU compiler, Mosaic included, under
+``JAX_PLATFORMS=cpu``. That turns "the compiler accepts the kernel at the
+full-width shapes" — VMEM budget, HBM capacity, layouts — into a tier-1
+check that costs seconds and no chip time. It is also the loop to fix a
+kernel in: edit, compile here, and only then spend a chip run.
+
+What this cannot show is that the compiled kernel computes the right thing
+on the hardware; ``chip_smoke.py`` (phase kernels) does that on the chip.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from photon_tpu.ops.losses import LogisticLoss
+from photon_tpu.ops.pallas_glm import (
+    MAX_FUSED_DIM,
+    fused_data_hvp,
+    fused_data_value_and_grad,
+)
+from photon_tpu.ops.pallas_newton import fused_newton_system
+
+# bench.py's headline shapes: N = 2^21 rows, d = 256; E = 4096 entities of
+# 512 rows, d_re = 16.
+N, D_FIX = 1 << 21, 256
+E, N_MAX, D_RE = 4096, 512, 16
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Sharding on one device of a described (not attached) v5e host.
+
+    Loading libtpu normally takes its multi-process lock
+    (/tmp/libtpu_lockfile) for the life of the process, which on a machine
+    WITH a chip would shut every other process out of the TPU while this
+    pytest process lives (seen on the v5e, PR 21). A compile-only load
+    opens no device, so it is told not to take the lock."""
+    with pytest.MonkeyPatch.context() as env:
+        env.setenv("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+        try:
+            import libtpu  # noqa: F401
+            from jax.experimental import topologies
+
+            topo = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        except Exception as exc:  # noqa: BLE001 — any failure: no compiler
+            pytest.skip(
+                f"no ahead-of-time TPU compiler in this install: {exc!r}"
+            )
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, sharding, *shapes):
+    """Lower for the described device, check Mosaic is in the program (not
+    the interpreter), and run the TPU compiler on it."""
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+        for shape, dtype in shapes
+    ]
+    lowered = jax.jit(fn).lower(*args)
+    assert "tpu_custom_call" in lowered.as_text()
+    return lowered.compile()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("n,d", [(N, D_FIX), (1 << 17, MAX_FUSED_DIM)])
+def test_fixed_effect_kernels_compile_for_v5e(v5e, n, d, dtype):
+    f32 = jnp.float32
+    vec, w = ((n,), f32), ((d,), f32)
+    for return_margins in (False, True):
+        _compile(
+            lambda w_, X, y, off, wt: fused_data_value_and_grad(
+                LogisticLoss, w_, X, y, off, wt, interpret=False,
+                return_margins=return_margins,
+            ),
+            v5e, w, ((n, d), dtype), vec, vec, vec,
+        )
+    _compile(
+        lambda v, X, d2: fused_data_hvp(v, X, d2, interpret=False),
+        v5e, w, ((n, d), dtype), vec,
+    )
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize(
+    "e,n_max,d_re",
+    [
+        (E, N_MAX, D_RE),    # the headline entity block
+        (64, 1 << 14, D_RE),  # wide n_max: the d2/dz columns bound the tile
+        (32, 1 << 13, 64),
+    ],
+)
+def test_random_effect_kernel_compiles_for_v5e(v5e, e, n_max, d_re, dtype):
+    f32 = jnp.float32
+    _compile(
+        jax.vmap(lambda X, d2, dz: fused_newton_system(
+            X, d2, dz, interpret=False)),
+        v5e, ((e, n_max, d_re), dtype), ((e, n_max), f32), ((e, n_max), f32),
+    )
