@@ -277,10 +277,21 @@ class CoordinateDescent:
                 if begin_pass is not None:
                     begin_pass(it)
                 t0 = time.monotonic()
-                # Residual: all OTHER coordinates' scores
-                # (summedScores − thisCoordinateScores, reference :441-446).
-                residual = None if single else total_scores - scores[cid]
+                # One coordinate update = exchange + solve + score. Under
+                # ``profile`` the score span ends on the fence, so the
+                # device time inside this span's interval is this
+                # coordinate's, whatever programs the solve launches. The
+                # closing exchange is dispatched after the fence: its two
+                # elementwise launches may run inside the NEXT update's
+                # interval.
                 with _export_trace(), span(f"cd/iter{it}/{cid}"):
+                    with span("exchange"):
+                        # Residual: all OTHER coordinates' scores
+                        # (summedScores − thisCoordinateScores, reference
+                        # :441-446).
+                        residual = (
+                            None if single else total_scores - scores[cid]
+                        )
                     with span("solve"):
                         model, diag = coord.train(batch, residual, models[cid])
                     with span("score"):
@@ -289,8 +300,9 @@ class CoordinateDescent:
                             # The clock must cover device execution, not
                             # dispatch.
                             jax.block_until_ready(new_scores)
+                    with span("exchange"):
+                        total_scores = total_scores - scores[cid] + new_scores
                 wall = time.monotonic() - t0
-                total_scores = total_scores - scores[cid] + new_scores
                 scores[cid] = new_scores
                 models[cid] = model
                 tracker[cid].append(diag)

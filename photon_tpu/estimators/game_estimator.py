@@ -43,6 +43,7 @@ from photon_tpu.models.game import (
     ProjectedRandomEffectModel,
     RandomEffectModel,
 )
+from photon_tpu.obs.trace import span
 from photon_tpu.ops.losses import loss_for_task
 from photon_tpu.ops.objective import GLMObjective
 from photon_tpu.ops.variance import normalize_variance_type
@@ -247,54 +248,74 @@ class GameEstimator:
         self._re_datasets = {}
         from photon_tpu.data.batch import SparseFeatures
 
-        # Sparse (wide) shards pass through as host triples — the builder
-        # compacts each block to its active-column subspace instead of
-        # densifying the full shard width.
-        feats_np = {
-            k: (
-                (np.asarray(v.indices), np.asarray(v.values), v.dim)
-                if isinstance(v, SparseFeatures)
-                else np.asarray(v)
-            )
-            for k, v in batch.features.items()
-        }
-        label_np = np.asarray(batch.label)
-        weight_np = np.asarray(batch.weight)
-        for cfg in self.coordinate_configs:
-            if isinstance(cfg, RandomEffectCoordinateConfig):
-                eids = np.asarray(batch.entity_ids[cfg.re_type])
-                E = self.num_entities.get(cfg.re_type, int(eids.max()) + 1 if eids.size else 0)
-                existing = None
-                if self.ignore_threshold_for_new_models:
-                    # Entities with an existing model in the warm-start
-                    # GameModel; ids outside it bypass the bound. Presence
-                    # comes from the loader's record-membership mask when
-                    # available (L1-zeroed models still count as existing,
-                    # matching the reference's key-presence semantics);
-                    # nonzero rows are the fallback for in-memory models.
-                    existing = np.zeros((E,), bool)
-                    prev_model = self.warm_start_model.get(cfg.coordinate_id)
-                    if prev_model is not None:
-                        existing_src = _existing_entity_mask(prev_model)
-                        k = min(E, existing_src.shape[0])
-                        existing[:k] = existing_src[:k]
-                self._re_datasets[cfg.coordinate_id] = build_random_effect_dataset(
-                    eids,
-                    feats_np[cfg.feature_shard],
-                    label_np,
-                    weight_np,
-                    E,
-                    RandomEffectDataConfig(
-                        re_type=cfg.re_type,
-                        feature_shard=cfg.feature_shard,
-                        active_upper_bound=cfg.active_upper_bound,
-                        active_lower_bound=cfg.active_lower_bound,
-                        features_to_samples_ratio=cfg.features_to_samples_ratio,
-                    ),
-                    uid=None if batch.uid is None else np.asarray(batch.uid),
-                    existing_model_mask=existing,
-                )
+        with span("prepare"):
+            with span("host_copy"):
+                # Sparse (wide) shards pass through as host triples — the
+                # builder compacts each block to its active-column subspace
+                # instead of densifying the full shard width.
+                feats_np = {
+                    k: (
+                        (np.asarray(v.indices), np.asarray(v.values), v.dim)
+                        if isinstance(v, SparseFeatures)
+                        else np.asarray(v)
+                    )
+                    for k, v in batch.features.items()
+                }
+                label_np = np.asarray(batch.label)
+                weight_np = np.asarray(batch.weight)
+                uid_np = None if batch.uid is None else np.asarray(batch.uid)
+                eids_np = {
+                    cfg.re_type: np.asarray(batch.entity_ids[cfg.re_type])
+                    for cfg in self.coordinate_configs
+                    if isinstance(cfg, RandomEffectCoordinateConfig)
+                }
+            with span("group"):
+                for cfg in self.coordinate_configs:
+                    if isinstance(cfg, RandomEffectCoordinateConfig):
+                        self._re_datasets[cfg.coordinate_id] = (
+                            self._group_entities(
+                                cfg, eids_np[cfg.re_type],
+                                feats_np[cfg.feature_shard],
+                                label_np, weight_np, uid_np,
+                            )
+                        )
         self._prepared_for = batch
+
+    def _group_entities(self, cfg, eids, feats, label_np, weight_np, uid_np):
+        """One random-effect coordinate's entity blocks from host arrays."""
+        E = self.num_entities.get(
+            cfg.re_type, int(eids.max()) + 1 if eids.size else 0
+        )
+        existing = None
+        if self.ignore_threshold_for_new_models:
+            # Entities with an existing model in the warm-start GameModel;
+            # ids outside it bypass the bound. Presence comes from the
+            # loader's record-membership mask when available (L1-zeroed
+            # models still count as existing, matching the reference's
+            # key-presence semantics); nonzero rows are the fallback for
+            # in-memory models.
+            existing = np.zeros((E,), bool)
+            prev_model = self.warm_start_model.get(cfg.coordinate_id)
+            if prev_model is not None:
+                existing_src = _existing_entity_mask(prev_model)
+                k = min(E, existing_src.shape[0])
+                existing[:k] = existing_src[:k]
+        return build_random_effect_dataset(
+            eids,
+            feats,
+            label_np,
+            weight_np,
+            E,
+            RandomEffectDataConfig(
+                re_type=cfg.re_type,
+                feature_shard=cfg.feature_shard,
+                active_upper_bound=cfg.active_upper_bound,
+                active_lower_bound=cfg.active_lower_bound,
+                features_to_samples_ratio=cfg.features_to_samples_ratio,
+            ),
+            uid=uid_np,
+            existing_model_mask=existing,
+        )
 
     # --- fit ---
 

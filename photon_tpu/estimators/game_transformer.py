@@ -35,7 +35,8 @@ class GameTransformer:
 
         def _score(model, batch):
             self.trace_count += 1
-            return model.score_with_offset(batch)
+            with jax.named_scope("compute_score"):
+                return model.score_with_offset(batch)
 
         self._score = jax.jit(_score)
 

@@ -4,7 +4,8 @@ Three pieces, one artifact:
 
 - :mod:`photon_tpu.obs.trace` — hierarchical host-wall spans
   (``span("cd/iter3/per-user/solve")``), thread-safe, nestable across the
-  ingest pipeline's stage threads.
+  ingest pipeline's stage threads; under a ``jax.profiler`` session each
+  also shows as ``photon/<path>`` on the device trace's clock.
 - :mod:`photon_tpu.obs.metrics` — process-global counters / gauges /
   histograms with labels; the solve cache, pipeline stages, replay cache,
   shape bucketing, and optimizers all publish here.

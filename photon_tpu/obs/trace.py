@@ -12,6 +12,16 @@ jitted dispatch under ``CoordinateDescent.run(profile=False)`` therefore
 times enqueue cost, never device execution, and introduces zero
 ``block_until_ready`` host syncs (tests/test_solve_cache.py pins this).
 
+The same spans reach the profiler: while a span is open, a
+``jax.profiler.TraceAnnotation("photon/<path>")`` is open beside it, so a
+``jax.profiler`` session shows the program's spans on the device trace's
+clock (a coordinate update that ends on its fence owns the device time
+inside its interval). The profiler's session is the only switch — outside
+one the annotation is a flag test — and the class is looked up in
+``sys.modules``, never imported: a process that has not imported jax (the
+pre-fork HTTP workers of serve/frontend.py) pays a dict lookup a span.
+``Tracer.record`` (externally timed spans) stays host-only.
+
 Nesting is thread-local by default: a span opened inside another span on
 the same thread becomes its child (path ``parent/child``). Work handed to
 another thread — the ingest pipeline's stage threads — passes the parent
@@ -40,6 +50,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import re
+import sys
 import threading
 import time
 from collections import OrderedDict, deque
@@ -189,6 +200,115 @@ class SpanRecord:
         )
 
 
+def _record(name, parent, start_s, duration_s, thread, trace_id, span_id,
+            parent_span_id, pid) -> SpanRecord:
+    """A SpanRecord without the frozen dataclass's nine ``object.
+    __setattr__`` calls (a third of what a span cost): the same fields,
+    written to the instance's dict."""
+    rec = object.__new__(SpanRecord)
+    rec.__dict__.update(
+        name=name, parent=parent, start_s=start_s, duration_s=duration_s,
+        thread=thread, trace_id=trace_id, span_id=span_id,
+        parent_span_id=parent_span_id, pid=pid,
+    )
+    return rec
+
+
+_PROFILER = None  # jax.profiler, once some other module has imported it
+
+
+def _annotation(path: str):
+    """``jax.profiler.TraceAnnotation("photon/<path>")``, or None until jax
+    has been imported by whoever needs it. Looked up in ``sys.modules`` and
+    never imported here; the miss is not cached, so the spans of a process
+    that imports jax later start reaching the profiler then."""
+    global _PROFILER
+    prof = _PROFILER
+    if prof is None:
+        prof = sys.modules.get("jax.profiler")
+        if getattr(prof, "TraceAnnotation", None) is None:
+            return None  # absent, or jax is still importing
+        _PROFILER = prof
+    return prof.TraceAnnotation("photon/" + path)
+
+
+class _Span:
+    """The context manager ``Tracer.span`` returns: one SpanRecord on exit
+    and, for the life of the span, the profiler annotation of the same
+    path. A plain class, not a generator, and the thread's two stacks are
+    fetched once: seven of these a served micro-batch sit on the one thread
+    every score passes through."""
+
+    __slots__ = ("_tracer", "_name", "_parent", "_context", "_path", "_base",
+                 "_tentry", "_psid", "_t0", "_annotation", "_stack", "_tstack")
+
+    def __init__(self, tracer, name, parent, context):
+        self._tracer = tracer
+        self._name = name
+        self._parent = parent
+        self._context = context
+
+    def __enter__(self) -> str:
+        tr = self._tracer
+        local = tr._local
+        stack = getattr(local, "stack", None)
+        tstack = getattr(local, "tstack", None)
+        if stack is None or tstack is None:  # this thread's first span
+            stack, tstack = tr._stack(), tr._tstack()
+        self._stack, self._tstack = stack, tstack
+        base = self._parent
+        if base is None and stack:
+            base = stack[-1]
+        self._base = base
+        path = self._path = f"{base}{SEP}{self._name}" if base else self._name
+        # The effective context: the explicit one, else the innermost open
+        # traced span's (the top of tstack), else the thread's attached one.
+        inner = tstack[-1] if tstack else None
+        ctx = self._context
+        if ctx is None and inner is None:
+            ctx = getattr(local, "attached", None)
+        tentry = psid = None
+        if ctx is not None:
+            if ctx.sampled:
+                psid = inner[1] if inner is not None else ctx.parent_span_id
+                tentry = (ctx.trace_id, new_span_id(), ctx.forced)
+        elif inner is not None:
+            psid = inner[1]
+            tentry = (inner[0], new_span_id(), inner[2])
+        self._tentry, self._psid = tentry, psid
+        stack.append(path)
+        tstack.append(tentry or inner)
+        self._annotation = ann = _annotation(path)
+        self._t0 = time.monotonic()
+        if ann is not None:
+            ann.__enter__()
+        return path
+
+    def __exit__(self, *exc) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        t0 = self._t0
+        dt = time.monotonic() - t0
+        stack = self._stack  # a span closes on the thread that opened it
+        if stack and stack[-1] == self._path:
+            stack.pop()
+            if self._tstack:
+                self._tstack.pop()
+        tr = self._tracer
+        tentry = self._tentry
+        tr._append(
+            _record(
+                self._path, self._base, t0 - tr._epoch, dt,
+                threading.current_thread().name,
+                tentry[0] if tentry else None,
+                tentry[1] if tentry else None,
+                self._psid,
+                os.getpid() if tentry else None,
+            )
+        )
+        return False
+
+
 class Tracer:
     """Thread-safe span collector. One process-global instance backs the
     module-level helpers; tests may build private ones."""
@@ -214,8 +334,10 @@ class Tracer:
         return stack
 
     def _tstack(self) -> List[Optional[Tuple[str, str, bool]]]:
-        """Parallel to ``_stack``: per open span, its (trace_id, span_id,
-        forced) when it was opened under a sampled context, else None."""
+        """Parallel to ``_stack``: per open span, the (trace_id, span_id,
+        forced) of the innermost traced span at that depth — its own when
+        it was opened under a sampled context, else its parent's entry —
+        or None when nothing up to there is traced."""
         ts = getattr(self._local, "tstack", None)
         if ts is None:
             ts = self._local.tstack = []
@@ -243,10 +365,8 @@ class Tracer:
             self._local.attached = prev
 
     def _innermost_traced(self) -> Optional[Tuple[str, str, bool]]:
-        for entry in reversed(self._tstack()):
-            if entry is not None:
-                return entry
-        return None
+        tstack = self._tstack()
+        return tstack[-1] if tstack else None
 
     def current_context(self) -> Optional[TraceContext]:
         """The context to hand DOWNSTREAM from this thread right now: the
@@ -262,62 +382,24 @@ class Tracer:
     # sender calls immediately before serializing onto the wire.
     extract_context = current_context
 
-    def _effective_context(
-        self, context: Optional[TraceContext]
-    ) -> Optional[TraceContext]:
-        if context is not None:
-            return context
-        return self.current_context()
-
     # -- recording ---------------------------------------------------------
 
-    @contextmanager
     def span(
         self,
         name: str,
         parent: Optional[str] = None,
         context: Optional[TraceContext] = None,
-    ) -> Iterator[str]:
+    ) -> _Span:
         """Time the body; record one SpanRecord on exit (exceptions
-        included — a failed phase still shows its wall). Yields the full
-        path so callers can hand it to worker threads.
+        included — a failed phase still shows its wall). ``with`` yields the
+        full path so callers can hand it to worker threads. While the span
+        is open, so is ``jax.profiler.TraceAnnotation("photon/<path>")``.
 
         With a sampled ``context`` (explicit, ambient from an enclosing
         traced span, or attached via ``attach_context``) the span also gets
         trace identity: a fresh span id, parented on the innermost open
         traced span or the context's remote parent."""
-        base = parent if parent is not None else self.current_path()
-        path = f"{base}{SEP}{name}" if base else name
-        ctx = self._effective_context(context)
-        tentry: Optional[Tuple[str, str, bool]] = None
-        psid: Optional[str] = None
-        if ctx is not None and ctx.sampled:
-            inner = self._innermost_traced()
-            psid = inner[1] if inner is not None else ctx.parent_span_id
-            tentry = (ctx.trace_id, new_span_id(), ctx.forced)
-        stack = self._stack()
-        tstack = self._tstack()
-        stack.append(path)
-        tstack.append(tentry)
-        t0 = time.monotonic()
-        try:
-            yield path
-        finally:
-            dt = time.monotonic() - t0
-            if stack and stack[-1] == path:
-                stack.pop()
-                if tstack:
-                    tstack.pop()
-            self._append(
-                SpanRecord(
-                    path, base, t0 - self._epoch, dt,
-                    threading.current_thread().name,
-                    trace_id=tentry[0] if tentry else None,
-                    span_id=tentry[1] if tentry else None,
-                    parent_span_id=psid if tentry else None,
-                    pid=os.getpid() if tentry else None,
-                )
-            )
+        return _Span(self, name, parent, context)
 
     def record(
         self,
@@ -369,12 +451,10 @@ class Tracer:
 
     def _append(self, rec: SpanRecord) -> None:
         with self._lock:
-            if (
-                self._spans.maxlen is not None
-                and len(self._spans) == self._spans.maxlen
-            ):
+            spans = self._spans
+            if len(spans) == spans.maxlen:  # never, when maxlen is None
                 self.dropped_spans += 1  # ring full: deque sheds the oldest
-            self._spans.append(rec)
+            spans.append(rec)
             sinks = list(self._sinks) if rec.trace_id is not None else ()
         for sink in sinks:
             try:
@@ -634,14 +714,12 @@ def flight_recorder() -> FlightRecorder:
     return _FLIGHT
 
 
-@contextmanager
 def span(
     name: str,
     parent: Optional[str] = None,
     context: Optional[TraceContext] = None,
-) -> Iterator[str]:
-    with _TRACER.span(name, parent=parent, context=context) as path:
-        yield path
+) -> _Span:
+    return _Span(_TRACER, name, parent, context)
 
 
 def record_span(

@@ -155,6 +155,7 @@ def fused_newton_system(
                 jax.ShapeDtypeStruct((d,), jnp.float32),
             ],
             interpret=interpret,
+            name="re_newton_system",
         )(X, d2, dz)
 
     d_pad = _round_up(max(d, 1), _LANE)
@@ -194,5 +195,6 @@ def fused_newton_system(
         ],
         compiler_params=None if interpret else _SEQUENTIAL_GRID,
         interpret=interpret,
+        name="re_newton_system_tiled",
     )(X, col(d2), col(dz))
     return h[:d, :d], g[:d, 0]

@@ -241,8 +241,9 @@ class MicroBatcher:
         if not live:
             return
         with tracer().span(f"{self.name}/batch"):
+            queue_wait = reg.histogram("serve_queue_wait_s")  # one lookup a batch
             for p in live:
-                reg.histogram("serve_queue_wait_s").observe(now - p.enqueue_t)
+                queue_wait.observe(now - p.enqueue_t)
             try:
                 scores = self._score_fn([p.request for p in live])
             except BaseException as exc:  # noqa: BLE001 — fail THIS batch only
@@ -252,14 +253,11 @@ class MicroBatcher:
                 return
             with tracer().span("respond"):
                 done_t = time.monotonic()
+                latency = reg.histogram("serve_request_latency_s")
                 for p, s in zip(live, scores):
-                    reg.histogram("serve_request_latency_s").observe(
-                        done_t - p.enqueue_t
-                    )
+                    latency.observe(done_t - p.enqueue_t)
                     p.future.set_result(float(s))
         reg.histogram("serve_batch_rows").observe(len(live))
-        reg.counter("serve_batches_total").inc()
-        reg.gauge("serve_batch_fill").set(len(live) / self.max_batch_size)
 
     # -- lifecycle ---------------------------------------------------------
 

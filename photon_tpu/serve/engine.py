@@ -318,23 +318,26 @@ class ServingEngine:
 
         def build() -> _State:
             faults.check("serve.warm_up", label=version)
-            store = HotColdEntityStore(
-                model,
-                self._entity_indexes,
-                hot_bytes=self.config.hot_bytes,
-                # Floor: one batch's unique entities always fit resident.
-                min_hot_rows=self.max_batch,
-                partition=self._partition,
-                device_shards=self.config.device_shards,
-            )
-            store.warm_uploads(self.max_batch)
-            transformer = GameTransformer(store.scoring_model())
-            template = self._template_batch(store)
-            traces = transformer.warm_up(
-                template, bucket_grid(self.max_batch),
-                sharding=store.batch_sharding,
-            )
-            registry().gauge("serve_warmup_traces").set(traces)
+            span = tracer().span
+            with span("store_build"):
+                store = HotColdEntityStore(
+                    model,
+                    self._entity_indexes,
+                    hot_bytes=self.config.hot_bytes,
+                    # Floor: one batch's unique entities always fit resident.
+                    min_hot_rows=self.max_batch,
+                    partition=self._partition,
+                    device_shards=self.config.device_shards,
+                )
+            with span("warm_uploads"):
+                store.warm_uploads(self.max_batch)
+            with span("transformer_warm_up"):
+                transformer = GameTransformer(store.scoring_model())
+                template = self._template_batch(store)
+                transformer.warm_up(
+                    template, bucket_grid(self.max_batch),
+                    sharding=store.batch_sharding,
+                )
             return _State(store, transformer, version, transformer.trace_count)
 
         with tracer().span("serve/warm_up"):
@@ -532,19 +535,28 @@ class ServingEngine:
         import jax
 
         n = len(requests)
-        with tracer().span("score"):
+        span = tracer().span
+        with span("score"):
             faults.check("serve.score")
-            batch = self._assemble(requests, state.store)
-            batch = pad_game_batch(batch, bucket_dim(n), xp=np)
-            # Sharded hot tables live on a mesh: replicate the batch over
-            # it so the jitted scorer sees consistent placements (a plain
-            # device_put would commit to device 0 and fail the jit's
-            # incompatible-devices check against mesh-resident tables).
-            dev = jax.device_put(batch, state.store.batch_sharding)
-            scores = state.transformer.transform(
-                dev, model=state.store.scoring_model()
+            with span("assemble"):
+                batch = self._assemble(requests, state.store)
+                batch = pad_game_batch(batch, bucket_dim(n), xp=np)
+            registry().histogram("serve_h2d_bytes").observe(
+                sum(a.nbytes for a in jax.tree_util.tree_leaves(batch))
             )
-            return np.asarray(scores)[:n]
+            with span("h2d"):
+                # Sharded hot tables live on a mesh: replicate the batch
+                # over it so the jitted scorer sees consistent placements (a
+                # plain device_put would commit to device 0 and fail the
+                # jit's incompatible-devices check against mesh-resident
+                # tables).
+                dev = jax.device_put(batch, state.store.batch_sharding)
+            with span("launch"):  # returns at dispatch
+                scores = state.transformer.transform(
+                    dev, model=state.store.scoring_model()
+                )
+            with span("d2h"):  # the wait for the device, then the copy back
+                return np.asarray(scores)[:n]
 
     def _score_batch(self, requests: List[ScoreRequest]) -> Sequence[float]:
         with self._lock:  # vs promote/reload swap; store.resolve single-writer

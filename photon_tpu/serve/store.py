@@ -57,6 +57,7 @@ from photon_tpu.models.game import (
 )
 from photon_tpu.models.glm import GeneralizedLinearModel
 from photon_tpu.obs.metrics import registry
+from photon_tpu.obs.trace import span
 from photon_tpu.serve.routing import HashRing
 from photon_tpu.utils import faults, resources
 
@@ -444,20 +445,29 @@ class HotColdEntityStore:
                 shard_lrus=shard_lrus,
             )
             if pinned:
-                if perm is not None:
-                    tabs = {}
-                    for cid in group.coord_ids:
-                        t = np.zeros(
-                            (group.capacity, host[cid].shape[1]), np.float32
-                        )
-                        t[perm] = host[cid]
-                        tabs[cid] = jax.device_put(t, self._table_sharding)
-                    group.tables = tabs
-                else:
-                    group.tables = {
-                        cid: jax.device_put(host[cid])
-                        for cid in group.coord_ids
-                    }
+                # ``device_put`` returns at once and the table lands later;
+                # the fence ends the span when it has, so the upload's
+                # seconds are read here and not under whichever fence comes
+                # next (the scorer's warm-up, which cannot start before).
+                with span("table_upload"):
+                    if perm is not None:
+                        tabs = {}
+                        for cid in group.coord_ids:
+                            t = np.zeros(
+                                (group.capacity, host[cid].shape[1]),
+                                np.float32,
+                            )
+                            t[perm] = host[cid]
+                            tabs[cid] = jax.device_put(
+                                t, self._table_sharding
+                            )
+                        group.tables = tabs
+                    else:
+                        group.tables = {
+                            cid: jax.device_put(host[cid])
+                            for cid in group.coord_ids
+                        }
+                    jax.block_until_ready(group.tables)
             else:
                 group.tables = {
                     cid: jax.device_put(
