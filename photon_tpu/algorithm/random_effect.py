@@ -321,10 +321,11 @@ class RandomEffectCoordinate(Coordinate):
     # ring rebalance moves files instead of re-streaming rows.
     device_spill_member: Optional[str] = None
     # Newton-system assembly lowering for the per-entity solves
-    # (ops/pallas_newton.RE_KERNELS): "auto" picks the fused batched Pallas
-    # kernel on a real TPU backend and XLA elsewhere; "pallas" /
-    # "pallas_bf16x" force the fused kernel (interpret mode off-TPU — the
-    # CPU parity/bench path); "xla" forces the two-read einsum lowering.
+    # (ops/pallas_newton.RE_KERNELS): "auto" is "xla", the two-read einsum
+    # lowering, on every backend (on the v5e it fits 2.4x faster than the
+    # kernel and nearer the float32 reference: PERF.md §6, PR 28); "pallas" /
+    # "pallas_bf16x" opt into the fused kernel (interpret mode off-TPU — the
+    # CPU parity/bench path).
     # Part of the solver-cache key, so variants never share executables.
     re_kernel: str = "auto"
     # Device placement for the entity-sharded multi-device path
@@ -670,18 +671,23 @@ class RandomEffectCoordinate(Coordinate):
     ) -> None:
         """Host-int accounting of the pass (no device reads): how many
         entities were re-solved vs skipped, and how much smaller the
-        dispatched entity allocation was than a full pass."""
+        dispatched entity allocation was than a full pass. Whatever the
+        gating, the pass's block solves are counted by the lowering that ran
+        them (``re_block_solves_total``)."""
+        from photon_tpu.obs.metrics import registry
+
+        reg = registry()
+        labels = dict(coordinate=self.coordinate_id)
+        reg.counter(
+            "re_block_solves_total", kernel=self._re_kernel, **labels
+        ).inc(num_dispatches)
         if not self.active_set:
             self.last_active_set_stats = None
             return
-        from photon_tpu.obs.metrics import registry
-
         total = self._total_valid_entities
         skipped = total - dispatched_valid
         full_alloc = int(sum(b.num_entities for b in self.dataset.blocks))
         ratio = (dispatched_alloc / full_alloc) if full_alloc else 0.0
-        reg = registry()
-        labels = dict(coordinate=self.coordinate_id)
         reg.gauge("re_entities_active", **labels).set(dispatched_valid)
         reg.counter("re_entities_skipped_total", **labels).inc(skipped)
         reg.histogram("re_compaction_ratio", **labels).observe(ratio)

@@ -33,6 +33,12 @@ accumulation stay float32. Parity vs the f32 XLA path is then a pinned
 tolerance, not bit-exact — see RE_KERNELS below and the BENCH_FULL.md
 verdict table.
 
+The kernel is OPT-IN (``re_kernel="pallas"`` / ``"pallas_bf16x"``): the XLA
+lowering is the default on every backend. On the v5e a warm fit on it took
+0.81 s against the kernel's 1.96 s (2^22 rows, 8192 users; chip runs of
+PR 28, PERF.md §6) and came out nearer the float32 reference, because
+Mosaic's float32 dot at default precision is one bfloat16 pass.
+
 Lowerings: interpret mode (CPU tests) runs the exact unpadded whole-slab
 kernel; compiling for a TPU takes the lane/sublane-padded row-tiled one
 (``padded=None`` auto), whose ``(tile_n, 1)`` d2/dz column blocks are
@@ -61,7 +67,7 @@ from photon_tpu.ops.pallas_glm import (
 Array = jax.Array
 
 # Solver-kernel routing values for RandomEffectCoordinate.re_kernel /
-# solve_cache.block_solver. "auto" resolves per backend; the other three are
+# solve_cache.block_solver. "auto" resolves to "xla"; the other three are
 # concrete lowerings:
 #   xla          — vmapped einsum/matmul Newton system (2 X reads/iter)
 #   pallas       — fused one-read Pallas Newton system, f32 X (bit-exact)
@@ -71,18 +77,15 @@ RE_KERNELS = ("auto", "xla", "pallas", "pallas_bf16x")
 
 
 def resolve_re_kernel(re_kernel: str) -> str:
-    """Concrete kernel for a requested routing value. ``auto`` picks the
-    fused Pallas lowering only where it runs at full speed (a real TPU
-    backend); everywhere else the XLA path wins — interpret-mode Pallas is
-    orders of magnitude slower than XLA on CPU, so auto must never select
-    it (tests and benches opt in explicitly)."""
+    """Concrete kernel for a requested routing value. ``auto`` is the XLA
+    lowering on every backend: on a TPU it is the fastest and the most
+    exact of the three (module docstring), and off a TPU interpret-mode
+    Pallas is orders of magnitude slower. The kernel is opt-in by name."""
     if re_kernel not in RE_KERNELS:
         raise ValueError(
             f"re_kernel must be one of {RE_KERNELS}, got {re_kernel!r}"
         )
-    if re_kernel == "auto":
-        return "pallas" if pallas_available() else "xla"
-    return re_kernel
+    return "xla" if re_kernel == "auto" else re_kernel
 
 
 def _system_kernel(x_ref, d2_ref, dz_ref, h_ref, g_ref):

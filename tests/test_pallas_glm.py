@@ -242,9 +242,8 @@ def test_losing_lowerings_deleted():
 
 def test_interpret_mode_is_cpu_only_and_explicit():
     """Pallas is part of the installed JAX: no import gate, no silent return
-    to XLA. Off-TPU ``pallas_available()`` is False (production call sites
-    such as re_kernel="auto" then choose XLA) and the kernels interpret
-    only because ``interpret`` resolves from the backend."""
+    to XLA. Off-TPU ``pallas_available()`` is False and the kernels
+    interpret only because ``interpret`` resolves from the backend."""
     assert not pallas_glm.pallas_available()  # no TPU backend here
     assert not hasattr(pallas_glm, "pallas_usable")
     assert not hasattr(pallas_glm, "_require_pallas")

@@ -95,15 +95,63 @@ def _solve_all(ds, re_kernel, spec=None, jit=False):
     return out
 
 
-def test_resolve_re_kernel():
+@pytest.mark.parametrize("on_tpu", [False, True])
+def test_resolve_re_kernel(on_tpu, monkeypatch):
+    """``auto`` is the XLA lowering on every backend (on the v5e it is the
+    fastest and the most exact of the three: PERF.md §6, PR 28); the kernel
+    runs only where it is asked for by name."""
+    from photon_tpu.ops import pallas_newton
+
+    monkeypatch.setattr(pallas_newton, "pallas_available", lambda: on_tpu)
     assert set(RE_KERNELS) == {"auto", "xla", "pallas", "pallas_bf16x"}
     for k in ("xla", "pallas", "pallas_bf16x"):
         assert resolve_re_kernel(k) == k
-    # CPU host: auto must pick the XLA path (interpret-mode pallas is
-    # orders slower; only tests/benches opt in).
     assert resolve_re_kernel("auto") == "xla"
     with pytest.raises(ValueError, match="re_kernel"):
         resolve_re_kernel("mosaic")
+
+
+def test_default_coordinate_counts_block_solves_by_kernel():
+    """A coordinate built with the default ``re_kernel`` runs the XLA
+    lowering, and ``re_block_solves_total`` says so: one count a dispatched
+    block, under the kernel that solved it."""
+    from photon_tpu.algorithm.random_effect import RandomEffectCoordinate
+    from photon_tpu.data.game_data import GameBatch
+    from photon_tpu.obs.metrics import registry
+    from photon_tpu.types import TaskType
+
+    ds, n = _workload(seed=8)
+    batch = GameBatch(
+        label=jnp.zeros(n, jnp.float32), offset=jnp.zeros(n, jnp.float32),
+        weight=jnp.ones(n, jnp.float32), features={}, entity_ids={},
+    )
+
+    def coordinate(cid, **kw):
+        return RandomEffectCoordinate(
+            coordinate_id=cid, dataset=ds, task=TaskType.LOGISTIC_REGRESSION,
+            objective=GLMObjective(loss=LogisticLoss, l2_weight=1.0),
+            optimizer_spec=OptimizerSpec(
+                optimizer=OptimizerType.NEWTON, max_iter=5, tol=1e-6
+            ),
+            solve_cache=SolveCache(donate=False), **kw,
+        )
+
+    def solves(cid):
+        return {
+            s["labels"]["kernel"]: s["value"] for s in registry().snapshot()
+            if s["metric"] == "re_block_solves_total"
+            and s["labels"]["coordinate"] == cid
+        }
+
+    default = coordinate("counted_default")
+    assert default.re_kernel == "auto"
+    model = None
+    for _ in range(2):
+        model, _stats = default.train(batch, None, model)
+    assert solves("counted_default") == {"xla": 2 * len(ds.blocks)}
+
+    coordinate("counted_pallas", re_kernel="pallas").train(batch, None, None)
+    assert solves("counted_pallas") == {"pallas": len(ds.blocks)}
 
 
 def test_fused_newton_system_bitexact_unbatched_and_vmapped():
