@@ -96,7 +96,9 @@ def make_data(seed=0):
     Xf[:, 0] = 1.0
     Xr = rng.normal(size=(N, D_RE)).astype(np.float32)
     Xr[:, 0] = 1.0
-    users = (rng.integers(0, E, size=N)).astype(np.int32)
+    # N / E rows a user exactly: one level of the block plan's grid, so the
+    # dataset is the ONE block the fused step takes.
+    users = rng.permutation(np.arange(N, dtype=np.int32) % E)
     w_true = (rng.normal(size=D_FIX) / np.sqrt(D_FIX)).astype(np.float32)
     logits = Xf @ w_true
     y = (rng.uniform(size=N) < 1.0 / (1.0 + np.exp(-logits))).astype(np.float32)
@@ -122,7 +124,7 @@ def run_glmix_bench(use_bf16=True, use_pallas=True):
     _progress("grouping random-effect dataset")
     ds = build_random_effect_dataset(
         users, Xr, y, np.ones(N, np.float32), E,
-        RandomEffectDataConfig(re_type="userId", feature_shard="re", n_buckets=1),
+        RandomEffectDataConfig(re_type="userId", feature_shard="re"),
     )
     (block,) = ds.blocks
 
@@ -246,7 +248,7 @@ def run_profile():
     Xf, Xr, users, y = make_data()
     ds = build_random_effect_dataset(
         users, Xr, y, np.ones(N, np.float32), E,
-        RandomEffectDataConfig(re_type="userId", feature_shard="re", n_buckets=1),
+        RandomEffectDataConfig(re_type="userId", feature_shard="re"),
     )
     (block,) = ds.blocks
     n_max = block.features.shape[1]
@@ -537,10 +539,10 @@ def run_solve_cache_ab():
 
     rng = np.random.default_rng(7)
     E_ab, d_ab, passes = 240, 8, 3
-    # Two size clusters with jittered counts — the case bucketing exists
-    # for: the quantile grouping yields blocks whose exact (E, n_max) all
-    # differ slightly (one executable each), but which round to the SAME
-    # bucket shape, collapsing onto a couple of cached executables.
+    # Two size clusters with jittered counts: the block plan gives one block
+    # a cluster (grid levels 6 and 48). Exact shapes follow the draw (E,
+    # largest count); bucketed ones sit on the grid, where the next draw of
+    # the same population lands on the executables already cached.
     counts = np.where(
         rng.uniform(size=E_ab) < 0.5,
         rng.integers(5, 7, size=E_ab),
@@ -564,7 +566,7 @@ def run_solve_cache_ab():
         ds = build_random_effect_dataset(
             users_ab, Xr_ab, y_ab, w_ab, E_ab,
             RandomEffectDataConfig(
-                re_type="userId", feature_shard="re", n_buckets=6,
+                re_type="userId", feature_shard="re",
                 shape_bucketing=bucketed, subspace_projection=False,
             ),
         )
@@ -785,7 +787,7 @@ def run_re_kernel_ab(passes: int = 4):
     """Batched small-GLM RE kernel A/B (--re-kernel-ab), four variants of
     the same clustered-entity CD workload:
 
-      xla_unmerged   — seed behavior: one dispatch per quantile block
+      xla_unmerged   — seed behavior: one dispatch per planned block
       xla_merged     — merge_same_geometry_blocks collapses same-(n,d)
                        blocks into one dispatch (real CPU wall win)
       pallas         — fused Newton-system kernel on the SAME merged
@@ -816,8 +818,9 @@ def run_re_kernel_ab(passes: int = 4):
 
     rng = np.random.default_rng(17)
     E_ab, d_ab = 360, 8
-    # Two size clusters; with 8 quantile buckets the bucketed shapes
-    # COLLIDE on a couple of (n_max, d) geometries — the merge target.
+    # Two size clusters; under ``make_ds``'s slab budget the block plan cuts
+    # the larger cluster's grid level into three blocks of ONE (n_max, d)
+    # geometry — the merge target.
     counts = np.where(
         rng.uniform(size=E_ab) < 0.5,
         rng.integers(5, 9, size=E_ab),
@@ -841,10 +844,11 @@ def run_re_kernel_ab(passes: int = 4):
         return build_random_effect_dataset(
             users, Xr, y, w, E_ab,
             RandomEffectDataConfig(
-                re_type="userId", feature_shard="re", n_buckets=8,
+                re_type="userId", feature_shard="re",
                 shape_bucketing=True, subspace_projection=False,
                 merge_same_geometry=merge,
             ),
+            slab_budget=48 * 48 * d_ab * 4,
         )
 
     ds_plain, ds_merged = make_ds(False), make_ds(True)
@@ -991,9 +995,12 @@ def run_active_set_ab(passes: int = 5):
     ds = build_random_effect_dataset(
         users, Xr, y, w, E_ab,
         RandomEffectDataConfig(
-            re_type="userId", feature_shard="re", n_buckets=6,
+            re_type="userId", feature_shard="re",
             shape_bucketing=True, subspace_projection=False,
         ),
+        # Cuts each grid level into three same-geometry blocks: the repack
+        # compacts within a geometry, and one block a geometry cannot shrink.
+        slab_budget=128 * 128 * d_re * 4,
     )
 
     def run_variant(active_set: bool):
@@ -1149,12 +1156,16 @@ def run_out_of_core_ab(passes: int = 4):
         entity_ids={"userId": jnp.asarray(users)},
     )
     cfg = RandomEffectDataConfig(
-        re_type="userId", feature_shard="re", n_buckets=8,
+        re_type="userId", feature_shard="re",
         shape_bucketing=True, subspace_projection=False,
     )
 
     def _dataset():
-        return build_random_effect_dataset(users, Xr, y, w, E_ab, cfg)
+        # 13 blocks of 64-128 lanes, so a quarter of the footprint holds the
+        # largest and the budgeted variant has blocks to evict.
+        return build_random_effect_dataset(
+            users, Xr, y, w, E_ab, cfg, slab_budget=64 * 128 * d_re * 4
+        )
 
     probe = _dataset().blocks
     footprint = sum(block_device_cost(b) for b in probe)
@@ -1165,7 +1176,7 @@ def run_out_of_core_ab(passes: int = 4):
     # only meaningful when the configured budget clears that floor.
     assert max_cost <= budget, (
         f"cohort too lumpy for a 4x A/B: largest block {max_cost} B exceeds "
-        f"quarter-footprint budget {budget} B — rebucket the cohort"
+        f"quarter-footprint budget {budget} B — lower _dataset's slab budget"
     )
 
     def run_variant(device_budget):
@@ -2047,7 +2058,7 @@ def run_exhaustion_soak():
         y = (rng.uniform(size=n) < 0.5).astype(np.float32)
         w = np.ones(n, np.float32)
         cfg = RandomEffectDataConfig(
-            re_type="userId", feature_shard="re", n_buckets=2,
+            re_type="userId", feature_shard="re",
             shape_bucketing=True,
         )
         batch = GameBatch(
@@ -5936,7 +5947,7 @@ def run_multichip_worker(n_devices: int, out_prefix: str) -> None:
         entity_ids={"userId": jnp.asarray(eids)},
     )
     cfg = RandomEffectDataConfig(
-        re_type="userId", feature_shard="re", n_buckets=4,
+        re_type="userId", feature_shard="re",
         shape_bucketing=True, subspace_projection=False,
     )
     cache = SolveCache(donate=True)
@@ -6036,7 +6047,7 @@ def _multichip_fused_rung(n_devices: int, devs, out_prefix: str) -> dict:
 
     plan = build_shard_plan(E, n_shards=S, seed=0)
     cfg = RandomEffectDataConfig(
-        re_type="userId", feature_shard="re", n_buckets=1,
+        re_type="userId", feature_shard="re",
         shape_bucketing=True, subspace_projection=False,
     )
     blocks = []

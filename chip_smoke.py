@@ -873,7 +873,11 @@ def child_kernels() -> dict:
     HIGHEST = jax.lax.Precision.HIGHEST
     s = SIZES
     n, d, d_re, E = s["kernel_n"], s["d_fix"], s["d_re"], s["entities"]
-    Xf, Xr, users, y = make_glmix_arrays(n, d, d_re, E, SEED + 2)
+    Xf, Xr, _users, y = make_glmix_arrays(n, d, d_re, E, SEED + 2)
+    # n / E rows a user exactly: one level of the block plan's grid, so the
+    # dataset is the ONE block the Newton system and the fused step take.
+    users = np.random.default_rng(SEED + 4).permutation(
+        np.arange(n, dtype=np.int32) % E)
     rng = np.random.default_rng(SEED + 3)
     w = (rng.normal(size=d) / np.sqrt(d)).astype(np.float32)
     v = rng.normal(size=d).astype(np.float32)
@@ -972,8 +976,7 @@ def child_kernels() -> dict:
     # --- random-effect Newton system, one grid instance an entity
     ds = build_random_effect_dataset(
         users, Xr, y, np.ones(n, np.float32), E,
-        RandomEffectDataConfig(re_type="userId", feature_shard="re",
-                               n_buckets=1),
+        RandomEffectDataConfig(re_type="userId", feature_shard="re"),
     )
     (block,) = ds.blocks
     Xb = jnp.asarray(block.features)  # (E, n_max, d_re)
@@ -1116,7 +1119,7 @@ def child_four_chips() -> dict:
         entity_ids={"userId": jnp.asarray(eids)},
     )
     cfg = RandomEffectDataConfig(
-        re_type="userId", feature_shard="re", n_buckets=4,
+        re_type="userId", feature_shard="re",
         shape_bucketing=True, subspace_projection=False,
     )
     warmup, steady = 2, 3
@@ -1178,7 +1181,7 @@ def child_four_chips() -> dict:
     w_f = np.ones(nf, np.float32)
     plan = build_shard_plan(Ef, n_shards=S, seed=0)
     cfg_f = RandomEffectDataConfig(
-        re_type="userId", feature_shard="re", n_buckets=1,
+        re_type="userId", feature_shard="re",
         shape_bucketing=True, subspace_projection=False,
     )
     blocks = [

@@ -179,8 +179,9 @@ batch = GameBatch(
 )
 ds = build_random_effect_dataset(
     eids, Xr, y, w, E,
-    RandomEffectDataConfig(re_type="userId", feature_shard="re", n_buckets=4,
+    RandomEffectDataConfig(re_type="userId", feature_shard="re",
                            shape_bucketing=True, subspace_projection=False),
+    slab_budget=24 * 48 * d_re * 4,  # four same-geometry blocks to compact
 )
 
 def run(active):
@@ -266,14 +267,15 @@ batch = GameBatch(
     entity_ids={"userId": jnp.asarray(eids)},
 )
 cfg = RandomEffectDataConfig(re_type="userId", feature_shard="re",
-                             n_buckets=4, shape_bucketing=True,
-                             subspace_projection=False)
+                             shape_bucketing=True, subspace_projection=False)
+SLAB = 24 * 48 * d_re * 4  # four same-geometry blocks, so a budget evicts
 
 def run(budget, passes=4):
     cache = SolveCache(donate=True)
     coord = RandomEffectCoordinate(
         coordinate_id="per_user",
-        dataset=build_random_effect_dataset(eids, Xr, y, w, E, cfg),
+        dataset=build_random_effect_dataset(eids, Xr, y, w, E, cfg,
+                                            slab_budget=SLAB),
         task=TaskType.LOGISTIC_REGRESSION,
         objective=GLMObjective(loss=LogisticLoss, l2_weight=0.5),
         optimizer_spec=OptimizerSpec(optimizer=OptimizerType.NEWTON,
@@ -289,7 +291,8 @@ def run(budget, passes=4):
     return model, coord, cache.traces_since(warm_mark)
 
 footprint = sum(block_device_cost(b) for b in
-                build_random_effect_dataset(eids, Xr, y, w, E, cfg).blocks)
+                build_random_effect_dataset(eids, Xr, y, w, E, cfg,
+                                            slab_budget=SLAB).blocks)
 ref, _, ref_post = run(None)
 ooc, coord, ooc_post = run(footprint // 4)
 st = coord.last_residency_stats

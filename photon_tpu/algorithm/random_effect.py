@@ -20,6 +20,7 @@ exactly like the reference's tracker.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -88,6 +89,9 @@ class RandomEffectTrackerStats:
     iterations: Array  # (T,) per-row iteration counts, blocks concatenated
     reasons: Array  # (T,) per-row termination reason codes
     valid: Array  # (T,) bool — False for shape-bucket padding rows
+    # (T,) int32 training rows of each entity, where the coordinate knows
+    # them in tracker order (a full resident pass); None otherwise.
+    samples: Optional[Array] = None
 
     @staticmethod
     def empty() -> "RandomEffectTrackerStats":
@@ -129,6 +133,20 @@ class RandomEffectTrackerStats:
             return 0
         return int(jnp.max(jnp.where(self.valid, self.iterations, 0)))
 
+    @property
+    def row_weighted_iterations(self) -> Optional[float]:
+        """Σ rows_e · iterations_e ÷ Σ rows_e: the iterations of the mean
+        ROW's entity, which is what the solve's work follows when entities
+        are uneven (``mean_iterations`` weighs a 30-row user like a
+        400,000-row one)."""
+        if self.samples is None:
+            return None
+        rows = jnp.where(self.valid, self.samples, 0).astype(jnp.float32)
+        return float(
+            jnp.sum(rows * self.iterations.astype(jnp.float32))
+            / jnp.maximum(jnp.sum(rows), 1.0)
+        )
+
     def summary(self) -> str:
         return (
             f"entities={self.num_entities} converged={self.num_converged} "
@@ -147,6 +165,7 @@ class RandomEffectTrackerStats:
             quarantined=self.num_quarantined,
             mean_iterations=self.mean_iterations,
             max_iterations=self.max_iterations,
+            row_weighted_iterations=self.row_weighted_iterations,
         )
 
 
@@ -279,6 +298,50 @@ def _solve_block(
     )
     return jax.vmap(solve_one)(
         block.features, block.label, block.weight, offsets, w0, fmask, block.train_mask
+    )
+
+
+def _dense_warm_start(coefs: Array, block: EntityBlock) -> Array:
+    """Fresh (E_b, block.dim) warm-start buffer for a dense block.
+
+    Always a gather (never a view of a live model array), so the solver
+    cache may DONATE it; padded entity rows gather row 0 (inert:
+    ``train_mask=False`` keeps their output at the warm start, and the
+    final scatter drops them); padded feature columns warm-start at 0.
+    """
+    w0 = coefs[jnp.maximum(block.entity_idx, 0)]
+    d = coefs.shape[1]
+    if block.dim > d:
+        w0 = jnp.pad(w0, ((0, 0), (0, block.dim - d)))
+    return w0
+
+
+@jax.jit
+def _block_inputs(block: EntityBlock, total_offset: Array, coefs: Array):
+    """What a dense block's solve reads, in ONE launch: its residual offsets
+    gathered from the flat (n,) vector and its warm start gathered from the
+    (E, d) table. Eager, the same work was six launches a block, and a
+    heavy-tailed plan dispatches twenty blocks a pass."""
+    return block.gather_offsets(total_offset), _dense_warm_start(coefs, block)
+
+
+@jax.jit
+def _merge_block_results(coefs: Array, entity_idx, ws, iterations, reasons):
+    """A full pass's epilogue in ONE launch: every block's coefficients
+    scattered into the (E, d) table (padding lanes target row E and are
+    dropped) and the tracker's rows concatenated. One program a block plan;
+    a coordinate with the active set on, whose block count changes from pass
+    to pass, keeps the per-block scatters on every pass."""
+    E, d = coefs.shape
+    for idx, w in zip(entity_idx, ws):
+        coefs = coefs.at[jnp.where(idx >= 0, idx, E)].set(
+            w[:, :d].astype(coefs.dtype), mode="drop"
+        )
+    return (
+        coefs,
+        jnp.concatenate([jnp.ravel(i) for i in iterations]).astype(jnp.int32),
+        jnp.concatenate([jnp.ravel(r) for r in reasons]).astype(jnp.int32),
+        jnp.concatenate([jnp.ravel(e) >= 0 for e in entity_idx]),
     )
 
 
@@ -781,10 +844,8 @@ class RandomEffectCoordinate(Coordinate):
         pending = []
         with span("re_dispatch_blocks"):
             for block, obj, mask, sb, sr in entries:
-                offs = faults.poison(
-                    "solve.re_block", block.gather_offsets(total_offset)
-                )
-                w0 = self._dense_warm_start(coefs, block, d)
+                offs, w0 = _block_inputs(block, total_offset, coefs)
+                offs = faults.poison("solve.re_block", offs)
                 solver = self.solve_cache.block_solver(
                     obj, self.optimizer_spec, self._config,
                     has_mask=mask is not None, convergence_tol=tol,
@@ -817,17 +878,35 @@ class RandomEffectCoordinate(Coordinate):
         )
         self._cd_pass += 1
 
-        # Per-block scatters (still async-dispatched, no host sync): each
-        # scatter's signature depends only on that block's (E_alloc,) shape,
-        # which the full first pass already compiled — so a gated pass that
-        # dispatches a different NUMBER of blocks reuses the same executables.
-        # (A single whole-pass concatenate+scatter would bake the block count
-        # into the eager-op signature and recompile at the first compaction.)
-        # Shape-bucket padding rows target out-of-range row E and are dropped.
-        for b, w, _i, _r in results:
-            idx = jnp.where(b.entity_idx >= 0, b.entity_idx, E)
-            coefs = coefs.at[idx].set(
-                w[:, :d].astype(coefs.dtype), mode="drop"
+        # With the active set on, EVERY pass scatters block by block (async,
+        # no host sync): each scatter's signature depends only on that block's
+        # (E_alloc,) shape, which the full first pass therefore compiles, so
+        # a gated pass that dispatches a different NUMBER of blocks reuses the
+        # same executables (one whole-pass program would bake the block count
+        # in and recompile at the first compaction; the tracker's two
+        # concatenations still do, once a block count). Shape-bucket padding
+        # rows target out-of-range row E and are dropped.
+        if self.active_set or not results:
+            for b, w, _i, _r in results:
+                idx = jnp.where(b.entity_idx >= 0, b.entity_idx, E)
+                coefs = coefs.at[idx].set(
+                    w[:, :d].astype(coefs.dtype), mode="drop"
+                )
+            stats = self._tracker_stats(
+                [(b.entity_idx, it, rs) for b, _w, it, rs in results]
+            )
+        else:
+            # Without it a pass dispatches the dataset's blocks in order, so
+            # its block count is the plan's: one program merges them all.
+            coefs, iters, reasons, valid = _merge_block_results(
+                coefs,
+                [b.entity_idx for b, *_ in results],
+                [w for _b, w, _i, _r in results],
+                [it for *_, it, _r in results],
+                [rs for *_, rs in results],
+            )
+            stats = RandomEffectTrackerStats(
+                iters, reasons, valid, samples=self.dataset.lane_samples
             )
 
         variances = None
@@ -838,23 +917,7 @@ class RandomEffectCoordinate(Coordinate):
             coefs, self.dataset.config.re_type, self.dataset.config.feature_shard,
             self.task, variances,
         )
-        stats = self._tracker_stats(
-            [(b.entity_idx, it, rs) for b, _w, it, rs in results]
-        )
         return model, stats
-
-    def _dense_warm_start(self, coefs: Array, block: EntityBlock, d: int) -> Array:
-        """Fresh (E_b, block.dim) warm-start buffer for a dense block.
-
-        Always a gather (never a view of a live model array), so the solver
-        cache may DONATE it; padded entity rows gather row 0 (inert:
-        ``train_mask=False`` keeps their output at the warm start, and the
-        final scatter drops them); padded feature columns warm-start at 0.
-        """
-        w0 = coefs[jnp.maximum(block.entity_idx, 0)]
-        if block.dim > d:
-            w0 = jnp.pad(w0, ((0, 0), (0, block.dim - d)))
-        return w0
 
     def _train_dense_ooc(
         self, batch: GameBatch, total_offset: Array, initial_model
@@ -1207,7 +1270,7 @@ class RandomEffectCoordinate(Coordinate):
             offs = block.gather_offsets(total_offset)
             v = jax.vmap(var_one)(
                 block.features, block.label, block.weight, offs,
-                self._dense_warm_start(coefs, block, d),
+                _dense_warm_start(coefs, block),
             )
             parts.append((block, v))
         if parts:

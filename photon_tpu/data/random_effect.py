@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from photon_tpu.data.batch import LabeledBatch
+from photon_tpu.obs.trace import span
 
 Array = jax.Array
 
@@ -75,6 +76,121 @@ def _publish_pad_waste(re_type: str, **dims: Tuple[int, int]) -> None:
         )
 
 
+# ---- block geometry: the rule, stated once ---------------------------------
+#
+# A coordinate's entities are cut into blocks (lanes, n_max, d) from their row
+# counts alone; nothing on GameEstimator or the CLI chooses it.
+#
+# 1. Every entity's n_max is its row count on the ``bucket_dim`` grid: at most
+#    1.5x its rows (4/3 above 1.5 x 2^k). Entities of one grid level share
+#    blocks, so a heavy-tailed population pads each user to its own level and
+#    never to the largest user's.
+# 2. Where few rows live, the grid is made coarser: neighbouring levels are
+#    joined, cheapest in added rows first, while the coordinate's allocated
+#    rows stay within ``PLAN_MERGE_PAD_BOUND`` x its used rows. One level less
+#    is one solver program, and one dispatched block a pass, less.
+# 3. A block's lanes are its entities rounded up by ``lane_dim`` (at most
+#    1/8 more), so allocated / used rows never pass 1.5 x 1.125 =
+#    ``PLAN_PAD_CEILING`` and sit near 1.25-1.33 in practice.
+# 4. No block's feature slab (lanes x n_max x d x itemsize) passes the
+#    ``slab_budget`` its caller gives: a level over it is cut into equal
+#    parts. The caller that places the blocks on a device owns the number
+#    (``GameEstimator``: ``slab_budget_of`` that device's memory); this module
+#    asks no device, and without a budget nothing is cut. One entity is never
+#    cut: a user whose own rows pass the budget gets a one-lane block over it.
+PLAN_MERGE_PAD_BOUND = 4.0 / 3.0
+PLAN_PAD_CEILING = 1.5 * 1.125
+PLAN_SLAB_DEVICE_SHARE = 128
+
+
+def slab_budget_of(device_bytes: int) -> int:
+    """The most bytes one block's feature slab may take on a device of
+    ``device_bytes``: 1/128 of it (134 MB of a 16 GiB chip). The share keeps
+    an even population's blocks at the lane counts they had before the plan
+    (8192 users of ~512 rows x 16 floats: 2304 lanes for 4608), and what it
+    buys is time, not memory: a block's Newton loop runs to its slowest
+    entity, and uncut that fit was 6 % slower at the same peak (PERF.md §6)."""
+    return int(device_bytes) // PLAN_SLAB_DEVICE_SHARE
+
+
+def lane_dim(e: int) -> int:
+    """Round a block's entity count UP to a multiple of 1/16 of the next
+    power of two: exact up to 16, at most 1/8 more above. Finer than
+    ``bucket_dim`` because a lane costs a Cholesky every Newton iteration,
+    and still a grid, so a population that drifts a little from one training
+    to the next lands on the shapes the compile cache already holds."""
+    e = max(int(e), 1)
+    step = max((1 << (e - 1).bit_length()) // 16, 1)
+    return -(-e // step) * step
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockPlan:
+    """One block of the plan: which entities (positions in the count vector,
+    ascending) and the allocated shape."""
+
+    members: np.ndarray
+    n_max: int
+    lanes: int
+
+
+def _plan_part(members: np.ndarray, counts: np.ndarray, n_max: int,
+               bucketed: bool) -> BlockPlan:
+    if not bucketed:
+        return BlockPlan(members, int(max(counts[members].max(), 1)), members.size)
+    return BlockPlan(members, int(n_max), lane_dim(members.size))
+
+
+def plan_blocks(
+    counts: np.ndarray,
+    row_bytes: int,
+    bucketed: bool = True,
+    slab_budget: Optional[int] = None,
+) -> List[BlockPlan]:
+    """Block geometry for entities with ``counts`` rows each (all > 0) and
+    ``row_bytes`` bytes a feature row, by the rule above. ``bucketed=False``
+    keeps the grouping and allocates exact shapes; ``slab_budget=None`` cuts
+    no level."""
+    counts = np.asarray(counts, np.int64)
+    if counts.size == 0:
+        return []
+    grid = {int(c): bucket_dim(int(c)) for c in np.unique(counts)}
+    level = np.array([grid[int(c)] for c in counts], np.int64)
+    # [n_max, entities] a level, ascending; joined where the rows are few.
+    groups = [[int(n), int(np.sum(level == n))] for n in np.unique(level)]
+
+    def allocated(gs) -> int:
+        return sum(lane_dim(e) * n for n, e in gs)
+
+    room = PLAN_MERGE_PAD_BOUND * float(counts.sum())
+    while len(groups) > 1:
+        joined = [
+            groups[:i] + [[groups[i + 1][0], groups[i][1] + groups[i + 1][1]]]
+            + groups[i + 2:]
+            for i in range(len(groups) - 1)
+        ]
+        cost, best = min(((allocated(g), g) for g in joined), key=lambda cg: cg[0])
+        if cost > room:
+            break
+        groups = best
+
+    plans: List[BlockPlan] = []
+    lower = 0
+    for n_max, _e in groups:
+        members = np.flatnonzero((level > lower) & (level <= n_max))
+        lower = n_max
+        parts = 1
+        while True:
+            cut = np.array_split(members, parts)
+            if slab_budget is None or cut[0].size == 1 or all(
+                lane_dim(m.size) * n_max * row_bytes <= slab_budget for m in cut
+            ):
+                break
+            parts += 1
+        plans.extend(_plan_part(m, counts, n_max, bucketed) for m in cut)
+    return plans
+
+
 def _byteswap64(x: np.ndarray) -> np.ndarray:
     """Deterministic sampling key (role of Spark's byteswap64 hash,
     RandomEffectDataset.scala:517-524)."""
@@ -104,7 +220,6 @@ class RandomEffectDataConfig:
     active_upper_bound: Optional[int] = None  # numActiveDataPointsUpperBound
     active_lower_bound: Optional[int] = None  # lower bound on #samples/entity
     features_to_samples_ratio: Optional[float] = None  # Pearson selection cap
-    n_buckets: int = 4  # blocks with distinct n_max to bound padding waste
     # Round block shapes (E, n_max, d) UP to the geometric bucket grid (see
     # ``bucket_dim``) so heterogeneous entity populations collapse onto a
     # handful of cached solver executables (algorithm/solve_cache.py).
@@ -126,7 +241,9 @@ class RandomEffectDataConfig:
     # whole-program fusion order inside the vmapped Newton solve, so results
     # match the unmerged layout to solver tolerance, not bit-for-bit (the
     # re_kernel pallas-vs-xla parity, which IS bit-exact, is a separate
-    # axis — it holds on whichever layout is selected here).
+    # axis — it holds on whichever layout is selected here). Under the block
+    # plan two dense blocks share a geometry only where a ``slab_budget`` cut
+    # a level, so this joins exactly those cuts again: ROADMAP D3 retires it.
     merge_same_geometry: bool = False
 
 
@@ -204,6 +321,10 @@ class RandomEffectDataset:
     blocks: List[EntityBlock]
     num_entities: int  # total interned entities E for this RE type
     dim: int
+    # (Σ lanes,) int32 rows of every lane, blocks in order, 0 on padding
+    # lanes: the tracker weights iterations by it. None where the blocks
+    # were rearranged after the build.
+    lane_samples: Optional[Array] = None
 
     @property
     def num_active_samples(self) -> int:
@@ -243,6 +364,7 @@ def build_random_effect_dataset(
     config: RandomEffectDataConfig,
     uid: Optional[np.ndarray] = None,
     existing_model_mask: Optional[np.ndarray] = None,
+    slab_budget: Optional[int] = None,
 ) -> RandomEffectDataset:
     """Host-side grouping: the TPU analogue of RandomEffectDataset.apply
     (reference :260-349 build pipeline).
@@ -263,6 +385,9 @@ def build_random_effect_dataset(
     each block to the union of its entities' active columns, reference
     RandomEffectDataset.scala:383-432); dense input opts in via
     ``config.subspace_projection=True``.
+
+    ``slab_budget`` (bytes) is rule 4 of the block plan above: the caller
+    that owns the device gives it, and None cuts no level.
     """
     sp_indices = sp_values = None
     if isinstance(features, tuple):
@@ -307,102 +432,106 @@ def build_random_effect_dataset(
 
     lb = config.active_lower_bound or 0
 
-    # Bucket entities by sample count to bound padding waste.
+    # Block geometry, planned from the row counts.
     counts = np.array([len(rows) for _, rows in entities])
     if counts.size == 0:
         return RandomEffectDataset(config, [], num_entities, d)
-    n_buckets = max(1, min(config.n_buckets, len(np.unique(counts))))
-    # Quantile cut points on counts → per-bucket n_max.
-    qs = np.quantile(counts, np.linspace(0, 1, n_buckets + 1)[1:], method="higher")
-    qs = np.unique(qs.astype(np.int64))
-
+    with span("plan"):
+        # A projected block's width is its content's (known only once it is
+        # grouped), so the byte budget holds dense blocks alone.
+        d_alloc = bucket_dim(d) if config.shape_bucketing else d
+        plans = plan_blocks(
+            counts,
+            0 if project else d_alloc * np.dtype(feat_dtype).itemsize,
+            bucketed=config.shape_bucketing,
+            slab_budget=slab_budget,
+        )
     blocks: List[EntityBlock] = []
-    assigned = np.digitize(counts, qs, right=True)
-    for b, n_max in enumerate(qs):
-        sel = np.flatnonzero(assigned == b)
-        if sel.size == 0:
-            continue
-        n_max = int(max(n_max, 1))
-        E = sel.size
-        block_rows = np.concatenate([entities[gi][1] for gi in sel])
+    lane_samples = []
+    with span("fill"):
+        for plan in plans:
+            sel, n_max, E_alloc = plan.members, plan.n_max, plan.lanes
+            E = sel.size
+            block_rows = np.concatenate([entities[gi][1] for gi in sel])
 
-        # Subspace compaction: block feature space = union of active columns
-        # (LinearSubspaceProjector per vmap block instead of per entity).
-        col_map = inv_map = None
-        if project:
-            if sp_indices is not None:
-                active = sp_indices[block_rows][sp_values[block_rows] != 0]
-                col_map = np.unique(active).astype(np.int64)
-            else:
-                col_map = np.flatnonzero(
-                    np.any(features[block_rows] != 0, axis=0)
-                ).astype(np.int64)
-            if col_map.size == 0:
-                col_map = np.zeros((1,), np.int64)  # degenerate all-zero block
-            inv_map = np.full((d,), -1, dtype=np.int64)
-            inv_map[col_map] = np.arange(col_map.size)
-        d_block = int(col_map.size) if project else d
+            # Subspace compaction: block feature space = union of active columns
+            # (LinearSubspaceProjector per vmap block instead of per entity).
+            col_map = inv_map = None
+            if project:
+                if sp_indices is not None:
+                    active = sp_indices[block_rows][sp_values[block_rows] != 0]
+                    col_map = np.unique(active).astype(np.int64)
+                else:
+                    col_map = np.flatnonzero(
+                        np.any(features[block_rows] != 0, axis=0)
+                    ).astype(np.int64)
+                if col_map.size == 0:
+                    col_map = np.zeros((1,), np.int64)  # degenerate all-zero block
+                inv_map = np.full((d,), -1, dtype=np.int64)
+                inv_map[col_map] = np.arange(col_map.size)
+            d_block = int(col_map.size) if project else d
 
-        # Shape bucketing: round (E, n_max, d) up to the geometric grid so
-        # the solver cache keys collapse; padding is inert by construction
-        # (weight 0, train_mask False, entity_idx −1). Projected blocks keep
-        # their exact content-defined col_map width.
-        E_alloc = E
-        n_used = int(counts[sel].sum())
-        d_used = d_block
-        if config.shape_bucketing:
-            n_max = bucket_dim(n_max)
-            E_alloc = bucket_dim(E)
-            if not project:
+            # Shape bucketing: the plan rounded (E, n_max) up to its grids and d
+            # follows here, so the solver cache keys collapse; padding is inert
+            # by construction (weight 0, train_mask False, entity_idx −1).
+            # Projected blocks keep their exact content-defined col_map width.
+            n_used = int(counts[sel].sum())
+            lane_samples.append(np.zeros((E_alloc,), np.int32))
+            lane_samples[-1][:E] = counts[sel]
+            d_used = d_block
+            if config.shape_bucketing and not project:
                 d_block = bucket_dim(d_block)
-        _publish_pad_waste(
-            config.re_type,
-            entities=(E, E_alloc),
-            samples=(n_used, E_alloc * n_max),
-            features=(d_used, d_block),
-        )
+            _publish_pad_waste(
+                config.re_type,
+                entities=(E, E_alloc),
+                samples=(n_used, E_alloc * n_max),
+                features=(d_used, d_block),
+            )
 
-        feat = np.zeros((E_alloc, n_max, d_block), dtype=feat_dtype)
-        lab = np.zeros((E_alloc, n_max), dtype=label.dtype)
-        wt = np.zeros((E_alloc, n_max), dtype=weight.dtype)
-        sidx = np.full((E_alloc, n_max), -1, dtype=np.int32)
-        eidx = np.full((E_alloc,), -1, dtype=np.int32)
-        tmask = np.zeros((E_alloc,), dtype=bool)
-        for j, gi in enumerate(sel):
-            eid, rows = entities[gi]
-            m = len(rows)
-            if sp_indices is not None:
-                # Scatter padded-sparse rows into the compact block space.
-                loc = inv_map[sp_indices[rows]]  # (m, k), −1 only for 0-values
-                vals = sp_values[rows]
-                keep = vals != 0
-                r_i, _k_i = np.nonzero(keep)
-                np.add.at(feat[j], (r_i, loc[keep]), vals[keep])
-            elif project:
-                feat[j, :m] = features[rows][:, col_map]
-            else:
-                # d_block ≥ d under bucketing; padded columns stay zero.
-                feat[j, :m, :d] = features[rows]
-            lab[j, :m] = label[rows]
-            wt[j, :m] = weight[rows]
-            sidx[j, :m] = rows
-            eidx[j] = eid
-            tmask[j] = m >= lb or (
-                existing_model_mask is not None
-                and not bool(existing_model_mask[eid])
+            feat = np.zeros((E_alloc, n_max, d_block), dtype=feat_dtype)
+            lab = np.zeros((E_alloc, n_max), dtype=label.dtype)
+            wt = np.zeros((E_alloc, n_max), dtype=weight.dtype)
+            sidx = np.full((E_alloc, n_max), -1, dtype=np.int32)
+            eidx = np.full((E_alloc,), -1, dtype=np.int32)
+            tmask = np.zeros((E_alloc,), dtype=bool)
+            for j, gi in enumerate(sel):
+                eid, rows = entities[gi]
+                m = len(rows)
+                if sp_indices is not None:
+                    # Scatter padded-sparse rows into the compact block space.
+                    loc = inv_map[sp_indices[rows]]  # (m, k), −1 only for 0-values
+                    vals = sp_values[rows]
+                    keep = vals != 0
+                    r_i, _k_i = np.nonzero(keep)
+                    np.add.at(feat[j], (r_i, loc[keep]), vals[keep])
+                elif project:
+                    feat[j, :m] = features[rows][:, col_map]
+                else:
+                    # d_block ≥ d under bucketing; padded columns stay zero.
+                    feat[j, :m, :d] = features[rows]
+                lab[j, :m] = label[rows]
+                wt[j, :m] = weight[rows]
+                sidx[j, :m] = rows
+                eidx[j] = eid
+                tmask[j] = m >= lb or (
+                    existing_model_mask is not None
+                    and not bool(existing_model_mask[eid])
+                )
+            blocks.append(
+                EntityBlock(
+                    entity_idx=jnp.asarray(eidx),
+                    features=jnp.asarray(feat),
+                    label=jnp.asarray(lab),
+                    weight=jnp.asarray(wt),
+                    sample_index=jnp.asarray(sidx),
+                    train_mask=jnp.asarray(tmask),
+                    col_map=None if col_map is None else jnp.asarray(col_map, jnp.int32),
+                )
             )
-        blocks.append(
-            EntityBlock(
-                entity_idx=jnp.asarray(eidx),
-                features=jnp.asarray(feat),
-                label=jnp.asarray(lab),
-                weight=jnp.asarray(wt),
-                sample_index=jnp.asarray(sidx),
-                train_mask=jnp.asarray(tmask),
-                col_map=None if col_map is None else jnp.asarray(col_map, jnp.int32),
-            )
-        )
-    dataset = RandomEffectDataset(config, blocks, num_entities, d)
+    dataset = RandomEffectDataset(
+        config, blocks, num_entities, d,
+        lane_samples=jnp.asarray(np.concatenate(lane_samples)),
+    )
     if config.merge_same_geometry:
         dataset = merge_same_geometry_blocks(dataset)
     return dataset
@@ -415,9 +544,9 @@ def merge_same_geometry_blocks(
     block each — the dispatch-count collapse behind ``re_kernel`` batching.
 
     Shape bucketing rounds every block's n_max/dim onto the geometric grid
-    (``bucket_dim``), so quantile n-buckets frequently COLLIDE on the same
-    (n_max, dim): the builder still emits them as separate blocks (one per
-    quantile), and each becomes one solver dispatch per CD pass. Entities
+    (``bucket_dim``), and a level the slab budget cut comes out as several
+    blocks of the same (n_max, dim): the builder emits them as separate
+    blocks, and each becomes one solver dispatch per CD pass. Entities
     are vmap lanes with no cross-entity math, so same-geometry blocks can
     concatenate along the entity axis with per-entity results unchanged —
     one dispatch solves them all, and the fused Pallas kernel
@@ -493,7 +622,7 @@ def merge_same_geometry_blocks(
                 col_map=None,
             )
         )
-    return dataclasses.replace(dataset, blocks=merged)
+    return dataclasses.replace(dataset, blocks=merged, lane_samples=None)
 
 
 def pack_into_sizes(total: int, allowed_sizes: Sequence[int]) -> List[int]:

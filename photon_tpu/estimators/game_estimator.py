@@ -19,6 +19,7 @@ import dataclasses
 import logging
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
+import jax
 import numpy as np
 
 from photon_tpu.algorithm.coordinate import Coordinate
@@ -30,6 +31,7 @@ from photon_tpu.data.normalization import NormalizationContext
 from photon_tpu.data.random_effect import (
     RandomEffectDataConfig,
     build_random_effect_dataset,
+    slab_budget_of,
 )
 from photon_tpu.estimators.config import (
     FixedEffectCoordinateConfig,
@@ -43,6 +45,7 @@ from photon_tpu.models.game import (
     ProjectedRandomEffectModel,
     RandomEffectModel,
 )
+from photon_tpu.obs.metrics import registry
 from photon_tpu.obs.trace import span
 from photon_tpu.ops.losses import loss_for_task
 from photon_tpu.ops.objective import GLMObjective
@@ -54,6 +57,14 @@ from photon_tpu.utils.timed import Timed
 logger = logging.getLogger(__name__)
 
 CoordinateConfig = Union[FixedEffectCoordinateConfig, RandomEffectCoordinateConfig]
+
+
+def _device_slab_budget() -> Optional[int]:
+    """The block plan's byte budget (data/random_effect.py, rule 4) for the
+    device the blocks are placed on; None, so no level is cut, where the
+    backend reports no memory limit, as the CPU does."""
+    limit = (jax.local_devices()[0].memory_stats() or {}).get("bytes_limit")
+    return slab_budget_of(limit) if limit else None
 
 
 def _existing_entity_mask(prev_model) -> np.ndarray:
@@ -271,14 +282,23 @@ class GameEstimator:
                 }
             with span("group"):
                 for cfg in self.coordinate_configs:
-                    if isinstance(cfg, RandomEffectCoordinateConfig):
-                        self._re_datasets[cfg.coordinate_id] = (
-                            self._group_entities(
-                                cfg, eids_np[cfg.re_type],
-                                feats_np[cfg.feature_shard],
-                                label_np, weight_np, uid_np,
-                            )
+                    if not isinstance(cfg, RandomEffectCoordinateConfig):
+                        continue
+                    # Children ``plan`` and ``fill`` open in the builder.
+                    with span(cfg.coordinate_id):
+                        ds = self._group_entities(
+                            cfg, eids_np[cfg.re_type],
+                            feats_np[cfg.feature_shard],
+                            label_np, weight_np, uid_np,
                         )
+                    self._re_datasets[cfg.coordinate_id] = ds
+                    # What the plan costs a pass: one dispatch a block, one
+                    # solver program a distinct (lanes, n_max, d).
+                    labels = dict(coordinate=cfg.coordinate_id)
+                    registry().gauge("re_blocks", **labels).set(len(ds.blocks))
+                    registry().gauge("re_block_geometries", **labels).set(
+                        len({b.features.shape for b in ds.blocks})
+                    )
         self._prepared_for = batch
 
     def _group_entities(self, cfg, eids, feats, label_np, weight_np, uid_np):
@@ -315,6 +335,7 @@ class GameEstimator:
             ),
             uid=uid_np,
             existing_model_mask=existing,
+            slab_budget=_device_slab_budget(),
         )
 
     # --- fit ---

@@ -24,7 +24,7 @@ from photon_tpu.types import OptimizerType, TaskType
 E = 96
 
 
-def _cold_cohort_problem(frac_cold=3, d=6, seed=7):
+def _cold_cohort_problem(frac_cold=3, d=6, seed=7, cold_level=False):
     """Logistic problem where every entity whose id is NOT a multiple of
     ``frac_cold`` has ALL-ZERO random-effect features: the ridge solve
     returns exactly w=0 for those entities every pass, so their coefficient
@@ -32,11 +32,18 @@ def _cold_cohort_problem(frac_cold=3, d=6, seed=7):
     at the first gated pass.
 
     Sample counts sit in ONE bucket window (37..46 → n_max bucket 48), so
-    the quantile grouping yields several SAME-geometry blocks — the regime
-    where the active-set repack actually compacts (a geometry group with a
-    single block can only fall back to identity dispatch, never shrink)."""
+    the block plan under ``_dataset``'s slab budget yields several
+    SAME-geometry blocks — the regime where the active-set repack actually
+    compacts (a geometry group with a single block can only fall back to
+    identity dispatch, never shrink). ``cold_level`` gives the cold entities
+    20..23 rows (n_max bucket 24): an all-cold block of their own without any
+    budget, which a projected dataset needs because the budget holds dense
+    blocks alone."""
     rng = np.random.default_rng(seed)
     counts = rng.integers(37, 47, size=E)
+    if cold_level:
+        cold = np.arange(E) % frac_cold != 0
+        counts[cold] = rng.integers(20, 24, size=int(cold.sum()))
     eids = np.repeat(np.arange(E, dtype=np.int32), counts)
     n = eids.size
     X = rng.normal(size=(n, d)).astype(np.float32)
@@ -46,13 +53,16 @@ def _cold_cohort_problem(frac_cold=3, d=6, seed=7):
     return eids, X, y, w
 
 
-def _dataset(eids, X, y, w, n_buckets=4, projected=False):
+def _dataset(eids, X, y, w, projected=False):
+    # One (E / 4, 48, d) slab as the budget: a dense data set's one grid level
+    # of 48 rows is cut into four same-geometry blocks.
     return build_random_effect_dataset(
         eids, X, y, w, E,
         RandomEffectDataConfig(
-            re_type="userId", feature_shard="re", n_buckets=n_buckets,
+            re_type="userId", feature_shard="re",
             shape_bucketing=True, subspace_projection=projected,
         ),
+        slab_budget=(E // 4) * 48 * X.shape[1] * X.dtype.itemsize,
     )
 
 
@@ -177,7 +187,7 @@ def test_compact_entity_blocks_rejects_mixed_geometry():
     # Bimodal counts (5..6 vs 37..46) land in different n_max buckets.
     rng = np.random.default_rng(3)
     counts = np.where(
-        np.arange(E) % 4 != 0,  # 3/4 small → the median cut lands at 6
+        np.arange(E) % 4 != 0,  # 3/4 small: grid levels 6 and 48
         rng.integers(5, 7, size=E),
         rng.integers(37, 47, size=E),
     )
@@ -186,7 +196,7 @@ def test_compact_entity_blocks_rejects_mixed_geometry():
     X = rng.normal(size=(n, 6)).astype(np.float32)
     y = (rng.uniform(size=n) < 0.5).astype(np.float32)
     w = np.ones(n, np.float32)
-    ds = _dataset(eids, X, y, w, n_buckets=2)
+    ds = _dataset(eids, X, y, w)
     geoms = {(b.n_max, b.dim) for b in ds.blocks}
     assert len(geoms) >= 2, f"expected mixed geometries, got {geoms}"
     keep = [np.asarray(b.entity_idx) >= 0 for b in ds.blocks]
@@ -245,10 +255,10 @@ def test_projected_whole_block_skip_parity():
     cannot merge without a retrace): an all-cold geometry converges its
     blocks entirely, later passes skip them, and the final objective still
     matches the full run at rtol 1e-5."""
-    eids, X, y, w = _cold_cohort_problem()
+    eids, X, y, w = _cold_cohort_problem(cold_level=True)
     batch = _batch(eids, X, y, w)
     ds = _dataset(eids, X, y, w, projected=True)
-    assert ds.projected
+    assert ds.projected and len(ds.blocks) == 2
 
     m_full, _ = _run_passes(
         _coordinate(ds, SolveCache(donate=True), active_set=False),

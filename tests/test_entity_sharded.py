@@ -82,7 +82,7 @@ def make_batch(eids, Xr, y, w):
 
 
 RE_CFG = RandomEffectDataConfig(
-    re_type="userId", feature_shard="re", n_buckets=3,
+    re_type="userId", feature_shard="re",
     shape_bucketing=True, subspace_projection=False,
 )
 OBJ = GLMObjective(loss=LogisticLoss, l2_weight=0.5)
@@ -458,7 +458,7 @@ def _fused_run(n_dev, S=8):
 
     plan = build_shard_plan(E_f, n_shards=S, seed=0)
     cfg = RandomEffectDataConfig(
-        re_type="userId", feature_shard="re", n_buckets=1,
+        re_type="userId", feature_shard="re",
         shape_bucketing=True, subspace_projection=False,
     )
     blocks = []
@@ -517,7 +517,7 @@ def test_stack_shard_blocks_rejects_mismatched_geometry():
     y = np.zeros(32, np.float32)
     w = np.ones(32, np.float32)
     cfg = RandomEffectDataConfig(
-        re_type="userId", feature_shard="re", n_buckets=1,
+        re_type="userId", feature_shard="re",
         shape_bucketing=True, subspace_projection=False,
     )
     a = build_random_effect_dataset(eids, Xr, y, w, 8, cfg).blocks[0]

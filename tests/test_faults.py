@@ -253,8 +253,7 @@ def _re_coordinate(eids, X, y, w, **kw):
 
     ds = build_random_effect_dataset(
         eids, X, y, w, E,
-        RandomEffectDataConfig(re_type="userId", feature_shard="re",
-                               n_buckets=2),
+        RandomEffectDataConfig(re_type="userId", feature_shard="re"),
     )
     return RandomEffectCoordinate(
         coordinate_id="per_user",

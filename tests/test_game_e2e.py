@@ -333,7 +333,7 @@ def test_active_lower_bound_and_ignore_threshold_for_new_models():
     y = (rng.uniform(size=n) < 0.5).astype(np.float32)
     w = np.ones(n, np.float32)
     cfg = RandomEffectDataConfig(
-        re_type="userId", feature_shard="re", active_lower_bound=3, n_buckets=1
+        re_type="userId", feature_shard="re", active_lower_bound=3
     )
 
     def trainable(ds):

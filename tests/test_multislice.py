@@ -89,7 +89,7 @@ def test_glmix_step_on_multislice_mesh():
 
     ds = build_random_effect_dataset(
         users, Xr, y, np.ones(n, np.float32), E,
-        RandomEffectDataConfig(re_type="userId", feature_shard="re", n_buckets=1),
+        RandomEffectDataConfig(re_type="userId", feature_shard="re"),
     )
     (block,) = ds.blocks
 

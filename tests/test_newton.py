@@ -172,11 +172,12 @@ def test_solve_block_routes_to_newton_and_matches_lbfgs():
     N, E, d = 512, 16, 4
     Xr = rng.normal(size=(N, d)).astype(np.float32)
     Xr[:, 0] = 1.0
-    users = rng.integers(0, E, size=N).astype(np.int32)
+    # N / E rows each: one grid level, so the plan is one block.
+    users = rng.permutation(np.repeat(np.arange(E, dtype=np.int32), N // E))
     y = (rng.uniform(size=N) < 0.5).astype(np.float32)
     ds = build_random_effect_dataset(
         users, Xr, y, np.ones(N, np.float32), E,
-        RandomEffectDataConfig(re_type="u", feature_shard="re", n_buckets=1),
+        RandomEffectDataConfig(re_type="u", feature_shard="re"),
     )
     (block,) = ds.blocks
     obj = GLMObjective(loss=LogisticLoss, l2_weight=0.5, intercept_index=0)
@@ -280,11 +281,12 @@ def test_solve_block_tron_masked_and_unmasked():
     N, E, d = 600, 12, 5
     Xr = rng.normal(size=(N, d)).astype(np.float32)
     Xr[:, 0] = 1.0
-    users = rng.integers(0, E, size=N).astype(np.int32)
+    # N / E rows each: one grid level, so the plan is one block.
+    users = rng.permutation(np.repeat(np.arange(E, dtype=np.int32), N // E))
     y = (rng.uniform(size=N) < 0.5).astype(np.float32)
     ds = build_random_effect_dataset(
         users, Xr, y, np.ones(N, np.float32), E,
-        RandomEffectDataConfig(re_type="u", feature_shard="re", n_buckets=1),
+        RandomEffectDataConfig(re_type="u", feature_shard="re"),
     )
     (block,) = ds.blocks
     d_b = block.dim  # may exceed d under shape bucketing (padded zero cols)

@@ -56,7 +56,7 @@ Y = (_rng.uniform(size=N) < 0.5).astype(np.float32)
 W = np.ones(N, np.float32)
 
 CFG = RandomEffectDataConfig(
-    re_type="userId", feature_shard="re", n_buckets=4, shape_bucketing=True
+    re_type="userId", feature_shard="re", shape_bucketing=True
 )
 BATCH = GameBatch(
     label=jnp.asarray(Y), offset=jnp.zeros(N, jnp.float32),
@@ -66,8 +66,15 @@ BATCH = GameBatch(
 SPEC = OptimizerSpec(optimizer=OptimizerType.NEWTON, max_iter=25, tol=1e-9)
 
 
+# One (24, 48, D) slab: cuts the one grid level the counts share into four
+# same-geometry blocks, so a device budget has blocks to evict.
+SLAB_BUDGET = 24 * 48 * D * 4
+
+
 def _dataset():
-    return build_random_effect_dataset(EIDS, X, Y, W, E, CFG)
+    return build_random_effect_dataset(
+        EIDS, X, Y, W, E, CFG, slab_budget=SLAB_BUDGET
+    )
 
 
 def _footprint():
@@ -272,7 +279,7 @@ def _coord_kwargs(**over):
 
 def test_ooc_projected_dataset_falls_back_fully_resident(caplog):
     cfg = RandomEffectDataConfig(
-        re_type="userId", feature_shard="re", n_buckets=4,
+        re_type="userId", feature_shard="re",
         shape_bucketing=True, subspace_projection=True,
     )
     ds = build_random_effect_dataset(EIDS, X, Y, W, E, cfg)
@@ -285,7 +292,7 @@ def test_ooc_projected_dataset_falls_back_fully_resident(caplog):
 
 def test_ooc_rejects_pearson_ratio():
     cfg = RandomEffectDataConfig(
-        re_type="userId", feature_shard="re", n_buckets=4,
+        re_type="userId", feature_shard="re",
         shape_bucketing=True, features_to_samples_ratio=0.5,
     )
     ds = build_random_effect_dataset(EIDS, X, Y, W, E, cfg)

@@ -44,7 +44,12 @@ from photon_tpu.types import OptimizerType
 BF16X_TOL = 5e-3
 
 
-def _workload(seed=0, n=1800, d=6, E=48, n_buckets=4):
+# One (8, 48, 6) float32 slab: as the block plan's budget it cuts the 32-row
+# grid level of ``_workload``'s counts into several same-geometry blocks.
+SLAB_BUDGET = 8 * 48 * 6 * 4
+
+
+def _workload(seed=0, n=1800, d=6, E=48, slab_budget=None):
     """Clustered-count workload whose bucketed blocks cover several
     geometries (the mixed-bucket case of the acceptance criteria)."""
     rng = np.random.default_rng(seed)
@@ -64,9 +69,10 @@ def _workload(seed=0, n=1800, d=6, E=48, n_buckets=4):
     ds = build_random_effect_dataset(
         eids, X, y, wt, E,
         RandomEffectDataConfig(
-            re_type="m", feature_shard="s", n_buckets=n_buckets,
+            re_type="m", feature_shard="s",
             subspace_projection=False,
         ),
+        slab_budget=slab_budget,
     )
     return ds, n
 
@@ -221,7 +227,7 @@ def test_padding_rows_inert():
     fused kernel: real entities' coefficients are unchanged by the
     padding's presence, and the padded rows produce the same (finite)
     output as the XLA path."""
-    ds, _ = _workload(seed=2, E=30, n_buckets=2)
+    ds, _ = _workload(seed=2)  # 23 entities of one level fill 24 lanes
     padded_blocks = [
         b for b in ds.blocks if np.any(np.asarray(b.entity_idx) < 0)
     ]
@@ -300,7 +306,7 @@ def test_zero_post_warmup_retraces():
 
 
 def test_merge_same_geometry_blocks():
-    ds, _ = _workload(seed=7, E=64, n_buckets=8)
+    ds, _ = _workload(seed=7, E=64, slab_budget=SLAB_BUDGET)
     geoms = [(b.n_max, b.dim) for b in ds.blocks]
     assert len(set(geoms)) < len(geoms), "need colliding geometries"
     merged = merge_same_geometry_blocks(ds)
@@ -351,9 +357,10 @@ def test_config_flag_builds_merged_dataset():
         return build_random_effect_dataset(
             eids, X, y, wt, E,
             RandomEffectDataConfig(
-                re_type="m", feature_shard="s", n_buckets=8,
+                re_type="m", feature_shard="s",
                 subspace_projection=False, merge_same_geometry=merge,
             ),
+            slab_budget=SLAB_BUDGET,
         )
 
     plain, merged = build(False), build(True)

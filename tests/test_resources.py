@@ -319,7 +319,7 @@ def _re_dataset():
     )
 
     cfg = RandomEffectDataConfig(
-        re_type="userId", feature_shard="re", n_buckets=2,
+        re_type="userId", feature_shard="re",
         shape_bucketing=True,
     )
     return build_random_effect_dataset(RE_EIDS, RE_X, RE_Y, RE_W, RE_E, cfg)

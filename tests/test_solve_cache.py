@@ -31,8 +31,9 @@ rng = np.random.default_rng(11)
 
 
 def _clustered_problem(dtype=np.float32):
-    """Entity sample counts in one bucket window, sized so the quantile
-    grouping yields THREE 12-entity blocks whose EXACT (E, n_max) differ —
+    """Entity sample counts in one bucket window, sized so the block plan
+    under ``_dataset``'s slab budget yields THREE 12-entity blocks whose EXACT
+    (E, n_max) differ —
     (12,40,·), (12,43,·), (12,46,·) — but whose bucketed shapes coincide at
     (12, 48, ·). The last 12 of the E entities carry no data (their rows
     stay zero in every trained model)."""
@@ -48,13 +49,16 @@ def _clustered_problem(dtype=np.float32):
     return eids, X, y, w
 
 
-def _dataset(eids, X, y, w, bucketed=True, n_buckets=4):
+def _dataset(eids, X, y, w, bucketed=True):
+    # The budget of one (12, 48, bucket_dim(D)) slab cuts the one grid level
+    # the counts share into three blocks.
     return build_random_effect_dataset(
         eids, X, y, w, E,
         RandomEffectDataConfig(
-            re_type="userId", feature_shard="re", n_buckets=n_buckets,
+            re_type="userId", feature_shard="re",
             shape_bucketing=bucketed, subspace_projection=False,
         ),
+        slab_budget=12 * 48 * bucket_dim(D) * X.dtype.itemsize,
     )
 
 
@@ -98,7 +102,7 @@ def test_retrace_once_per_bucket_across_passes():
     once per (bucket, objective-config) key; every other dispatch is a
     cache hit (the ISSUE acceptance criterion)."""
     eids, X, y, w = _clustered_problem()
-    ds = _dataset(eids, X, y, w, bucketed=True, n_buckets=4)
+    ds = _dataset(eids, X, y, w, bucketed=True)
     assert len(ds.blocks) >= 3  # ≥3 same-bucket blocks (the criterion)
     shapes = {tuple(b.features.shape) for b in ds.blocks}
     assert len(shapes) == 1, "clustered counts must collapse to one bucket"
@@ -123,7 +127,7 @@ def test_exact_shapes_trace_per_block():
     """Without bucketing the same data costs one trace per distinct block
     shape — the regression the cache+bucketing pair exists to prevent."""
     eids, X, y, w = _clustered_problem()
-    ds = _dataset(eids, X, y, w, bucketed=False, n_buckets=4)
+    ds = _dataset(eids, X, y, w, bucketed=False)
     shapes = {tuple(b.features.shape) for b in ds.blocks}
     cache = SolveCache(donate=True)
     coord = _coordinate(ds, cache)
@@ -164,7 +168,7 @@ def test_donation_safety():
     un-donated solve, and a later dispatch must not disturb the first
     result (nothing reads w0 after donation)."""
     eids, X, y, w = _clustered_problem()
-    ds = _dataset(eids, X, y, w, bucketed=True, n_buckets=2)
+    ds = _dataset(eids, X, y, w, bucketed=True)
     block = ds.blocks[0]
     obj = GLMObjective(loss=LogisticLoss, l2_weight=0.5, intercept_index=0)
     spec = OptimizerSpec(optimizer=OptimizerType.NEWTON, max_iter=25, tol=1e-9)
@@ -316,7 +320,7 @@ def test_lru_eviction_bounded_cache():
     from photon_tpu.obs.metrics import registry
 
     eids, X, y, w = _clustered_problem()
-    ds = _dataset(eids, X, y, w, bucketed=True, n_buckets=2)
+    ds = _dataset(eids, X, y, w, bucketed=True)
     block = ds.blocks[0]
     spec = OptimizerSpec(optimizer=OptimizerType.NEWTON, max_iter=10, tol=1e-9)
     cfg = dataclasses.replace(spec.config(), track_history=False)

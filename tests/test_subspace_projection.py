@@ -55,7 +55,7 @@ def _dense_of(indices, values):
 
 def _config(**kw):
     return RandomEffectDataConfig(
-        re_type="userId", feature_shard="wide", n_buckets=2, **kw
+        re_type="userId", feature_shard="wide", **kw
     )
 
 

@@ -112,7 +112,7 @@ def test_random_effect_full_variances_vmapped():
     y = (rng.uniform(size=N) < 0.5).astype(np.float32)
     ds = build_random_effect_dataset(
         users, Xr, y, np.ones(N, np.float32), E,
-        RandomEffectDataConfig(re_type="u", feature_shard="re", n_buckets=1),
+        RandomEffectDataConfig(re_type="u", feature_shard="re"),
     )
     obj = GLMObjective(loss=LogisticLoss, l2_weight=1.0)
     coord = RandomEffectCoordinate(
