@@ -22,7 +22,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,7 +56,7 @@ from photon_tpu.optim.common import (
 )
 from photon_tpu.optim.lbfgs import minimize_lbfgs  # noqa: F401 (TRON/HVP paths)
 from photon_tpu.optim.margin_lbfgs import minimize_lbfgs_margin
-from photon_tpu.optim.newton import minimize_newton
+from photon_tpu.optim.newton import minimize_newton, spd_solve_lowering
 from photon_tpu.optim.tron import minimize_tron
 from photon_tpu.optim.owlqn import minimize_owlqn
 from photon_tpu.optim.factory import OptimizerSpec
@@ -67,7 +67,10 @@ Array = jax.Array
 
 # Widest per-entity dimension for which the default solver forms exact
 # (d, d) Hessians: above this, batched Newton's E·d² HBM footprint and d³
-# Cholesky cost lose to margin-LBFGS's d-linear iterations.
+# Cholesky cost lose to margin-LBFGS's d-linear iterations. Inside it the
+# system is solved by column steps unrolled over the entity axis up to
+# optim/newton.py's SPD_UNROLL_MAX_DIM (in blocks of SPD_UNROLL_MIN_LANES
+# entities and more), by the library's Cholesky above.
 NEWTON_AUTO_MAX_DIM = 128
 
 
@@ -728,22 +731,37 @@ class RandomEffectCoordinate(Coordinate):
                 entries.append((block_c, obj, mask_c, sb, sr))
         return entries
 
+    def _spd_solve_of(self, block: EntityBlock, objective, mask) -> str:
+        """The lowering that solves the block's Newton system, static like
+        the kernel: by the route ``_solve_block`` takes and, on the Newton
+        route, the block's width and lanes. ``"none"`` off it (OWL-QN, TRON
+        and margin-L-BFGS solve no SPD system)."""
+        if not newton_eligible(
+            objective, self.optimizer_spec, block.dim, has_mask=mask is not None
+        ):
+            return "none"
+        return spd_solve_lowering(block.dim, block.num_entities)
+
     def _publish_active_set_stats(
         self, gated: bool, dispatched_valid: int, dispatched_alloc: int,
-        num_dispatches: int,
+        solved_by: Sequence[str],
     ) -> None:
         """Host-int accounting of the pass (no device reads): how many
         entities were re-solved vs skipped, and how much smaller the
         dispatched entity allocation was than a full pass. Whatever the
-        gating, the pass's block solves are counted by the lowering that ran
-        them (``re_block_solves_total``)."""
+        gating, the pass's block solves are counted by the lowerings that ran
+        them (``re_block_solves_total``: ``kernel`` assembled the Newton
+        system, ``spd_solve`` solved it: ``solved_by``, one a dispatched
+        block, from ``_spd_solve_of``)."""
         from photon_tpu.obs.metrics import registry
 
         reg = registry()
         labels = dict(coordinate=self.coordinate_id)
-        reg.counter(
-            "re_block_solves_total", kernel=self._re_kernel, **labels
-        ).inc(num_dispatches)
+        for how in solved_by:
+            reg.counter(
+                "re_block_solves_total", kernel=self._re_kernel,
+                spd_solve=how, **labels,
+            ).inc()
         if not self.active_set:
             self.last_active_set_stats = None
             return
@@ -761,7 +779,7 @@ class RandomEffectCoordinate(Coordinate):
             entities_active=dispatched_valid,
             entities_skipped=skipped,
             entities_quarantined=self._fetched_quarantined,
-            dispatched_blocks=num_dispatches,
+            dispatched_blocks=len(solved_by),
             dispatched_entity_alloc=dispatched_alloc,
             full_entity_alloc=full_alloc,
             compaction_ratio=ratio,
@@ -874,7 +892,7 @@ class RandomEffectCoordinate(Coordinate):
             gated,
             dispatched_valid=int(sum(int(np.sum(sb >= 0)) for *_x, sb, _sr in entries)),
             dispatched_alloc=int(sum(e[0].num_entities for e in entries)),
-            num_dispatches=len(entries),
+            solved_by=[self._spd_solve_of(*e[:3]) for e in entries],
         )
         self._cd_pass += 1
 
@@ -1095,7 +1113,7 @@ class RandomEffectCoordinate(Coordinate):
                 sum(int(np.sum(sb >= 0)) for *_x, sb, _sr in entries)
             ),
             dispatched_alloc=int(sum(e[0].num_entities for e in entries)),
-            num_dispatches=len(entries),
+            solved_by=[self._spd_solve_of(*e[:3]) for e in entries],
         )
         self._cd_pass += 1
         self.last_residency_stats = dict(
@@ -1130,7 +1148,8 @@ class RandomEffectCoordinate(Coordinate):
         tol = self.convergence_tol if self.active_set else None
         parts = []
         pending = []
-        dispatched_valid = dispatched_alloc = num_dispatches = 0
+        dispatched_valid = dispatched_alloc = 0
+        solved_by = []
         block_coefs, block_vars, col_maps, block_offs = [], [], [], []
         # Sync-free dispatch: every block solve is issued before any
         # dependent work (variances) touches the outputs.
@@ -1180,11 +1199,11 @@ class RandomEffectCoordinate(Coordinate):
                 parts.append((block.entity_idx, iters, reasons))
                 dispatched_valid += self._block_valid_counts[i]
                 dispatched_alloc += block.num_entities
-                num_dispatches += 1
+                solved_by.append(self._spd_solve_of(block, obj, mask))
         if tol is not None:
             self._pending_masks = pending
         self._publish_active_set_stats(
-            gated, dispatched_valid, dispatched_alloc, num_dispatches
+            gated, dispatched_valid, dispatched_alloc, solved_by
         )
         self._cd_pass += 1
         if self.compute_variance != VarianceComputationType.NONE:

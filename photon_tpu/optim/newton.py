@@ -8,18 +8,33 @@ second-order option.
 
 TPU-first design: for the random-effect shape (d ≲ 64, thousands of
 entities solved as ONE vmapped program) the right second-order method is
-exact Newton with a batched Cholesky — H = XᵀDX + λI is a tiny (d, d)
-matrix whose assembly is an MXU einsum and whose factorization is cheap,
-while L-BFGS's nested line-search loops dominate wall time on deep
-``lax.while_loop`` nests (each vmapped while iteration costs fixed overhead
-regardless of lane width). Newton converges in 3-5 iterations where L-BFGS
-needs 10+, and each iteration is exactly TWO passes over X (one gradient
-+ Hessian assembly, one trial-point margin refresh) with no inner loops.
+exact Newton — H = XᵀDX + λI is a tiny (d, d) matrix whose assembly is an
+MXU einsum, while L-BFGS's nested line-search loops dominate wall time on
+deep ``lax.while_loop`` nests (each vmapped while iteration costs fixed
+overhead regardless of lane width). Newton converges in 3-5 iterations
+where L-BFGS needs 10+, and each iteration is exactly TWO passes over X
+(one gradient + Hessian assembly, one trial-point margin refresh) with no
+inner loops.
+
+The damped system is solved by Cholesky, and how is chosen by two static
+sizes (``spd_solve``). Up to ``SPD_UNROLL_MAX_DIM`` wide and from
+``SPD_UNROLL_MIN_LANES`` entities in the ``vmap``, the factorisation and
+both triangular solves are ``d`` column steps unrolled in Python, plain
+float32 multiply / subtract / divide / sqrt with the ENTITY axis as the
+minor (lane) axis of every intermediate; else
+``jax.scipy.linalg.cho_factor`` / ``cho_solve``. Under ``vmap`` the library
+call becomes one batched ``Cholesky`` custom call that factorises the
+matrices one after another, each 16 × 16 on a tile of its own: on a TPU v5e
+at ``f32[3072,16,16]`` it took 5.16 ms inside a loop and was 42–50 % of a
+GLMix fit, where the unrolled steps take 0.016 ms; both land 4e-7 to 6e-7
+from a float64 solve (chip runs of PR 32, PERF.md §6).
 
 Damping follows the Levenberg accept/reject pattern (the scalar analogue of
 TRON's trust-region radius update, TRON.scala:93-94): a rejected step keeps
 the iterate and multiplies the damping by 10; an accepted step shrinks it.
-A failed Cholesky (NaNs) lands in the reject branch by construction.
+A failed factorisation (sqrt of a pivot that is not positive: NaN in that
+entity's step, on either lowering) lands in the reject branch by
+construction.
 """
 
 from __future__ import annotations
@@ -28,6 +43,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from photon_tpu.data.batch import LabeledBatch, SparseFeatures
 from photon_tpu.ops.objective import GLMObjective
@@ -46,6 +62,117 @@ _MU_INIT = 0.0  # start with pure Newton; L2'd GLM Hessians are PD
 _MU_BOOST = 10.0
 _MU_SHRINK = 0.25
 _MU_MIN_ON_REJECT = 1e-4  # first reject jumps 0 → 1e-3 (×10 applied after)
+
+# Widest system solved by the unrolled column steps; wider ones take the
+# library's ``cho_factor`` / ``cho_solve``. The steps' program grows with d
+# and every block geometry compiles its own: for the v5e at 3072 lanes the
+# compiler takes 0.9 s at d = 16, 2.6 s at 32 and 12.5 s at 64 (the library
+# call 0.3 s at each), and the gain over the library call shrinks as the
+# rank-one updates outgrow VMEM: 326× at d = 16, 31× at 32, 8.9× at 64
+# (chip runs of PR 32). Every benchmark cell has d = 16.
+SPD_UNROLL_MAX_DIM = 32
+
+# Fewest systems in one ``vmap`` for which the column steps are worth their
+# set-up. Every block geometry's solver program traces and lowers its own
+# copy of them, 0.4 s each on the v5e's host (its Python runs four to five
+# times slower than this repository's sandbox), and set-up has a 10 % bound:
+# the heavy-tailed cell has 19 geometries, ten of them under 128 lanes, and
+# the first fit of its set-up read 27.0–27.5 s without this bound, 22.3–23.4
+# with it and 20.0 at the parent (`setup_s` +20 % and +9 %; one call, warm,
+# PR 32). The library call costs 1.4 µs a matrix: 6 to 110 µs an iteration
+# up to 72 lanes against the steps' 6, 4 ms of a 0.37 s fit (PERF.md §6).
+SPD_UNROLL_MIN_LANES = 128
+
+
+@jax.jit
+def _solve_columns(A: Array, b: Array) -> Array:
+    """Cholesky factorisation and both substitutions of ``A x = b`` as
+    ``d`` unrolled column steps of elementwise float32 arithmetic.
+
+    ``A`` is ``(d, d, lanes)`` and ``b`` ``(d, lanes)``: the last axis holds
+    independent systems and is the minor axis of every intermediate. A
+    pivot that is not positive gives NaN (``sqrt``) in that system's ``x``
+    and in no other. What set-up pays for it, once a solver program (one a
+    block geometry of a plan), is why it is written so: on ``lax`` calls,
+    which trace twice as fast as ``jnp`` operators, and jitted, because
+    ``vmap`` of the Newton loop asks the batching rule below for these
+    ~17·d equations three times a program: the jit traces them once and
+    binds one equation each time (PERF.md §6: 19 programs in the
+    heavy-tailed cell, and set-up has a bound).
+    """
+    d, lanes = A.shape[0], A.shape[2]
+    M = lax.concatenate([A, lax.expand_dims(b, (1,))], 1)  # (d, d + 1, lanes)
+    one = lax.full((1, 1, lanes), 1.0, A.dtype)
+    cols, ys, rinvs = [], [], []
+    for j in range(d):
+        # Right-looking, as LAPACK's potf2. What is left of the system is
+        # symmetric, so its first ROW scaled by 1/sqrt(pivot) is column j
+        # of L below the diagonal, and its last entry is y_j of y = L⁻¹b.
+        m = d - j
+        rinv = lax.div(one, lax.sqrt(lax.slice(M, (0, 0, 0), (1, 1, lanes))))
+        row = lax.mul(lax.slice(M, (0, 1, 0), (1, m + 1, lanes)), rinv)
+        rinvs.append(rinv)
+        ys.append(lax.slice(row, (0, m - 1, 0), (1, m, lanes)))
+        if m > 1:
+            col = lax.slice(row, (0, 0, 0), (1, m - 1, lanes))  # L[j+1:, j]
+            cols.append(col)
+            rank_one = lax.mul(lax.reshape(col, (m - 1, 1, lanes)), row)
+            M = lax.sub(lax.slice(M, (1, 1, 0), (m, m + 1, lanes)), rank_one)
+    x = lax.mul(ys[-1], rinvs[-1])  # entries j.. of the solution of Lᵀx = y
+    for j in reversed(range(d - 1)):
+        dot = lax.expand_dims(
+            lax.reduce_sum(lax.mul(cols[j], x), axes=(1,)), (1,)
+        )
+        x = lax.concatenate([lax.mul(lax.sub(ys[j], dot), rinvs[j]), x], 1)
+    return lax.squeeze(x, (0,))
+
+
+def _solve_library(A: Array, b: Array) -> Array:
+    chol, _ = jax.scipy.linalg.cho_factor(A, lower=True)
+    return jax.scipy.linalg.cho_solve((chol, True), b)
+
+
+@jax.custom_batching.custom_vmap
+def _solve_by_lanes(A: Array, b: Array) -> Array:
+    return _solve_library(A, b)  # one system: no lanes to lay it along
+
+
+@_solve_by_lanes.def_vmap
+def _solve_by_lanes_vmap(axis_size, in_batched, A, b):
+    """``vmap`` of enough systems puts the batch axis LAST: a TPU tiles an
+    array's two minor axes, so ``(E, d, d)`` leaves a 16 × 16 matrix on a
+    tile of its own and the entities one after another, where ``(d, d, E)``
+    lays the entities along the lanes. An outer ``vmap`` of this
+    (``batched_tuning`` maps λ over the entity map) puts its axis in front
+    of every step, so the lanes stay minor."""
+    if spd_solve_lowering(A.shape[-1], axis_size) == "library":
+        in_axes = tuple(0 if batched else None for batched in in_batched)
+        return jax.vmap(_solve_library, in_axes)(A, b), True
+
+    def last(x, batched):
+        if batched:
+            return jnp.moveaxis(x, 0, -1)
+        return jnp.broadcast_to(x[..., None], x.shape + (axis_size,))
+
+    x = _solve_columns(last(A, in_batched[0]), last(b, in_batched[1]))
+    return jnp.moveaxis(x, -1, 0), True
+
+
+def spd_solve(A: Array, b: Array) -> Array:
+    """Solve the symmetric positive definite ``(d, d)`` system ``A x = b``
+    by Cholesky, on the lowering the static ``d`` and the ``vmap``'s size
+    choose (``spd_solve_lowering``). Not positive definite: NaN in ``x``."""
+    if A.shape[0] > SPD_UNROLL_MAX_DIM:
+        return _solve_library(A, b)
+    return _solve_by_lanes(A, b)
+
+
+def spd_solve_lowering(d: int, lanes: int) -> str:
+    """``"unrolled"`` for ``lanes`` systems of width ``d`` under one
+    ``vmap`` up to ``SPD_UNROLL_MAX_DIM`` and from ``SPD_UNROLL_MIN_LANES``,
+    else ``"library"``."""
+    unrolled = d <= SPD_UNROLL_MAX_DIM and lanes >= SPD_UNROLL_MIN_LANES
+    return "unrolled" if unrolled else "library"
 
 
 def minimize_newton(
@@ -169,13 +296,12 @@ def minimize_newton(
         # The diagonal is floored at a tiny fraction of its largest entry so
         # a feature column with no active samples (H_jj = 0, arises when
         # l2 = 0) still becomes positive-definite under damping instead of
-        # failing Cholesky forever — the dead direction then gets step
-        # p_j = −g_j/(μ·floor) = 0 since g_j = 0 too.
+        # failing the factorisation forever — the dead direction then gets
+        # step p_j = −g_j/(μ·floor) = 0 since g_j = 0 too.
         diag_h = jnp.diagonal(H)
         floor = 1e-7 * jnp.maximum(jnp.max(diag_h), 1.0)
         Hd = H + st["mu"] * jnp.diag(jnp.maximum(diag_h, floor))
-        chol, _ = jax.scipy.linalg.cho_factor(Hd, lower=True)
-        p = -jax.scipy.linalg.cho_solve((chol, True), g)
+        p = -spd_solve(Hd, g)
 
         # --- pass 2: trial margins, then FREE backtracking on margins ---
         # Margins are affine in the step: z(w + t·p) = z + t·u with
@@ -193,7 +319,7 @@ def minimize_newton(
             return data_value(z + t * u) + l2_value(w + t * p)
 
         fs = jax.vmap(f_at)(ts)
-        fs = jnp.where(jnp.isnan(fs), jnp.inf, fs)  # failed Cholesky → reject
+        fs = jnp.where(jnp.isnan(fs), jnp.inf, fs)  # failed solve → reject
         ib = jnp.argmin(fs)
         f_best, t_best = fs[ib], ts[ib]
         # <= so ties at f32 resolution near the optimum still step (the

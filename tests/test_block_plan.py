@@ -218,9 +218,12 @@ def test_fit_on_zipf_users_matches_the_ragged_reference():
 
     xf, xr, ids, y = _zipf_data()
     reset_tracer()
-    solves = registry().counter(
-        "re_block_solves_total", kernel="xla", coordinate="per_user")
-    solves_before = solves.value
+    def solves():  # 64 users: every block is under the lanes' bound
+        return registry().counter(
+            "re_block_solves_total", kernel="xla", spd_solve="library",
+            coordinate="per_user").value
+
+    solves_before = solves()
     result, batch = _fit(xf, xr, ids, y, 64)
     ref = glmix_ragged.fit(
         REFERENCE_CONFIG, jnp.asarray(xf), {"per_user": jnp.asarray(xr)},
@@ -250,7 +253,7 @@ def test_fit_on_zipf_users_matches_the_ragged_reference():
     ds = _dataset(ids, xr, y, 64)
     assert gauges["re_blocks"] == len(ds.blocks)
     # one dispatch a block and pass: the counter over the passes is the gauge
-    assert solves.value - solves_before == 2 * len(ds.blocks)
+    assert solves() - solves_before == 2 * len(ds.blocks)
     assert gauges["re_block_geometries"] == len(
         {b.features.shape for b in ds.blocks})
     # The tracker weighs iterations by rows where it knows them.

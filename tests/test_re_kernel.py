@@ -120,44 +120,64 @@ def test_resolve_re_kernel(on_tpu, monkeypatch):
 def test_default_coordinate_counts_block_solves_by_kernel():
     """A coordinate built with the default ``re_kernel`` runs the XLA
     lowering, and ``re_block_solves_total`` says so: one count a dispatched
-    block, under the kernel that solved it."""
+    block, under the kernel that assembled its Newton system and the
+    lowering that solved it (by the block's width and lanes)."""
     from photon_tpu.algorithm.random_effect import RandomEffectCoordinate
     from photon_tpu.data.game_data import GameBatch
     from photon_tpu.obs.metrics import registry
     from photon_tpu.types import TaskType
 
-    ds, n = _workload(seed=8)
+    ds, n = _workload(seed=8, E=400)
     batch = GameBatch(
         label=jnp.zeros(n, jnp.float32), offset=jnp.zeros(n, jnp.float32),
         weight=jnp.ones(n, jnp.float32), features={}, entity_ids={},
     )
 
-    def coordinate(cid, **kw):
+    def coordinate(cid, optimizer=OptimizerType.NEWTON, **kw):
         return RandomEffectCoordinate(
             coordinate_id=cid, dataset=ds, task=TaskType.LOGISTIC_REGRESSION,
             objective=GLMObjective(loss=LogisticLoss, l2_weight=1.0),
             optimizer_spec=OptimizerSpec(
-                optimizer=OptimizerType.NEWTON, max_iter=5, tol=1e-6
+                optimizer=optimizer, max_iter=5, tol=1e-6
             ),
             solve_cache=SolveCache(donate=False), **kw,
         )
 
     def solves(cid):
         return {
-            s["labels"]["kernel"]: s["value"] for s in registry().snapshot()
+            (s["labels"]["kernel"], s["labels"]["spd_solve"]): s["value"]
+            for s in registry().snapshot()
             if s["metric"] == "re_block_solves_total"
             and s["labels"]["coordinate"] == cid
         }
+
+    from collections import Counter
+
+    from photon_tpu.optim.newton import spd_solve_lowering
+
+    # by the block's width and lanes: 400 users in two clusters of counts
+    by_solve = Counter(
+        spd_solve_lowering(b.dim, b.num_entities) for b in ds.blocks
+    )
+    assert by_solve["unrolled"] >= 1
 
     default = coordinate("counted_default")
     assert default.re_kernel == "auto"
     model = None
     for _ in range(2):
         model, _stats = default.train(batch, None, model)
-    assert solves("counted_default") == {"xla": 2 * len(ds.blocks)}
+    assert solves("counted_default") == {
+        ("xla", how): 2 * n for how, n in by_solve.items()
+    }
 
     coordinate("counted_pallas", re_kernel="pallas").train(batch, None, None)
-    assert solves("counted_pallas") == {"pallas": len(ds.blocks)}
+    assert solves("counted_pallas") == {
+        ("pallas", how): n for how, n in by_solve.items()
+    }
+
+    # Off the Newton route no SPD system is solved, whatever the sizes.
+    coordinate("counted_tron", OptimizerType.TRON).train(batch, None, None)
+    assert solves("counted_tron") == {("xla", "none"): len(ds.blocks)}
 
 
 def test_fused_newton_system_bitexact_unbatched_and_vmapped():

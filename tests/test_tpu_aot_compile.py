@@ -1,4 +1,5 @@
-"""Every ``pallas_call``, compiled ahead of time for a TPU v5e — on the CPU.
+"""Every ``pallas_call`` and the Newton loop's SPD solve, compiled ahead of
+time for a TPU v5e — on the CPU.
 
 ``jax.experimental.topologies.get_topology_desc`` describes a v5e:2x2 host
 with no chip attached, and lowering against a ``ShapeDtypeStruct`` placed
@@ -24,6 +25,7 @@ from photon_tpu.ops.pallas_glm import (
     fused_data_value_and_grad,
 )
 from photon_tpu.ops.pallas_newton import fused_newton_system
+from photon_tpu.optim.newton import spd_solve, spd_solve_lowering
 
 # bench.py's headline shapes: N = 2^21 rows, d = 256; E = 4096 entities of
 # 512 rows, d_re = 16.
@@ -104,3 +106,25 @@ def test_random_effect_kernel_compiles_for_v5e(v5e, e, n_max, d_re, dtype):
             X, d2, dz, interpret=False)),
         v5e, ((e, n_max, d_re), dtype), ((e, n_max), f32), ((e, n_max), f32),
     )
+
+
+@pytest.mark.parametrize("lanes", [3072, 128, 1])
+def test_spd_solve_compiles_for_v5e_without_the_cholesky_call(v5e, lanes):
+    """The RE Newton system at d_re = 16 under the entity ``vmap``: from 128
+    lanes the compiled program is the unrolled column steps (no Mosaic, no
+    library ``Cholesky`` custom call), the entities on the minor axis; a
+    block of one lane keeps the library call."""
+    f32 = jnp.float32
+    args = [
+        jax.ShapeDtypeStruct(shape, f32, sharding=v5e)
+        for shape in ((lanes, D_RE, D_RE), (lanes, D_RE))
+    ]
+    text = jax.jit(jax.vmap(spd_solve)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" not in text  # no Mosaic kernel
+    if spd_solve_lowering(D_RE, lanes) == "library":
+        assert 'custom_call_target="Cholesky"' in text
+        return
+    assert 'custom_call_target="Cholesky"' not in text
+    # The rank-one updates run at (d - j, d - j + 1, lanes), the entity
+    # axis minor in the device layout.
+    assert f"f32[{D_RE - 1},{D_RE},{lanes}]{{2,1,0" in text
