@@ -783,154 +783,6 @@ def run_fe_bandwidth_ab():
     return out
 
 
-def run_re_kernel_ab(passes: int = 4):
-    """Batched small-GLM RE kernel A/B (--re-kernel-ab), four variants of
-    the same clustered-entity CD workload:
-
-      xla_unmerged   — seed behavior: one dispatch per planned block
-      xla_merged     — merge_same_geometry_blocks collapses same-(n,d)
-                       blocks into one dispatch (real CPU wall win)
-      pallas         — fused Newton-system kernel on the SAME merged
-                       layout; coefficients asserted BIT-EQUAL to
-                       xla_merged (the parity acceptance criterion)
-      pallas_bf16x   — bf16 X read, f32 accumulate; pinned tolerance
-
-    Reports the dispatch-count collapse (solver calls per pass), the
-    per-pass RE wall ratio, and zero post-warmup retraces for every
-    variant. Merged-vs-unmerged coefficients agree at solver tolerance
-    (NOT bitwise — lane count changes XLA's whole-program fusion order;
-    see data/random_effect.merge_same_geometry_blocks). Off-TPU the
-    pallas walls are interpret-mode and flagged."""
-    import jax
-    import jax.numpy as jnp
-
-    from photon_tpu.algorithm.random_effect import RandomEffectCoordinate
-    from photon_tpu.algorithm.solve_cache import SolveCache
-    from photon_tpu.data.game_data import GameBatch
-    from photon_tpu.data.random_effect import (
-        RandomEffectDataConfig,
-        build_random_effect_dataset,
-    )
-    from photon_tpu.ops.losses import LogisticLoss
-    from photon_tpu.ops.objective import GLMObjective
-    from photon_tpu.optim.factory import OptimizerSpec
-    from photon_tpu.types import OptimizerType, TaskType
-
-    rng = np.random.default_rng(17)
-    E_ab, d_ab = 360, 8
-    # Two size clusters; under ``make_ds``'s slab budget the block plan cuts
-    # the larger cluster's grid level into three blocks of ONE (n_max, d)
-    # geometry — the merge target.
-    counts = np.where(
-        rng.uniform(size=E_ab) < 0.5,
-        rng.integers(5, 9, size=E_ab),
-        rng.integers(30, 44, size=E_ab),
-    ).astype(int)
-    users = np.repeat(np.arange(E_ab, dtype=np.int32), counts)
-    n = users.size
-    Xr = rng.normal(size=(n, d_ab)).astype(np.float32)
-    Xr[:, 0] = 1.0
-    y = (rng.uniform(size=n) < 0.5).astype(np.float32)
-    w = np.ones(n, np.float32)
-    batch = GameBatch(
-        label=jnp.asarray(y),
-        offset=jnp.zeros(n, jnp.float32),
-        weight=jnp.asarray(w),
-        features={"re": jnp.asarray(Xr)},
-        entity_ids={"userId": jnp.asarray(users)},
-    )
-
-    def make_ds(merge):
-        return build_random_effect_dataset(
-            users, Xr, y, w, E_ab,
-            RandomEffectDataConfig(
-                re_type="userId", feature_shard="re",
-                shape_bucketing=True, subspace_projection=False,
-                merge_same_geometry=merge,
-            ),
-            slab_budget=48 * 48 * d_ab * 4,
-        )
-
-    ds_plain, ds_merged = make_ds(False), make_ds(True)
-
-    def run_variant(ds, re_kernel):
-        cache = SolveCache(donate=True)
-        coord = RandomEffectCoordinate(
-            coordinate_id="per_user", dataset=ds,
-            task=TaskType.LOGISTIC_REGRESSION,
-            # Fully regularized (no free intercept direction): entities
-            # with all-equal labels stay bounded and converge inside
-            # max_iter, so the bf16 comparison measures rounding, not the
-            # trajectory of a non-converged separable solve.
-            objective=GLMObjective(loss=LogisticLoss, l2_weight=0.5),
-            optimizer_spec=OptimizerSpec(
-                optimizer=OptimizerType.NEWTON, max_iter=25, tol=1e-8
-            ),
-            solve_cache=cache,
-            re_kernel=re_kernel,
-        )
-        model, wall, traces_warm = None, [], None
-        for i in range(passes):
-            t0 = time.perf_counter()
-            model, _stats = coord.train(batch, None, model)
-            jax.block_until_ready(model.coefficients)
-            wall.append(time.perf_counter() - t0)
-            if i == 0:
-                traces_warm = cache.stats.traces
-        return dict(
-            coef=np.asarray(model.coefficients),
-            calls_per_pass=cache.stats.calls // passes,
-            traces=cache.stats.traces,
-            post_warmup_retraces=cache.stats.traces - traces_warm,
-            blocks=len(ds.blocks),
-            first_pass_s=round(wall[0], 4),
-            steady_pass_s=round(min(wall[1:]), 4),
-        )
-
-    _progress("re-kernel A/B: xla unmerged (seed layout)")
-    a = run_variant(ds_plain, "xla")
-    _progress("re-kernel A/B: xla merged")
-    b = run_variant(ds_merged, "xla")
-    _progress("re-kernel A/B: pallas fused (merged layout)")
-    c = run_variant(ds_merged, "pallas")
-    _progress("re-kernel A/B: pallas bf16-X (merged layout)")
-    e = run_variant(ds_merged, "pallas_bf16x")
-
-    # The parity acceptance criterion: fused kernel vs XLA on the SAME
-    # layout is bit-for-bit.
-    pallas_bitexact = bool(np.array_equal(c["coef"], b["coef"]))
-    assert pallas_bitexact, (
-        "pallas re_kernel must be bit-exact vs xla on an identical layout"
-    )
-    bf16_max_abs = float(np.max(np.abs(e["coef"] - b["coef"])))
-    assert bf16_max_abs < 5e-3, bf16_max_abs
-    merged_vs_unmerged_max_abs = float(np.max(np.abs(b["coef"] - a["coef"])))
-    assert np.allclose(b["coef"], a["coef"], rtol=2e-3, atol=1e-5)
-    for v in (a, b, c, e):
-        assert v["post_warmup_retraces"] == 0, v
-
-    on_tpu = jax.default_backend() == "tpu"
-    strip = lambda v: {k: x for k, x in v.items() if k != "coef"}  # noqa: E731
-    return dict(
-        metric="re_kernel_ab",
-        value=round(a["calls_per_pass"] / max(b["calls_per_pass"], 1), 2),
-        unit="dispatch_collapse_x",
-        cd_passes=passes,
-        backend=jax.default_backend(),
-        xla_unmerged=strip(a),
-        xla_merged=strip(b),
-        pallas=strip(c),
-        pallas_bf16x=strip(e),
-        re_wall_ratio_merged_vs_unmerged=round(
-            b["steady_pass_s"] / max(a["steady_pass_s"], 1e-9), 3),
-        pallas_bitexact_vs_xla_same_layout=pallas_bitexact,
-        bf16x_max_abs_vs_xla=bf16_max_abs,
-        merged_vs_unmerged_max_abs=merged_vs_unmerged_max_abs,
-        interpret_walls_not_comparable=not on_tpu,
-        on_chip="not measured (pallas walls are interpret-mode)",
-    )
-
-
 def run_active_set_ab(passes: int = 5):
     """Gated-vs-full A/B for convergence-gated active-set random-effect
     passes (algorithm/random_effect.py): a two-coordinate (fixed effect +
@@ -7007,9 +6859,6 @@ def main():
         return
     if "--fe-bandwidth-ab" in sys.argv:
         print(json.dumps(run_fe_bandwidth_ab()))
-        return
-    if "--re-kernel-ab" in sys.argv:
-        print(json.dumps(run_re_kernel_ab()))
         return
     if "--rmatvec-cpu-ab" in sys.argv:
         # Four sparse-rmatvec lowerings head-to-head at CPU-mesh scale

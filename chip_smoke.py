@@ -600,16 +600,19 @@ def _auc(scores: np.ndarray, labels: np.ndarray) -> float:
     return float((ranks[pos].sum() - n_pos * (n_pos + 1) / 2) / (n_pos * n_neg))
 
 
-def make_glmix_arrays(n: int, d_fix: int, d_re: int, E: int, seed: int):
+def make_glmix_arrays(n: int, d_fix: int, d_re: int, E: int, seed: int,
+                      users=None):
     """bench.py's headline shape (N × d_fix fixed effect, d_re per-user
     random effect over E users, intercepts in column 0), with a real user
-    effect so the random-effect coordinate has something to find."""
+    effect so the random-effect coordinate has something to find. ``users``
+    given: each row's user, in place of the uniform draw."""
     rng = np.random.default_rng(seed)
     Xf = rng.standard_normal(size=(n, d_fix), dtype=np.float32)
     Xf[:, 0] = 1.0
     Xr = rng.standard_normal(size=(n, d_re), dtype=np.float32)
     Xr[:, 0] = 1.0
-    users = rng.integers(0, E, size=n).astype(np.int32)
+    drawn = rng.integers(0, E, size=n).astype(np.int32)
+    users = drawn if users is None else users
     w_fix = (rng.normal(size=d_fix) / np.sqrt(d_fix)).astype(np.float32)
     w_user = rng.normal(scale=0.5, size=(E, d_re)).astype(np.float32)
     logits = Xf @ w_fix + np.einsum("nd,nd->n", Xr, w_user[users])
@@ -866,18 +869,17 @@ def child_kernels() -> dict:
     from photon_tpu.ops.pallas_glm import (
         fused_data_hvp, fused_data_value_and_grad,
     )
-    from photon_tpu.ops.pallas_newton import fused_newton_system
     from photon_tpu.optim.common import OptimizerConfig
     from photon_tpu.parallel.train_step import glmix_train_step
 
     HIGHEST = jax.lax.Precision.HIGHEST
     s = SIZES
     n, d, d_re, E = s["kernel_n"], s["d_fix"], s["d_re"], s["entities"]
-    Xf, Xr, _users, y = make_glmix_arrays(n, d, d_re, E, SEED + 2)
     # n / E rows a user exactly: one level of the block plan's grid, so the
-    # dataset is the ONE block the Newton system and the fused step take.
+    # dataset is the ONE block the fused step takes.
     users = np.random.default_rng(SEED + 4).permutation(
         np.arange(n, dtype=np.int32) % E)
+    Xf, Xr, users, y = make_glmix_arrays(n, d, d_re, E, SEED + 2, users=users)
     rng = np.random.default_rng(SEED + 3)
     w = (rng.normal(size=d) / np.sqrt(d)).astype(np.float32)
     v = rng.normal(size=d).astype(np.float32)
@@ -973,37 +975,12 @@ def child_kernels() -> dict:
                 xla_default_err_vs_highest=_rel_err(hv_x, hv_r))
         del Xr32
 
-    # --- random-effect Newton system, one grid instance an entity
+    # --- one fused GLMix step on bf16 X: what the benchmark times first
     ds = build_random_effect_dataset(
         users, Xr, y, np.ones(n, np.float32), E,
         RandomEffectDataConfig(re_type="userId", feature_shard="re"),
     )
     (block,) = ds.blocks
-    Xb = jnp.asarray(block.features)  # (E, n_max, d_re)
-    zb = jnp.einsum("end,d->en", Xb, jnp.asarray(w[:d_re]), precision=HIGHEST)
-    d2b = block.weight * LogisticLoss.dzz(zb, block.label)
-    dzb = block.weight * LogisticLoss.dz(zb, block.label)
-    for name, Xk in (("f32", Xb), ("bf16", Xb.astype(jnp.bfloat16))):
-        tol = TOL["kernel_f32" if name == "f32" else "kernel_bf16"]
-        X_read = Xk.astype(jnp.float32)
-        H_r = jnp.einsum("end,en,enf->edf", X_read, d2b, X_read,
-                         precision=HIGHEST)
-        g_r = jnp.einsum("end,en->ed", X_read, dzb, precision=HIGHEST)
-        H_x = jnp.einsum("end,en,enf->edf", X_read, d2b, X_read)
-        fn = jax.vmap(fused_newton_system)
-        _not_interpreted(fn, Xk, d2b, dzb)
-        compiled = jax.jit(fn).lower(Xk, d2b, dzb).compile()
-        temp = compiled.memory_analysis().temp_size_in_bytes
-        H, g = compiled(Xk, d2b, dzb)
-        compare(
-            f"newton_system[{name}]",
-            dict(H=_rel_err(H, H_r), g=_rel_err(g, g_r)), tol,
-            shape=list(Xk.shape), temp_bytes=int(temp),
-            xla_default_err_vs_highest=dict(H=_rel_err(H_x, H_r)),
-        )
-    results["newton_system_peak_bytes_in_use"] = _peak_bytes()
-
-    # --- one fused GLMix step on bf16 X: what the benchmark times first
     fe_cfg = OptimizerConfig(max_iter=30, track_history=False)
     re_cfg = OptimizerConfig(max_iter=8, tol=1e-6, track_history=False)
     re_obj = GLMObjective(loss=LogisticLoss, l2_weight=1.0, intercept_index=0)

@@ -669,13 +669,14 @@ run_experiments() {
 }
 
 run_kernels() {
-    # Kernel-surface smoke: interpret-mode parity for both Pallas kernel
-    # families (FE fused value+grad/HVP, RE batched Newton system), every
-    # pallas_call through the real TPU compiler ahead of time, and a
-    # dead-code gate — the round-4 FE A/B DELETED the losing lowerings, so
-    # their per-call tile_n override must stay gone from the public
-    # signatures (no quietly resurrected code paths in ops/pallas_glm.py).
-    echo "== kernels: FE/RE Pallas parity smokes + deleted-lowering gate =="
+    # Kernel-surface smoke: interpret-mode parity for the FE Pallas kernels
+    # (fused value+grad/HVP), the RE block solve's zero-retrace discipline,
+    # every pallas_call and the RE block solve through the real TPU compiler
+    # ahead of time, and a dead-code gate — the round-4 FE A/B DELETED the
+    # losing lowerings, so their per-call tile_n override must stay gone from
+    # the public signatures (no quietly resurrected code paths in
+    # ops/pallas_glm.py).
+    echo "== kernels: FE Pallas parity smoke + deleted-lowering gate =="
     JAX_PLATFORMS=cpu python - <<'EOF'
 import inspect
 
@@ -683,7 +684,6 @@ from photon_tpu.ops.pallas_glm import (
     fused_data_hvp,
     fused_data_value_and_grad,
 )
-from photon_tpu.ops.pallas_newton import fused_newton_system
 
 for fn in (fused_data_value_and_grad, fused_data_hvp):
     params = inspect.signature(fn).parameters
@@ -695,10 +695,7 @@ print("   deleted-lowering gate OK (no tile_n in public signatures)")
 EOF
     JAX_PLATFORMS=cpu python -m pytest -q -p no:cacheprovider \
         tests/test_pallas_glm.py \
-        tests/test_re_kernel.py::test_fused_newton_system_bitexact_unbatched_and_vmapped \
-        "tests/test_re_kernel.py::test_solve_block_pallas_bitexact_mixed_geometries[False]" \
-        tests/test_re_kernel.py::test_solve_block_bf16x_pinned_tolerance \
-        tests/test_re_kernel.py::test_zero_post_warmup_retraces \
+        tests/test_re_block_solve.py::test_zero_post_warmup_retraces \
         tests/test_tpu_aot_compile.py
     echo "   kernels smoke OK"
 }

@@ -205,25 +205,17 @@ def _solve_block(
     spec: OptimizerSpec,
     config: OptimizerConfig,
     feature_mask: Optional[Array] = None,  # (E, d) 0/1 Pearson mask
-    re_kernel: str = "xla",
 ):
     """vmap one optimizer over all entities of a block. Returns (E, d) coefs +
     per-entity (iterations, reason) for the tracker.
 
-    Solver routing (one production path — the same program bench.py measures):
+    Solver routing (one production path):
     L1 → OWL-QN; explicit TRON honored; otherwise smooth unmasked problems at
     random-effect widths (d ≤ NEWTON_AUTO_MAX_DIM) run batched damped Newton
     (optim/newton.py — 3-5 iterations of MXU Hessian assembly + Cholesky,
     vs the reference's per-entity Breeze L-BFGS inside mapValues,
     RandomEffectCoordinate.scala:228-283), with margin-space L-BFGS as the
     wide-d / feature-masked / shift-normalized fallback.
-
-    ``re_kernel`` (already resolved, never "auto") selects the Newton-system
-    assembly lowering for the Newton route only — "pallas"/"pallas_bf16x"
-    fuse the Hessian + gradient reductions into one Pallas read of each
-    entity's slab, batched by this function's vmap into one grid instance
-    per block row (ops/pallas_newton). Non-Newton routes (OWL-QN, TRON,
-    margin-L-BFGS fallbacks) ignore it.
     """
     use_newton = newton_eligible(
         objective, spec, block.dim, has_mask=feature_mask is not None
@@ -261,7 +253,7 @@ def _solve_block(
                 l1_mask = jnp.ones_like(w_init).at[objective.intercept_index].set(0.0)
             res = minimize_owlqn(vg, w_start, objective.l1_weight, config, l1_mask)
         elif use_newton:
-            res = minimize_newton(objective, lb, w_start, config, kernel=re_kernel)
+            res = minimize_newton(objective, lb, w_start, config)
         elif spec.optimizer == OptimizerType.TRON:
             res = minimize_tron(
                 vg, None, w_start, config, spec.max_cg_iter,
@@ -386,14 +378,6 @@ class RandomEffectCoordinate(Coordinate):
     # ``<device_spill_dir>/host-<k>/`` (re_store.partition_spill_dir) so a
     # ring rebalance moves files instead of re-streaming rows.
     device_spill_member: Optional[str] = None
-    # Newton-system assembly lowering for the per-entity solves
-    # (ops/pallas_newton.RE_KERNELS): "auto" is "xla", the two-read einsum
-    # lowering, on every backend (on the v5e it fits 2.4x faster than the
-    # kernel and nearer the float32 reference: PERF.md §6, PR 28); "pallas" /
-    # "pallas_bf16x" opt into the fused kernel (interpret mode off-TPU — the
-    # CPU parity/bench path).
-    # Part of the solver-cache key, so variants never share executables.
-    re_kernel: str = "auto"
     # Device placement for the entity-sharded multi-device path
     # (parallel/entity_shard.py): commit this coordinate's blocks,
     # coefficients, and solves to ONE device. The solve cache needs no
@@ -405,9 +389,6 @@ class RandomEffectCoordinate(Coordinate):
 
     def __post_init__(self):
         self.compute_variance = normalize_variance_type(self.compute_variance)
-        from photon_tpu.ops.pallas_newton import resolve_re_kernel
-
-        self._re_kernel = resolve_re_kernel(self.re_kernel)
         if self.solve_cache is None:
             self.solve_cache = default_cache()
         # Per-entity solves keep only aggregate tracker stats (HBM budget).
@@ -732,9 +713,9 @@ class RandomEffectCoordinate(Coordinate):
         return entries
 
     def _spd_solve_of(self, block: EntityBlock, objective, mask) -> str:
-        """The lowering that solves the block's Newton system, static like
-        the kernel: by the route ``_solve_block`` takes and, on the Newton
-        route, the block's width and lanes. ``"none"`` off it (OWL-QN, TRON
+        """The lowering that solves the block's Newton system, static: by
+        the route ``_solve_block`` takes and, on the Newton route, the
+        block's width and lanes. ``"none"`` off it (OWL-QN, TRON
         and margin-L-BFGS solve no SPD system)."""
         if not newton_eligible(
             objective, self.optimizer_spec, block.dim, has_mask=mask is not None
@@ -749,19 +730,16 @@ class RandomEffectCoordinate(Coordinate):
         """Host-int accounting of the pass (no device reads): how many
         entities were re-solved vs skipped, and how much smaller the
         dispatched entity allocation was than a full pass. Whatever the
-        gating, the pass's block solves are counted by the lowerings that ran
-        them (``re_block_solves_total``: ``kernel`` assembled the Newton
-        system, ``spd_solve`` solved it: ``solved_by``, one a dispatched
-        block, from ``_spd_solve_of``)."""
+        gating, the pass's block solves are counted by the lowering that
+        solved their Newton systems (``re_block_solves_total``'s
+        ``spd_solve``: ``solved_by``, one a dispatched block, from
+        ``_spd_solve_of``)."""
         from photon_tpu.obs.metrics import registry
 
         reg = registry()
         labels = dict(coordinate=self.coordinate_id)
         for how in solved_by:
-            reg.counter(
-                "re_block_solves_total", kernel=self._re_kernel,
-                spd_solve=how, **labels,
-            ).inc()
+            reg.counter("re_block_solves_total", spd_solve=how, **labels).inc()
         if not self.active_set:
             self.last_active_set_stats = None
             return
@@ -867,7 +845,6 @@ class RandomEffectCoordinate(Coordinate):
                 solver = self.solve_cache.block_solver(
                     obj, self.optimizer_spec, self._config,
                     has_mask=mask is not None, convergence_tol=tol,
-                    re_kernel=self._re_kernel,
                 )
                 if gated and self.solve_cache.max_entries is None:
                     # Compacted shapes were all compiled during the full
@@ -1079,7 +1056,6 @@ class RandomEffectCoordinate(Coordinate):
                     solver = self.solve_cache.block_solver(
                         obj, self.optimizer_spec, self._config,
                         has_mask=mask is not None, convergence_tol=tol,
-                        re_kernel=self._re_kernel,
                     )
                     store.mark_solve_start()
                     if gated and self.solve_cache.max_entries is None:
@@ -1174,7 +1150,6 @@ class RandomEffectCoordinate(Coordinate):
                 solver = self.solve_cache.block_solver(
                     obj, self.optimizer_spec, self._config,
                     has_mask=mask is not None, convergence_tol=tol,
-                    re_kernel=self._re_kernel,
                 )
                 if gated and self.solve_cache.max_entries is None:
                     with self.solve_cache.expect_cached(

@@ -139,7 +139,6 @@ class ShardedRandomEffectCoordinate(Coordinate):
         convergence_tol: float = 1e-4,
         device_budget_bytes: Optional[int] = None,  # PER SHARD
         device_spill_dir: Optional[str] = None,
-        re_kernel: str = "auto",
     ) -> "ShardedRandomEffectCoordinate":
         """Shard the flat sample arrays by entity owner and build one
         per-device sub-coordinate per shard.
@@ -196,7 +195,6 @@ class ShardedRandomEffectCoordinate(Coordinate):
                     device_spill_member=(
                         s if device_spill_dir is not None else None
                     ),
-                    re_kernel=re_kernel,
                     device=dev,
                 )
             )

@@ -221,7 +221,6 @@ class SolveCache:
     def block_solver(
         self, objective, spec, config, has_mask: bool,
         convergence_tol: Optional[float] = None,
-        re_kernel: str = "xla",
     ) -> Callable[..., Tuple[Array, Array, Array]]:
         """Jitted ``_solve_block`` executable for one static configuration.
 
@@ -249,10 +248,6 @@ class SolveCache:
         """
         has_mask = bool(has_mask)
         tol = None if convergence_tol is None else float(convergence_tol)
-        # ``re_kernel`` (resolved — never "auto") is part of the key: the
-        # Newton-system lowering changes the traced program, so XLA and
-        # fused-Pallas dispatches must never share an executable.
-        re_kernel = str(re_kernel)
         key = (
             "block",
             self._objective_key(objective),
@@ -260,7 +255,6 @@ class SolveCache:
             self._config_key(config),
             has_mask,
             tol,
-            re_kernel,
         )
 
         def build():
@@ -275,8 +269,7 @@ class SolveCache:
                     ("block",) + tuple(block.features.shape) + (has_mask,)
                 )
                 w, iterations, reasons = _solve_block(
-                    block, offsets, w0, objective, spec, config, feature_mask,
-                    re_kernel=re_kernel,
+                    block, offsets, w0, objective, spec, config, feature_mask
                 )
                 # Per-entity divergence quarantine, fully in-trace: a row
                 # whose solve went non-finite keeps its warm start and is

@@ -29,7 +29,7 @@ produces dense blocks:
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -235,16 +235,6 @@ class RandomEffectDataConfig:
     # projector per entity). None = auto: on for sparse shard input, off for
     # dense. Blocks store a ``col_map`` back to the global feature space.
     subspace_projection: Optional[bool] = None
-    # Collapse dense blocks sharing an (n_max, d) geometry into one block
-    # each (``merge_same_geometry_blocks``) — fewer solver dispatches per CD
-    # pass at identical convergence. Opt-in: merged lane counts change XLA's
-    # whole-program fusion order inside the vmapped Newton solve, so results
-    # match the unmerged layout to solver tolerance, not bit-for-bit (the
-    # re_kernel pallas-vs-xla parity, which IS bit-exact, is a separate
-    # axis — it holds on whichever layout is selected here). Under the block
-    # plan two dense blocks share a geometry only where a ``slab_budget`` cut
-    # a level, so this joins exactly those cuts again: ROADMAP D3 retires it.
-    merge_same_geometry: bool = False
 
 
 @jax.tree_util.register_dataclass
@@ -322,8 +312,8 @@ class RandomEffectDataset:
     num_entities: int  # total interned entities E for this RE type
     dim: int
     # (Σ lanes,) int32 rows of every lane, blocks in order, 0 on padding
-    # lanes: the tracker weights iterations by it. None where the blocks
-    # were rearranged after the build.
+    # lanes: the tracker weights iterations by it. None where there are no
+    # blocks.
     lane_samples: Optional[Array] = None
 
     @property
@@ -528,101 +518,10 @@ def build_random_effect_dataset(
                     col_map=None if col_map is None else jnp.asarray(col_map, jnp.int32),
                 )
             )
-    dataset = RandomEffectDataset(
+    return RandomEffectDataset(
         config, blocks, num_entities, d,
         lane_samples=jnp.asarray(np.concatenate(lane_samples)),
     )
-    if config.merge_same_geometry:
-        dataset = merge_same_geometry_blocks(dataset)
-    return dataset
-
-
-def merge_same_geometry_blocks(
-    dataset: RandomEffectDataset,
-) -> RandomEffectDataset:
-    """Collapse dense blocks that share an (n_max, dim) geometry into ONE
-    block each — the dispatch-count collapse behind ``re_kernel`` batching.
-
-    Shape bucketing rounds every block's n_max/dim onto the geometric grid
-    (``bucket_dim``), and a level the slab budget cut comes out as several
-    blocks of the same (n_max, dim): the builder emits them as separate
-    blocks, and each becomes one solver dispatch per CD pass. Entities
-    are vmap lanes with no cross-entity math, so same-geometry blocks can
-    concatenate along the entity axis with per-entity results unchanged —
-    one dispatch solves them all, and the fused Pallas kernel
-    (ops/pallas_newton) runs one grid instance per merged row.
-
-    Invariants preserved:
-    * Per-entity data layout: rows are concatenated in block order, padding
-      rows stay inert (entity_idx −1, weight 0, train_mask False), and the
-      drop-mode scatter keys on ``entity_idx`` — which rows share a block
-      never enters the math. Results are NOT bit-identical to the unmerged
-      layout, however: the vmapped Newton program compiles per lane count,
-      and XLA's fusion/reduction order inside that whole program shifts
-      with the batch dimension (measured ≤ 2.3e-4 coefficient drift with
-      occasional ±1 iteration-count differences on the CPU smoke workload
-      — both layouts converge to the same tolerance). That is why
-      ``RandomEffectDataConfig.merge_same_geometry`` is opt-in and why the
-      re_kernel bit-parity tests always compare on a FIXED layout.
-    * Shape bucketing: the merged entity count re-buckets via
-      ``bucket_dim`` (when the dataset was built with bucketing) so the
-      merged allocation stays on the solver-cache shape grid.
-    * Projected blocks (content-defined ``col_map``) pass through
-      untouched — merging them would retrace on the union col_map.
-
-    Host-side numpy concatenation, one-time at dataset build — never inside
-    the dispatch loop.
-    """
-    groups: Dict[Tuple[int, int], List[int]] = {}
-    for i, b in enumerate(dataset.blocks):
-        if b.col_map is not None:
-            continue
-        groups.setdefault((b.n_max, b.dim), []).append(i)
-
-    merged: List[EntityBlock] = []
-    consumed = set()
-    for i, b in enumerate(dataset.blocks):
-        if i in consumed:
-            continue
-        key = (b.n_max, b.dim)
-        idxs = groups.get(key) if b.col_map is None else None
-        if not idxs or len(idxs) == 1:
-            merged.append(b)
-            continue
-        consumed.update(idxs)
-        parts = [dataset.blocks[j] for j in idxs]
-        E = sum(p.num_entities for p in parts)
-        E_alloc = bucket_dim(E) if dataset.config.shape_bucketing else E
-        pad = E_alloc - E
-        n_max, d = key
-
-        def cat(field, pad_arr):
-            arrs = [np.asarray(getattr(p, field)) for p in parts]
-            if pad:
-                arrs.append(pad_arr)
-            return jnp.asarray(np.concatenate(arrs))
-
-        merged.append(
-            EntityBlock(
-                entity_idx=cat("entity_idx", np.full((pad,), -1, np.int32)),
-                features=cat(
-                    "features",
-                    np.zeros((pad, n_max, d), np.asarray(parts[0].features).dtype),
-                ),
-                label=cat(
-                    "label", np.zeros((pad, n_max), np.asarray(parts[0].label).dtype)
-                ),
-                weight=cat(
-                    "weight", np.zeros((pad, n_max), np.asarray(parts[0].weight).dtype)
-                ),
-                sample_index=cat(
-                    "sample_index", np.full((pad, n_max), -1, np.int32)
-                ),
-                train_mask=cat("train_mask", np.zeros((pad,), bool)),
-                col_map=None,
-            )
-        )
-    return dataclasses.replace(dataset, blocks=merged, lane_samples=None)
 
 
 def pack_into_sizes(total: int, allowed_sizes: Sequence[int]) -> List[int]:

@@ -181,7 +181,6 @@ def minimize_newton(
     w0: Array,
     config: OptimizerConfig = OptimizerConfig(),
     l2_override: Optional[Array] = None,
-    kernel: str = "xla",
 ) -> OptimizeResult:
     """Levenberg-damped exact Newton over a dense-feature GLM batch.
 
@@ -189,27 +188,11 @@ def minimize_newton(
     as ``minimize_lbfgs_margin``. Dense features only (the per-entity blocks
     are dense by construction); scale-type normalization is folded, shift
     normalization is not supported (the random-effect path never uses it).
-
-    ``kernel`` selects the Newton-system assembly lowering
-    (ops/pallas_newton.RE_KERNELS): ``"xla"`` reads X twice per iteration
-    (einsum Hessian + transpose matvec); ``"pallas"`` fuses both reductions
-    into one Pallas read of X with per-entity results bit-equal to the XLA
-    formulations (so the whole solve — same while_loop, damping, and trial
-    sweep — stays bit-exact); ``"pallas_bf16x"`` additionally reads a
-    bfloat16 copy of X inside the fused kernel (f32 accumulation,
-    pinned-tolerance parity). Margins always use the f32 X — the trial
-    sweep's affine-margin update is precision-critical.
     """
     if isinstance(batch.features, SparseFeatures):
         raise ValueError("minimize_newton requires dense features")
     if objective.l1_weight > 0.0:
         raise ValueError("Newton solves smooth objectives; use OWL-QN for L1")
-    if kernel not in ("xla", "pallas", "pallas_bf16x"):
-        raise ValueError(
-            "minimize_newton kernel must be resolved to 'xla', 'pallas', or "
-            f"'pallas_bf16x' (got {kernel!r}; resolve 'auto' via "
-            "ops.pallas_newton.resolve_re_kernel first)"
-        )
     norm = objective.normalization
     if norm is not None and not norm.is_identity and norm.shifts is not None:
         raise ValueError("minimize_newton supports scale normalization only")
@@ -225,13 +208,6 @@ def minimize_newton(
     d = w0.shape[0]
     dtype = w0.dtype
     m_iter, tol = config.max_iter, config.tol
-
-    use_fused = kernel in ("pallas", "pallas_bf16x")
-    if use_fused:
-        from photon_tpu.ops.pallas_newton import fused_newton_system
-
-        # The kernel's HBM read; margins below keep the f32 slab.
-        X_sys = X.astype(jnp.bfloat16) if kernel == "pallas_bf16x" else X
 
     def _l2_mask(w: Array) -> Array:
         if objective.intercept_index is None:
@@ -279,15 +255,8 @@ def minimize_newton(
         # --- pass 1: gradient + Hessian from the carried margins ---
         dz = weight * loss.dz(z, label)
         d2 = weight * loss.dzz(z, label)
-        if use_fused:
-            # One fused X read for both reductions; vmapped callers batch
-            # this into one grid instance per entity (ops/pallas_newton).
-            H_data, g_data = fused_newton_system(X_sys, d2, dz)
-            g = g_data + (l2 * _l2_mask(w) if has_l2 else 0.0)
-            H = H_data + jnp.diag(lam_diag)
-        else:
-            g = X.T @ dz + (l2 * _l2_mask(w) if has_l2 else 0.0)
-            H = jnp.einsum("nd,n,ne->de", X, d2, X) + jnp.diag(lam_diag)
+        g = X.T @ dz + (l2 * _l2_mask(w) if has_l2 else 0.0)
+        H = jnp.einsum("nd,n,ne->de", X, d2, X) + jnp.diag(lam_diag)
         gnorm = jnp.linalg.norm(g)
         g0_norm = jnp.where(st["it"] == 0, gnorm, st["g0_norm"])
 

@@ -220,7 +220,7 @@ def test_fit_on_zipf_users_matches_the_ragged_reference():
     reset_tracer()
     def solves():  # 64 users: every block is under the lanes' bound
         return registry().counter(
-            "re_block_solves_total", kernel="xla", spd_solve="library",
+            "re_block_solves_total", spd_solve="library",
             coordinate="per_user").value
 
     solves_before = solves()
@@ -305,6 +305,60 @@ def test_planned_and_four_quantile_geometries_agree_to_solver_tolerance(monkeypa
     assert np.linalg.norm(r_a - r_b) <= 1e-3 * np.linalg.norm(r_b)
 
 
+def _one_level_users(seed, entities=96):
+    """Users of 37 to 46 rows, d = 6: one grid level (n_max 48), so only a
+    ``slab_budget`` makes more than one block of them."""
+    rng = np.random.default_rng(seed)
+    ids = np.repeat(np.arange(entities, dtype=np.int32),
+                    rng.integers(37, 47, size=entities))
+    x = rng.normal(size=(ids.size, 6)).astype(np.float32)
+    y = (rng.uniform(size=ids.size) < 0.5).astype(np.float32)
+    return ids, x, y
+
+
+def _per_user_batch(ids, x, y):
+    return GameBatch(
+        label=jnp.asarray(y), offset=jnp.zeros(y.shape, jnp.float32),
+        weight=jnp.ones(y.shape, jnp.float32), features={"per_user": jnp.asarray(x)},
+        entity_ids={"userId": jnp.asarray(ids)})
+
+
+@pytest.mark.parametrize("parts", [2, 4])
+def test_a_level_cut_by_the_slab_budget_solves_every_entity_as_uncut(parts):
+    """A ``slab_budget`` cuts one grid level into ``parts`` blocks of one
+    geometry: a pass then counts ``parts`` block solves, and every entity's
+    coefficients are the uncut plan's to solver tolerance (entities are
+    lanes, and no arithmetic crosses them)."""
+    entities = 96
+    ids, x, y = _one_level_users(seed=11, entities=entities)
+    batch = _per_user_batch(ids, x, y)
+
+    def train(coordinate_id, slab_budget):
+        ds = _dataset(ids, x, y, entities, slab_budget=slab_budget)
+        coord = RandomEffectCoordinate(
+            coordinate_id=coordinate_id, dataset=ds,
+            task=TaskType.LOGISTIC_REGRESSION,
+            objective=GLMObjective(loss=LogisticLoss, l2_weight=0.5),
+            optimizer_spec=OptimizerSpec(
+                optimizer=OptimizerType.NEWTON, max_iter=25, tol=1e-9),
+            solve_cache=SolveCache(donate=False))
+        model, _ = coord.train(batch, None, None)
+        solves = registry().counter(
+            "re_block_solves_total", spd_solve="library",
+            coordinate=coordinate_id).value
+        return ds, np.asarray(model.coefficients), solves
+
+    uncut_ds, want, uncut_solves = train(f"uncut_for_{parts}", None)
+    assert len(uncut_ds.blocks) == 1 and uncut_solves == 1
+    lanes = entities // parts
+    cut_ds, got, cut_solves = train(
+        f"cut_in_{parts}", lanes * 48 * 6 * 4)
+    assert [b.features.shape for b in cut_ds.blocks] == [(lanes, 48, 6)] * parts
+    assert cut_solves == parts
+    assert np.all(np.any(want != 0, axis=1))
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+
+
 def test_a_gated_pass_compiles_no_scatter_or_solver_of_its_own():
     """With the active set on every pass scatters block by block, so the full
     first pass compiles what a gated pass, which dispatches another NUMBER of
@@ -317,19 +371,12 @@ def test_a_gated_pass_compiles_no_scatter_or_solver_of_its_own():
             compiles.append(event)
 
     jax.monitoring.register_event_duration_secs_listener(on_duration)
-    rng = np.random.default_rng(7)
     entities = 96
-    ids = np.repeat(np.arange(entities, dtype=np.int32),
-                    rng.integers(37, 47, size=entities))
-    x = rng.normal(size=(ids.size, 6)).astype(np.float32)
+    ids, x, y = _one_level_users(seed=7, entities=entities)
     x[ids % 3 != 0] = 0.0          # a cold cohort: retires after the first pass
-    y = (rng.uniform(size=ids.size) < 0.5).astype(np.float32)
     ds = _dataset(ids, x, y, entities, slab_budget=24 * 48 * 6 * 4)
     assert len(ds.blocks) == 4
-    batch = GameBatch(
-        label=jnp.asarray(y), offset=jnp.zeros(y.shape, jnp.float32),
-        weight=jnp.ones(y.shape, jnp.float32), features={"per_user": jnp.asarray(x)},
-        entity_ids={"userId": jnp.asarray(ids)})
+    batch = _per_user_batch(ids, x, y)
     coord = RandomEffectCoordinate(
         coordinate_id="per_user", dataset=ds, task=TaskType.LOGISTIC_REGRESSION,
         objective=GLMObjective(loss=LogisticLoss, l2_weight=0.5),

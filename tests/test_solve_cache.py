@@ -123,6 +123,30 @@ def test_retrace_once_per_bucket_across_passes():
     assert len(set(cache.stats.trace_keys)) == 1
 
 
+def test_coordinates_of_equal_static_configuration_share_one_entry():
+    """Two coordinates over one cache, each with an objective and a spec of
+    its own that compare equal: one ``block_solver`` entry, one trace, and
+    every dispatch of the second coordinate a hit."""
+    eids, X, y, w = _clustered_problem()
+    ds = _dataset(eids, X, y, w, bucketed=True)
+    cache = SolveCache(donate=True)
+    first, second = _coordinate(ds, cache), _coordinate(ds, cache)
+    assert first.objective is not second.objective
+    batch = _batch(eids, X, y, w)
+    model, _stats = first.train(batch, None, None)
+    assert (cache.num_entries, cache.stats.traces) == (1, 1)
+    with cache.expect_cached("the second coordinate"):
+        again, _stats = second.train(batch, None, None)
+    assert (cache.num_entries, cache.stats.traces) == (1, 1)
+    assert cache.stats.hits == 2 * len(ds.blocks) - 1
+    assert np.array_equal(
+        np.asarray(model.coefficients), np.asarray(again.coefficients)
+    )
+    # Another static configuration (here the spec's memory) is another entry.
+    _coordinate(ds, cache, memory=7).train(batch, None, None)
+    assert cache.num_entries == 2
+
+
 def test_exact_shapes_trace_per_block():
     """Without bucketing the same data costs one trace per distinct block
     shape — the regression the cache+bucketing pair exists to prevent."""

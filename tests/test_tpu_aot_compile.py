@@ -1,5 +1,5 @@
-"""Every ``pallas_call`` and the Newton loop's SPD solve, compiled ahead of
-time for a TPU v5e — on the CPU.
+"""Every ``pallas_call``, the random-effect block solve and its Newton loop's
+SPD solve, compiled ahead of time for a TPU v5e — on the CPU.
 
 ``jax.experimental.topologies.get_topology_desc`` describes a v5e:2x2 host
 with no chip attached, and lowering against a ``ShapeDtypeStruct`` placed
@@ -18,19 +18,23 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from photon_tpu.algorithm.random_effect import _solve_block
+from photon_tpu.data.random_effect import EntityBlock
 from photon_tpu.ops.losses import LogisticLoss
+from photon_tpu.ops.objective import GLMObjective
 from photon_tpu.ops.pallas_glm import (
     MAX_FUSED_DIM,
     fused_data_hvp,
     fused_data_value_and_grad,
 )
-from photon_tpu.ops.pallas_newton import fused_newton_system
+from photon_tpu.optim.factory import OptimizerSpec
 from photon_tpu.optim.newton import spd_solve, spd_solve_lowering
+from photon_tpu.types import OptimizerType
 
-# bench.py's headline shapes: N = 2^21 rows, d = 256; E = 4096 entities of
-# 512 rows, d_re = 16.
+# bench.py's headline shapes: N = 2^21 rows, d = 256; d_re = 16, as in every
+# benchmark cell.
 N, D_FIX = 1 << 21, 256
-E, N_MAX, D_RE = 4096, 512, 16
+D_RE = 16
 
 
 @pytest.fixture(scope="module")
@@ -90,22 +94,41 @@ def test_fixed_effect_kernels_compile_for_v5e(v5e, n, d, dtype):
     )
 
 
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 @pytest.mark.parametrize(
-    "e,n_max,d_re",
+    "lanes,n_max",
     [
-        (E, N_MAX, D_RE),    # the headline entity block
-        (64, 1 << 14, D_RE),  # wide n_max: the d2/dz columns bound the tile
-        (32, 1 << 13, 64),
+        # The blocks the fit cells dispatch (PERF.md §4): fit.glmix2's two,
+        # fit.glmix3's items, and the ends of fit.glmix2-zipf's plan.
+        (2304, 512), (2048, 768), (144, 8192), (128, 12288),
+        (3840, 96), (1, 524288),
     ],
 )
-def test_random_effect_kernel_compiles_for_v5e(v5e, e, n_max, d_re, dtype):
-    f32 = jnp.float32
-    _compile(
-        jax.vmap(lambda X, d2, dz: fused_newton_system(
-            X, d2, dz, interpret=False)),
-        v5e, ((e, n_max, d_re), dtype), ((e, n_max), f32), ((e, n_max), f32),
+def test_random_effect_block_solve_compiles_for_v5e(v5e, lanes, n_max):
+    """The jitted ``_solve_block`` on the Newton route, the program a
+    ``RandomEffectCoordinate`` dispatches once a block: it compiles for the
+    v5e at the cells' block shapes, holds no Mosaic kernel, and the library
+    ``Cholesky`` call exactly where ``spd_solve_lowering`` says so."""
+    def on_chip(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    objective = GLMObjective(loss=LogisticLoss, l2_weight=1.0)
+    spec = OptimizerSpec(optimizer=OptimizerType.NEWTON, max_iter=20, tol=1e-7)
+    block = EntityBlock(
+        entity_idx=on_chip((lanes,), jnp.int32),
+        features=on_chip((lanes, n_max, D_RE)),
+        label=on_chip((lanes, n_max)),
+        weight=on_chip((lanes, n_max)),
+        sample_index=on_chip((lanes, n_max), jnp.int32),
+        train_mask=on_chip((lanes,), jnp.bool_),
     )
+    text = jax.jit(
+        lambda b, offsets, w0: _solve_block(
+            b, offsets, w0, objective, spec, spec.config()
+        )
+    ).lower(block, on_chip((lanes, n_max)), on_chip((lanes, D_RE))).compile().as_text()
+    assert "tpu_custom_call" not in text
+    library = spd_solve_lowering(D_RE, lanes) == "library"
+    assert ('custom_call_target="Cholesky"' in text) == library
 
 
 @pytest.mark.parametrize("lanes", [3072, 128, 1])
