@@ -149,6 +149,14 @@ class CoordinateDescent:
         coordinates stay enqueued on device with no host sync, and the
         recorded wall times measure dispatch only.
 
+        Outside the coordinate updates ``run`` reads nothing back from the
+        device: the trackers it returns hold device arrays, and each costs
+        one transfer when somebody reads it (``summary()``,
+        ``diagnostics_dict()``). Two readers live here: the optimisation
+        summary, built only when this module's logger is enabled for INFO
+        (the drivers under ``photon_tpu/cli`` are), and the per-update event
+        of an ``emitter`` under ``profile``.
+
         With ``checkpoint_dir``, full descent state (models, score arrays,
         iteration counter, metric history) is persisted every
         ``checkpoint_every`` iterations and training RESUMES from the latest
@@ -455,7 +463,10 @@ class CoordinateDescent:
             tracker=tracker,
             wall_times=wall_times,
         )
-        summary = result.summary()
-        if summary:
-            logger.info("optimization summary:\n%s", summary)
+        # The summary reads every tracker back from the device: built only
+        # for a log that will show it.
+        if logger.isEnabledFor(logging.INFO):
+            summary = result.summary()
+            if summary:
+                logger.info("optimization summary:\n%s", summary)
         return result

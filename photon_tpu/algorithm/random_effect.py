@@ -83,10 +83,12 @@ class RandomEffectTrackerStats:
 
     The per-row iteration/reason arrays stay ON DEVICE: building the tracker
     after a coordinate pass costs no host sync, so the coordinate-descent
-    sequence never blocks mid-pass on diagnostics. Python scalars
-    materialize lazily — through the aggregate properties or ``summary()``,
-    which is where the device→host transfer happens. ``valid`` masks
-    shape-bucket padding rows out of every aggregate.
+    sequence never blocks mid-pass on diagnostics. Reading it is ONE
+    device→host transfer a call, then numpy: ``summary()``,
+    ``diagnostics_dict()`` and each aggregate property fetch the rows with
+    one ``jax.device_get`` and apply no ``jnp`` operation. The host copies
+    live for that call alone (the pytree's leaves stay the device rows).
+    ``valid`` masks shape-bucket padding rows out of every aggregate.
     """
 
     iterations: Array  # (T,) per-row iteration counts, blocks concatenated
@@ -101,75 +103,86 @@ class RandomEffectTrackerStats:
         z = jnp.zeros((0,), jnp.int32)
         return RandomEffectTrackerStats(z, z, jnp.zeros((0,), bool))
 
+    def _aggregates(self) -> dict:
+        """The seven aggregates from one transfer. Counts and the mean's
+        numerator are exact integer sums; the two means divide in float32,
+        as the device form did."""
+        h = jax.device_get(self)
+        valid = h.valid
+        iters = np.where(valid, h.iterations, 0).astype(np.int64)
+        entities = int(valid.sum())
+
+        def count(*codes) -> int:
+            return int((np.isin(h.reasons, codes) & valid).sum())
+
+        weighted = None
+        if h.samples is not None:
+            rows = np.where(valid, h.samples, 0).astype(np.int64)
+            weighted = float(
+                np.float32((rows * iters).sum())
+                / np.float32(max(int(rows.sum()), 1))
+            )
+        return dict(
+            entities=entities,
+            converged=count(
+                REASON_FUNCTION_VALUES_CONVERGED, REASON_GRADIENT_CONVERGED
+            ),
+            hit_max_iter=count(REASON_MAX_ITERATIONS),
+            quarantined=count(REASON_DIVERGED),
+            mean_iterations=float(
+                np.float32(iters.sum()) / np.float32(max(entities, 1))
+            ),
+            max_iterations=int(iters.max()) if iters.size else 0,
+            row_weighted_iterations=weighted,
+        )
+
     @property
     def num_entities(self) -> int:
-        return int(jnp.sum(self.valid))
+        return self._aggregates()["entities"]
 
     @property
     def num_converged(self) -> int:
-        conv = (self.reasons == REASON_FUNCTION_VALUES_CONVERGED) | (
-            self.reasons == REASON_GRADIENT_CONVERGED
-        )
-        return int(jnp.sum(conv & self.valid))
+        return self._aggregates()["converged"]
 
     @property
     def num_max_iter(self) -> int:
-        return int(jnp.sum((self.reasons == REASON_MAX_ITERATIONS) & self.valid))
+        return self._aggregates()["hit_max_iter"]
 
     @property
     def num_quarantined(self) -> int:
         """Entities whose solve diverged and kept their previous coefficients
         (the in-trace guard in solve_cache.block_solver)."""
-        return int(jnp.sum((self.reasons == REASON_DIVERGED) & self.valid))
+        return self._aggregates()["quarantined"]
 
     @property
     def mean_iterations(self) -> float:
-        n = jnp.maximum(jnp.sum(self.valid), 1)
-        return float(
-            jnp.sum(jnp.where(self.valid, self.iterations, 0).astype(jnp.float32))
-            / n
-        )
+        return self._aggregates()["mean_iterations"]
 
     @property
     def max_iterations(self) -> int:
-        if self.iterations.shape[0] == 0:
-            return 0
-        return int(jnp.max(jnp.where(self.valid, self.iterations, 0)))
+        return self._aggregates()["max_iterations"]
 
     @property
     def row_weighted_iterations(self) -> Optional[float]:
         """Σ rows_e · iterations_e ÷ Σ rows_e: the iterations of the mean
         ROW's entity, which is what the solve's work follows when entities
         are uneven (``mean_iterations`` weighs a 30-row user like a
-        400,000-row one)."""
-        if self.samples is None:
-            return None
-        rows = jnp.where(self.valid, self.samples, 0).astype(jnp.float32)
-        return float(
-            jnp.sum(rows * self.iterations.astype(jnp.float32))
-            / jnp.maximum(jnp.sum(rows), 1.0)
-        )
+        400,000-row one). None where the tracker holds no ``samples``."""
+        return self._aggregates()["row_weighted_iterations"]
 
     def summary(self) -> str:
+        a = self._aggregates()
         return (
-            f"entities={self.num_entities} converged={self.num_converged} "
-            f"hit_max_iter={self.num_max_iter} quarantined={self.num_quarantined} "
-            f"iters(mean={self.mean_iterations:.1f}, max={self.max_iterations})"
+            f"entities={a['entities']} converged={a['converged']} "
+            f"hit_max_iter={a['hit_max_iter']} quarantined={a['quarantined']} "
+            f"iters(mean={a['mean_iterations']:.1f}, max={a['max_iterations']})"
         )
 
     def diagnostics_dict(self) -> dict:
-        """Report-ready aggregates. Materializes the device-resident rows —
-        call only at run-report finalize, never inside the dispatch loop."""
-        return dict(
-            type="random_effect",
-            entities=self.num_entities,
-            converged=self.num_converged,
-            hit_max_iter=self.num_max_iter,
-            quarantined=self.num_quarantined,
-            mean_iterations=self.mean_iterations,
-            max_iterations=self.max_iterations,
-            row_weighted_iterations=self.row_weighted_iterations,
-        )
+        """Report-ready aggregates: one device→host transfer of the rows,
+        then host arithmetic. Still a read the dispatch loop must not make
+        — call it at run-report finalize."""
+        return dict(type="random_effect", **self._aggregates())
 
 
 def newton_eligible(
@@ -485,12 +498,10 @@ class RandomEffectCoordinate(Coordinate):
                 obj = self._block_objective(b)
                 obj_memo[memo_key] = obj
             self._block_objectives.append(obj)
-        # Host-side valid-row masks/counts (entity_idx >= 0), computed once
-        # at construction so active-set accounting never reads device arrays
-        # inside the dispatch loop.
-        self._block_valid_rows = [
-            np.asarray(b.entity_idx) >= 0 for b in self.dataset.blocks
-        ]
+        # Host-side valid-row masks/counts (entity_idx >= 0): the dataset
+        # kept them from its build, so neither this constructor (one a fit)
+        # nor the active-set accounting reads a block back from the device.
+        self._block_valid_rows = self.dataset.lane_valid
         self._block_valid_counts = [
             int(np.sum(v)) for v in self._block_valid_rows
         ]

@@ -315,6 +315,10 @@ class RandomEffectDataset:
     # lanes: the tracker weights iterations by it. None where there are no
     # blocks.
     lane_samples: Optional[Array] = None
+    # One (lanes,) HOST bool array a block: True where the lane holds an
+    # entity (``entity_idx >= 0``). Kept from the build's host arrays so a
+    # coordinate built on this dataset (one a fit) reads no block back.
+    lane_valid: List[np.ndarray] = dataclasses.field(default_factory=list)
 
     @property
     def num_active_samples(self) -> int:
@@ -438,6 +442,7 @@ def build_random_effect_dataset(
         )
     blocks: List[EntityBlock] = []
     lane_samples = []
+    lane_valid = []
     with span("fill"):
         for plan in plans:
             sel, n_max, E_alloc = plan.members, plan.n_max, plan.lanes
@@ -507,6 +512,7 @@ def build_random_effect_dataset(
                     existing_model_mask is not None
                     and not bool(existing_model_mask[eid])
                 )
+            lane_valid.append(eidx >= 0)
             blocks.append(
                 EntityBlock(
                     entity_idx=jnp.asarray(eidx),
@@ -521,6 +527,7 @@ def build_random_effect_dataset(
     return RandomEffectDataset(
         config, blocks, num_entities, d,
         lane_samples=jnp.asarray(np.concatenate(lane_samples)),
+        lane_valid=lane_valid,
     )
 
 

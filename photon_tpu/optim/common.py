@@ -110,37 +110,48 @@ class OptimizeResult:
     def convergence_reason(self) -> ConvergenceReason:
         return _REASONS[int(self.reason_code)]
 
+    def _on_host(self) -> "OptimizeResult":
+        """The tracker's fields (all but ``w``) as numpy, in ONE
+        ``jax.device_get``: the two readers below format from this copy and
+        apply no ``jnp`` operation, so reading a result is one transfer
+        whatever the number of iterations."""
+        return jax.device_get(dataclasses.replace(self, w=None))
+
     def diagnostics_dict(self) -> dict:
-        """Report-ready host scalars. This is a device→host read, so call it
-        only at run-report finalize — never inside the dispatch loop."""
+        """Report-ready host scalars: one device→host transfer, then host
+        arithmetic. Still a read the dispatch loop must not make — call it
+        at run-report finalize."""
+        h = self._on_host()
         return dict(
             type="fixed_effect",
-            iterations=int(self.iterations),
-            value=float(self.value),
-            grad_norm=float(self.grad_norm),
-            reason=self.convergence_reason.value,
-            converged=bool(self.converged),
-            evals=int(self.evals),
+            iterations=int(h.iterations),
+            value=float(h.value),
+            grad_norm=float(h.grad_norm),
+            reason=h.convergence_reason.value,
+            converged=bool(h.converged),
+            evals=int(h.evals),
             eval_unit=self.eval_unit,
         )
 
     def summary(self) -> str:
-        """Human-readable per-iteration table (tracker toSummaryString)."""
-        n = int(self.iterations)
-        if self.loss_history.shape[0] < n + 1:
+        """Human-readable per-iteration table (tracker toSummaryString):
+        one device→host transfer, formatted from numpy."""
+        h = self._on_host()
+        n = int(h.iterations)
+        if h.loss_history.shape[0] < n + 1:
             # track_history=False run: only aggregates are available.
             return (
-                f"iterations={n} value={float(self.value):.6e} "
-                f"|grad|={float(self.grad_norm):.6e} "
-                f"reason: {self.convergence_reason.value} (history not tracked)"
+                f"iterations={n} value={float(h.value):.6e} "
+                f"|grad|={float(h.grad_norm):.6e} "
+                f"reason: {h.convergence_reason.value} (history not tracked)"
             )
         lines = ["iter    loss           |grad|"]
         for i in range(n + 1):
             lines.append(
-                f"{i:4d}    {float(self.loss_history[i]):.6e}   "
-                f"{float(self.grad_norm_history[i]):.6e}"
+                f"{i:4d}    {float(h.loss_history[i]):.6e}   "
+                f"{float(h.grad_norm_history[i]):.6e}"
             )
-        lines.append(f"reason: {self.convergence_reason.value}")
+        lines.append(f"reason: {h.convergence_reason.value}")
         return "\n".join(lines)
 
 
