@@ -89,6 +89,9 @@ class FixedEffectCoordinate(Coordinate):
         # device; nothing here blocks).
         with span("fe_solve"):
             result = solve(w0, lb)
+        # The label the tracker's read publishes this solve under (a static
+        # field: no device work).
+        result = dataclasses.replace(result, coordinate=self.coordinate_id)
         # SIMPLE/FULL variance computation
         # (DistributedOptimizationProblem.scala:83-103 role). Evaluated at
         # the transformed-space optimum (self-consistent with the folded

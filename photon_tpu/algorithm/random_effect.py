@@ -261,10 +261,9 @@ def _solve_block(
                 return objective.linearized_hvp(w, lb)
 
         if objective.l1_weight > 0.0:
-            l1_mask = None
-            if objective.intercept_index is not None:
-                l1_mask = jnp.ones_like(w_init).at[objective.intercept_index].set(0.0)
-            res = minimize_owlqn(vg, w_start, objective.l1_weight, config, l1_mask)
+            res = minimize_owlqn(
+                vg, w_start, objective.l1_weight, config, objective.l1_mask(w_init)
+            )
         elif use_newton:
             res = minimize_newton(objective, lb, w_start, config)
         elif spec.optimizer == OptimizerType.TRON:

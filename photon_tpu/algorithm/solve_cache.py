@@ -351,12 +351,14 @@ class SolveCache:
                 # flagged DIVERGED (L-BFGS additionally rolls back to the
                 # last finite iterate inside its own loop).
                 ok = jnp.all(jnp.isfinite(res.w))
+                w = jnp.where(ok, res.w, w0)
                 return dataclasses.replace(
                     res,
-                    w=jnp.where(ok, res.w, w0),
+                    w=w,
                     reason_code=jnp.where(
                         ok, res.reason_code, jnp.int32(REASON_DIVERGED)
                     ),
+                    nonzeros=jnp.count_nonzero(w).astype(jnp.int32),
                 )
 
             return jax.jit(traced)

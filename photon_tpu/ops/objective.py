@@ -94,6 +94,13 @@ class GLMObjective:
             return jnp.zeros((), w.dtype)
         return self.l1_weight * jnp.sum(jnp.abs(self._l2_mask(w)))
 
+    def l1_mask(self, w: Array) -> Optional[Array]:
+        """The 0/1 vector OWL-QN multiplies its L1 weight by: 0 at the
+        intercept, which is unpenalised; None where there is no intercept."""
+        if self.intercept_index is None:
+            return None
+        return jnp.ones_like(w).at[self.intercept_index].set(0.0)
+
     # ----- ObjectiveFunction.value -----
 
     def value(self, w: Array, batch: LabeledBatch) -> Array:

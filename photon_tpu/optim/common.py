@@ -93,6 +93,17 @@ class OptimizeResult:
     eval_unit: str = dataclasses.field(
         default="objective_evals", metadata=dict(static=True)
     )
+    # Coefficients of ``w`` that are not exactly zero (what an L1 term
+    # leaves), counted inside the solve's own program where the solve cache
+    # builds it; -1 where nobody counted.
+    nonzeros: Array = dataclasses.field(
+        default_factory=lambda: jnp.full((), -1, jnp.int32)
+    )
+    # Which solver the factory routed to ("owlqn", "lbfgs_margin", ...) and
+    # which coordinate it solved: the labels this result is published under
+    # when it is read. Empty where no factory or coordinate made it.
+    optimizer: str = dataclasses.field(default="", metadata=dict(static=True))
+    coordinate: str = dataclasses.field(default="", metadata=dict(static=True))
 
     @property
     def x_passes(self) -> Array:
@@ -114,8 +125,16 @@ class OptimizeResult:
         """The tracker's fields (all but ``w``) as numpy, in ONE
         ``jax.device_get``: the two readers below format from this copy and
         apply no ``jnp`` operation, so reading a result is one transfer
-        whatever the number of iterations."""
-        return jax.device_get(dataclasses.replace(self, w=None))
+        whatever the number of iterations. The copy is kept, so a result
+        read twice (the log's summary, then the run report) is transferred
+        and published once."""
+        host = self.__dict__.get("_host")
+        if host is None:
+            host = jax.device_get(dataclasses.replace(self, w=None))
+            object.__setattr__(self, "_host", host)
+            if self.optimizer:
+                _publish(host)
+        return host
 
     def diagnostics_dict(self) -> dict:
         """Report-ready host scalars: one device→host transfer, then host
@@ -153,6 +172,25 @@ class OptimizeResult:
             )
         lines.append(f"reason: {h.convergence_reason.value}")
         return "\n".join(lines)
+
+
+def _publish(host: OptimizeResult) -> None:
+    """One solve's work into the registry, from the host copy the tracker's
+    readers already made: iterations and evaluations as counters, the count
+    of non-zero coefficients as a gauge (the last solve's)."""
+    from photon_tpu.obs.metrics import registry
+
+    labels = dict(coordinate=host.coordinate, optimizer=host.optimizer)
+    registry().counter("fe_solver_iterations_total", **labels).inc(
+        int(host.iterations)
+    )
+    registry().counter(
+        "fe_solver_evals_total", unit=host.eval_unit, **labels
+    ).inc(int(host.evals))
+    if int(host.nonzeros) >= 0:
+        registry().gauge(
+            "fe_nonzero_coefficients", coordinate=host.coordinate
+        ).set(int(host.nonzeros))
 
 
 def check_convergence(

@@ -59,6 +59,13 @@ def make_optimizer(
     """
     config = spec.config()
 
+    def run(name: str, minimize: Callable[..., OptimizeResult], *args, **kwargs):
+        # The solver's name is the scope of its operations in a profile
+        # (``fe_solve/owlqn`` under the solve cache's scope) and the label
+        # its result is published under when the tracker is read.
+        with jax.named_scope(name):
+            return dataclasses.replace(minimize(*args, **kwargs), optimizer=name)
+
     def solve(w0: Array, batch) -> OptimizeResult:
         vg = lambda w: objective.value_and_grad(w, batch)
         # OWL-QN whenever an L1 term exists (auto-selected or explicit) —
@@ -66,27 +73,30 @@ def make_optimizer(
         # projection still pins sign-crossing coordinates), so a smooth
         # objective always routes to L-BFGS regardless of the spec.
         if objective.l1_weight > 0.0:
-            l1_mask = None
-            if objective.intercept_index is not None:
-                import jax.numpy as jnp
-
-                l1_mask = jnp.ones_like(w0).at[objective.intercept_index].set(0.0)
-            return minimize_owlqn(vg, w0, objective.l1_weight, config, l1_mask)
+            return run(
+                "owlqn", minimize_owlqn, vg, w0, objective.l1_weight, config,
+                objective.l1_mask(w0),
+            )
         if spec.optimizer == OptimizerType.TRON:
             # Factory form: margins/curvature built once per outer iteration,
             # shared across that iteration's CG products (2 X passes each).
-            return minimize_tron(
-                vg, None, w0, config, spec.max_cg_iter, spec.box,
+            return run(
+                "tron", minimize_tron, vg, None, w0, config, spec.max_cg_iter,
+                spec.box,
                 hvp_factory=lambda w: objective.linearized_hvp(w, batch),
             )
         if spec.optimizer == OptimizerType.LBFGSB:
             assert spec.box is not None, "LBFGSB requires a box"
-            return minimize_lbfgsb(vg, w0, spec.box[0], spec.box[1], config)
+            return run(
+                "lbfgsb", minimize_lbfgsb, vg, w0, spec.box[0], spec.box[1], config
+            )
         # Smooth unconstrained GLM over a LabeledBatch: margin-space L-BFGS
         # (photon_tpu.optim.margin_lbfgs) — ~2 X passes/iteration instead of
         # the black-box 2·(1+trials); measured ~3× per-solve on TPU.
         if spec.box is None and isinstance(batch, LabeledBatch):
-            return minimize_lbfgs_margin(objective, batch, w0, config)
-        return minimize_lbfgs(vg, w0, config, spec.box)
+            return run(
+                "lbfgs_margin", minimize_lbfgs_margin, objective, batch, w0, config
+            )
+        return run("lbfgs", minimize_lbfgs, vg, w0, config, spec.box)
 
     return solve

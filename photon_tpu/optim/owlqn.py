@@ -10,6 +10,8 @@ Algorithm (Andrew & Gao 2007, public): minimize f(w) + λ‖w‖₁ by
   3. sign-align the direction with the negative pseudo-gradient,
   4. backtracking (Armijo on the regularized objective) with orthant
      projection: trial points are clipped to the orthant of the search point.
+     A non-finite trial is a rejected trial; a search without an accepted
+     trial keeps the iterate and ends with OBJECTIVE_NOT_IMPROVING.
 
 Fully jittable: one ``lax.while_loop`` per optimize call, inner backtracking
 as a nested while_loop. The intercept is excluded from the L1 term via the
@@ -28,6 +30,7 @@ from photon_tpu.optim.common import (
     OptimizerConfig,
     REASON_MAX_ITERATIONS,
     REASON_NOT_CONVERGED,
+    REASON_OBJECTIVE_NOT_IMPROVING,
     check_convergence,
 )
 from photon_tpu.optim.lbfgs import two_loop_direction
@@ -112,10 +115,12 @@ def minimize_owlqn(
         ).astype(dtype)
 
         # Backtracking Armijo on the regularized objective with orthant projection.
+        def armijo(alpha, Ft):
+            return Ft <= F + 1e-4 * alpha * dirderiv
+
         def bt_cond(bs):
             alpha, Ft, _wt, _gt, evals = bs
-            armijo = Ft <= F + 1e-4 * alpha * dirderiv
-            return (~armijo) & (evals < config.max_line_search_evals)
+            return (~armijo(alpha, Ft)) & (evals < config.max_line_search_evals)
 
         def bt_body(bs):
             alpha, _Ft, _wt, _gt, evals = bs
@@ -124,11 +129,25 @@ def minimize_owlqn(
             Ft, _ft, gt = full_value(wt)
             return alpha, Ft, wt, gt, evals + 1
 
-        w1 = _orthant_project(w + init_step * p, xi)
-        F1, _f1, g1 = full_value(w1)
-        alpha, F_new, w_new, g_new, bt_evals = jax.lax.while_loop(
-            bt_cond, bt_body, (init_step, F1, w1, g1, jnp.int32(1))
+        with jax.named_scope("line_search"):
+            w1 = _orthant_project(w + init_step * p, xi)
+            F1, _f1, g1 = full_value(w1)
+            alpha, F_new, w_new, g_new, bt_evals = jax.lax.while_loop(
+                bt_cond, bt_body, (init_step, F1, w1, g1, jnp.int32(1))
+            )
+        # A trial that overflowed (``exp`` of a Poisson margin in float32)
+        # reads inf or NaN, never meets Armijo and is halved like any other
+        # rejected trial. A search that spent its budget without an accepted
+        # trial keeps the iterate and ends the solve: the last trial is not
+        # a step.
+        found = (
+            armijo(alpha, F_new)
+            & jnp.all(jnp.isfinite(w_new))
+            & jnp.all(jnp.isfinite(g_new))
         )
+        w_new = jnp.where(found, w_new, w)
+        F_new = jnp.where(found, F_new, F)
+        g_new = jnp.where(found, g_new, g)
 
         s = w_new - w
         y = g_new - g  # curvature pairs from the SMOOTH gradient (per OWL-QN)
@@ -147,6 +166,7 @@ def minimize_owlqn(
         pg_new = _pseudo_gradient(w_new, g_new, l1)
         pgn = jnp.linalg.norm(pg_new)
         reason = check_convergence(F_new, F, pgn, pg0_norm, tol, it, max_iter)
+        reason = jnp.where(found, reason, REASON_OBJECTIVE_NOT_IMPROVING)
         return dict(
             w=w_new, F=F_new, g=g_new, it=it, reason=reason,
             s_hist=s_hist, y_hist=y_hist, rho_hist=rho_hist,

@@ -461,7 +461,20 @@ def test_profile_event_payload_is_the_tracker_summary(glmix):
 # --- (d) the second fit reads no block back --------------------------------------
 
 
-def test_second_fit_reads_nothing_back_from_the_device(glmix, monkeypatch, caplog):
+# The fixed effect's solver and its penalty by configuration: L2 alone routes
+# to margin-space L-BFGS; an elastic net (benchmark/configs/
+# glmix2-poisson-enet.json's shape: a Poisson loss, weight and alpha) to
+# OWL-QN, whose counters and gauge are published when a tracker is READ.
+FIT_CASES = {
+    "logistic_l2": (TaskType.LOGISTIC_REGRESSION, (1.0, 0.0), "lbfgs_margin"),
+    "poisson_elastic_net": (TaskType.POISSON_REGRESSION, (16.0, 0.5), "owlqn"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FIT_CASES))
+def test_second_fit_reads_nothing_back_from_the_device(
+    case, glmix, monkeypatch, caplog
+):
     from photon_tpu.estimators.config import (
         FixedEffectCoordinateConfig,
         GameOptimizationConfig,
@@ -469,10 +482,12 @@ def test_second_fit_reads_nothing_back_from_the_device(glmix, monkeypatch, caplo
         RegularizationConfig,
     )
     from photon_tpu.estimators.game_estimator import GameEstimator
+    from photon_tpu.obs.metrics import registry
 
+    task, (weight, alpha), optimizer = FIT_CASES[case]
     batch = glmix[0]
     estimator = GameEstimator(
-        task=TaskType.LOGISTIC_REGRESSION,
+        task=task,
         coordinate_configs=[
             FixedEffectCoordinateConfig("global", "global"),
             RandomEffectCoordinateConfig("per_user", "userId", "per_user"),
@@ -482,9 +497,10 @@ def test_second_fit_reads_nothing_back_from_the_device(glmix, monkeypatch, caplo
         num_entities={"userId": E},
     )
     opt = GameOptimizationConfig(reg={
-        "global": RegularizationConfig(weight=1.0),
+        "global": RegularizationConfig(weight=weight, alpha=alpha),
         "per_user": RegularizationConfig(weight=0.5),
     })
+    registry().reset()
     with caplog.at_level(logging.WARNING, logger="photon_tpu"):
         (first,) = estimator.fit(batch, optimization_configs=[opt])
         dataset = estimator._re_datasets["per_user"]
@@ -498,6 +514,17 @@ def test_second_fit_reads_nothing_back_from_the_device(glmix, monkeypatch, caplo
     entity_idx = {id(b.entity_idx) for b in dataset.blocks}
     assert not [x for x in read if id(x) in entity_idx]
     assert read == []  # nor anything else: a warm fit only dispatches
+    # Nothing was published either: the solver's counters and the gauge come
+    # with the tracker's one transfer, when somebody reads it.
+    labels = dict(coordinate="global", optimizer=optimizer)
+    assert registry().find("fe_solver_iterations_total", **labels) is None
+    with device_gets(monkeypatch) as gets:
+        diags = [d.diagnostics_dict() for d in second.tracker["global"]]
+        [d.summary() for d in second.tracker["global"]]  # the copy is kept
+    assert len(gets) == len(diags) == 2
+    assert registry().find("fe_solver_iterations_total", **labels).value == sum(
+        d["iterations"] for d in diags
+    )
     for a, b in zip(jax.tree_util.tree_leaves(first.model),
                     jax.tree_util.tree_leaves(second.model), strict=True):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
