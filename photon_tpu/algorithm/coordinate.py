@@ -21,7 +21,12 @@ Array = jax.Array
 
 
 class Coordinate(abc.ABC):
-    """One coordinate: owns its view of the data + optimization problem."""
+    """One coordinate: owns its view of the data + optimization problem.
+
+    Coordinate descent asks for one :meth:`update` a pass: the new model, its
+    diagnostics and its scores on the batch. The default is :meth:`train`
+    then :meth:`score`; a coordinate whose solve already holds the scores
+    (the fixed effect's margins) overrides it and reads the data once."""
 
     coordinate_id: str
 
@@ -39,6 +44,21 @@ class Coordinate(abc.ABC):
     @abc.abstractmethod
     def score(self, model: Any, batch: GameBatch) -> Array:
         """Per-sample raw scores of this coordinate's model (no offsets)."""
+
+    def update(
+        self,
+        batch: GameBatch,
+        residual_scores: Optional[Array] = None,
+        initial_model: Optional[Any] = None,
+        initial_scores: Optional[Array] = None,
+    ) -> Tuple[Any, Any, Array]:
+        """One coordinate-descent update: ``(model, diagnostics, scores)``,
+        the scores being :meth:`score` of the new model on ``batch``.
+        ``initial_scores`` are this coordinate's scores of ``initial_model``
+        on ``batch`` where the caller holds them (zeros for no model): a
+        coordinate may start from them, and may not rely on getting them."""
+        model, diag = self.train(batch, residual_scores, initial_model)
+        return model, diag, self.score(model, batch)
 
     @abc.abstractmethod
     def zero_model(self) -> Any:

@@ -285,12 +285,14 @@ class CoordinateDescent:
                 if begin_pass is not None:
                     begin_pass(it)
                 t0 = time.monotonic()
-                # One coordinate update = exchange + solve + score. Under
-                # ``profile`` the score span ends on the fence, so the
-                # device time inside this span's interval is this
-                # coordinate's, whatever programs the solve launches. The
-                # closing exchange is dispatched after the fence: its two
-                # elementwise launches may run inside the NEXT update's
+                # One coordinate update = exchange + solve + score. The
+                # solve span holds every launch of the coordinate's update
+                # (its scores too: the fixed effect's come out of the solve
+                # program itself); under ``profile`` the score span ends on
+                # the fence, so the device time inside this span's interval
+                # is this coordinate's, whatever programs the solve launches.
+                # The closing exchange is dispatched after the fence: its
+                # two elementwise launches may run inside the NEXT update's
                 # interval.
                 with _export_trace(), span(f"cd/iter{it}/{cid}"):
                     with span("exchange"):
@@ -301,9 +303,10 @@ class CoordinateDescent:
                             None if single else total_scores - scores[cid]
                         )
                     with span("solve"):
-                        model, diag = coord.train(batch, residual, models[cid])
+                        model, diag, new_scores = coord.update(
+                            batch, residual, models[cid], scores[cid]
+                        )
                     with span("score"):
-                        new_scores = coord.score(model, batch)
                         if profile:
                             # The clock must cover device execution, not
                             # dispatch.

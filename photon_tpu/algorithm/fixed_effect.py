@@ -20,6 +20,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from photon_tpu.data.batch import features_dot
 from photon_tpu.data.game_data import GameBatch
 from photon_tpu.algorithm.coordinate import Coordinate
 from photon_tpu.models.coefficients import Coefficients
@@ -28,13 +29,19 @@ from photon_tpu.models.glm import GeneralizedLinearModel
 from photon_tpu.ops.objective import GLMObjective
 from photon_tpu.ops.variance import coefficient_variances, normalize_variance_type
 from photon_tpu.optim.common import OptimizeResult
-from photon_tpu.optim.factory import OptimizerSpec
+from photon_tpu.optim.factory import OptimizerSpec, carries_margins
 from photon_tpu.algorithm.solve_cache import SolveCache, default_cache
+from photon_tpu.obs.metrics import registry
 from photon_tpu.obs.trace import span
 from photon_tpu.sampling.down_sampler import DownSampler
 from photon_tpu.types import TaskType, VarianceComputationType
 
 Array = jax.Array
+
+# x·w as one fused pass over X: the score of a model nobody has solved here
+# (a warm start, a resume), and what the solve's own program runs at its end
+# for a solver that carries no margins.
+_fused_score = jax.jit(features_dot)
 
 
 @dataclasses.dataclass
@@ -65,6 +72,24 @@ class FixedEffectCoordinate(Coordinate):
         residual_scores: Optional[Array] = None,
         initial_model: Optional[FixedEffectModel] = None,
     ) -> Tuple[FixedEffectModel, OptimizeResult]:
+        """:meth:`update` without the scores (the program computes them all
+        the same: free where the solver carries margins, one pass over X
+        where it does not)."""
+        model, result, _scores = self.update(batch, residual_scores, initial_model)
+        return model, result
+
+    def update(
+        self,
+        batch: GameBatch,
+        residual_scores: Optional[Array] = None,
+        initial_model: Optional[FixedEffectModel] = None,
+        initial_scores: Optional[Array] = None,
+    ) -> Tuple[FixedEffectModel, OptimizeResult, Array]:
+        """The solve program's launch and nothing else that reads X: the new
+        model's scores come back from the program (the solver's own margins,
+        or one fused pass at its end), and a margin-carrying solver starts
+        from the scores the caller holds: zero for a model that starts at
+        zero, ``initial_scores`` for ``initial_model``."""
         lb = batch.labeled_batch(self.feature_shard, residual_scores)
         if self.down_sampler is not None:
             # Down-sampling as reweighting mask — static shapes
@@ -79,16 +104,35 @@ class FixedEffectCoordinate(Coordinate):
         # Models live in MODEL space; solves run in the normalization-folded
         # transformed space (reference Optimizer.scala:167 converts the warm
         # start in, DistributedOptimizationProblem.scala:127 converts the
-        # result out).
+        # result out). Scores are the same in both.
         norm = self.objective.normalization
         folded = norm is not None and not norm.is_identity
         if folded:
             w0 = norm.model_to_transformed_space(w0)
+        # Where the solve's starting margins and the new scores come from:
+        # static facts of the routed solver and of what the caller passed,
+        # counted at dispatch (nothing is read back).
+        margins = carries_margins(self.objective, self.optimizer_spec)
+        source = "solver_margins" if margins else "fused_pass"
+        if not margins or (initial_model is not None and initial_scores is None):
+            start, start_score = "recomputed", None
+        elif initial_model is not None:
+            start, start_score = "prior_score", initial_scores
+        elif initial_scores is not None:
+            start, start_score = "zero", initial_scores
+        else:
+            start, start_score = "zero", jnp.zeros((lb.n,), lb.label.dtype)
+        registry().counter(
+            "fe_start_margins_total", coordinate=self.coordinate_id, source=start
+        ).inc()
+        registry().counter(
+            "fe_score_source_total", coordinate=self.coordinate_id, source=source
+        ).inc()
         solve = self.solve_cache.fe_solver(self.objective, self.optimizer_spec)
         # Host-wall span of the dispatch (the solve itself runs async on
         # device; nothing here blocks).
         with span("fe_solve"):
-            result = solve(w0, lb)
+            result, scores = solve(w0, lb, start_score)
         # The label the tracker's read publishes this solve under (a static
         # field: no device work).
         result = dataclasses.replace(result, coordinate=self.coordinate_id)
@@ -107,7 +151,7 @@ class FixedEffectCoordinate(Coordinate):
             GeneralizedLinearModel(Coefficients(w_model, variances), self.task),
             self.feature_shard,
         )
-        return model, result
+        return model, result, scores
 
     def train_from_stream(
         self,
@@ -133,7 +177,9 @@ class FixedEffectCoordinate(Coordinate):
         )
 
     def score(self, model: FixedEffectModel, batch: GameBatch) -> Array:
-        return model.score(batch)
+        return _fused_score(
+            batch.features[self.feature_shard], model.model.coefficients.means
+        )
 
     def zero_model(self) -> FixedEffectModel:
         assert self.dim is not None, "dim required for zero_model"

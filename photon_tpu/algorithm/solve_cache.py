@@ -328,31 +328,40 @@ class SolveCache:
         return call
 
     def fe_solver(self, objective, spec) -> Callable:
-        """Jitted fixed-effect solve ``(w0, labeled_batch) -> OptimizeResult``
-        for one (objective, spec). The batch is a traced argument, so the
-        one cache entry serves every batch of the same structure; w0 is NOT
-        donated here (fixed-effect warm starts alias live model buffers)."""
+        """Jitted fixed-effect solve ``(w0, labeled_batch, start_score=None)
+        -> (OptimizeResult, score)`` for one (objective, spec). ``score`` is
+        x·w at the result for every sample (what the model made of it scores
+        on the batch), from the solver's own margins where it carries them
+        and from one pass over X inside the same program where it does not
+        (``make_optimizer(..., with_score=True)``); ``start_score`` is x·w0
+        where the caller holds it, which a margin-carrying solver starts
+        from. The batch is a traced argument, so the one cache entry serves
+        every batch of the same structure; w0 is NOT donated here
+        (fixed-effect warm starts alias live model buffers)."""
         key = ("fe", self._objective_key(objective), self._spec_key(spec))
 
         def build():
             from photon_tpu.optim.common import REASON_DIVERGED
             from photon_tpu.optim.factory import make_optimizer
 
-            solve = make_optimizer(objective, spec)
+            solve = make_optimizer(objective, spec, with_score=True)
             stats = self.stats
 
-            def traced(w0, lb):
+            def traced(w0, lb, start_score=None):
                 stats.traces += 1
                 stats.trace_keys.append(("fe", int(w0.shape[0])))
                 with jax.named_scope("fe_solve"):
-                    res = solve(w0, lb)
+                    res, score = solve(w0, lb, start_score)
                 # Divergence backstop covering every optimizer type: a
-                # non-finite final point falls back to the warm start and is
-                # flagged DIVERGED (L-BFGS additionally rolls back to the
-                # last finite iterate inside its own loop).
+                # non-finite final point falls back to the warm start, its
+                # score with it, and is flagged DIVERGED (L-BFGS additionally
+                # rolls back to the last finite iterate inside its own loop).
                 ok = jnp.all(jnp.isfinite(res.w))
                 w = jnp.where(ok, res.w, w0)
-                return dataclasses.replace(
+                score = jax.lax.cond(
+                    ok, lambda: score, lambda: objective.scores(w0, lb)
+                )
+                res = dataclasses.replace(
                     res,
                     w=w,
                     reason_code=jnp.where(
@@ -360,6 +369,7 @@ class SolveCache:
                     ),
                     nonzeros=jnp.count_nonzero(w).astype(jnp.int32),
                 )
+                return res, score
 
             return jax.jit(traced)
 

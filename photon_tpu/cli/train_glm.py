@@ -480,7 +480,7 @@ def run(args) -> Dict:
         t0 = time.monotonic()
         with span(f"glm/lambda{lam:g}"):
             with span("solve"):
-                result = solve(w, train)
+                result, _scores = solve(w, train)
         solver_walls.append(time.monotonic() - t0)
         solver_diags.append(result)
         w = result.w  # warm start (ModelTraining.scala:162-200)

@@ -155,6 +155,16 @@ class SparseFeatures:
 Features = Union[Array, SparseFeatures]
 
 
+def features_dot(features: Features, w: Array) -> Array:
+    """x·w for every sample as ONE pass over the features, whatever their
+    storage: a matvec, where ``Coefficients.compute_score`` multiplies and
+    reduces (bit-stable across batch sizes, which serving needs and training
+    does not; on a TPU it is two launches around an (n, d) temporary)."""
+    if isinstance(features, SparseFeatures):
+        return features.matvec(w)
+    return features @ w
+
+
 @jax.tree_util.register_pytree_node_class
 class LabeledBatch:
     """A batch of labeled samples (struct-of-arrays LabeledPoint).
@@ -189,11 +199,7 @@ class LabeledBatch:
 
     def margins(self, w: Array) -> Array:
         """x·w + offset for every sample (LabeledPoint.computeMargin)."""
-        if isinstance(self.features, SparseFeatures):
-            xw = self.features.matvec(w)
-        else:
-            xw = self.features @ w
-        return xw + self.offset
+        return features_dot(self.features, w) + self.offset
 
     def with_offset(self, offset: Array) -> "LabeledBatch":
         return LabeledBatch(self.label, self.features, offset, self.weight, self.uid)

@@ -31,7 +31,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from photon_tpu.data.batch import LabeledBatch, SparseFeatures
+from photon_tpu.data.batch import LabeledBatch, SparseFeatures, features_dot
 from photon_tpu.data.normalization import NormalizationContext
 from photon_tpu.ops.losses import PointwiseLoss
 
@@ -74,6 +74,15 @@ class GLMObjective:
             ew, es = self.normalization.effective(w)
             return batch.margins(ew) + es
         return batch.margins(w)
+
+    def scores(self, w: Array, batch: LabeledBatch) -> Array:
+        """x·w for every sample, normalization folded: the margins less the
+        batch's offset, and what the model made of ``w`` scores in model
+        space. One pass over X."""
+        if self.normalization is not None and not self.normalization.is_identity:
+            ew, es = self.normalization.effective(w)
+            return features_dot(batch.features, ew) + es
+        return features_dot(batch.features, w)
 
     # ----- regularization -----
 

@@ -9,7 +9,7 @@ L1 weight is present, otherwise the configured solver.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Optional, Tuple
+from typing import Callable, Optional, Tuple, Union
 
 import jax
 
@@ -49,54 +49,87 @@ class OptimizerSpec:
         )
 
 
+def routed_solver(objective: GLMObjective, spec: OptimizerSpec) -> str:
+    """The solver ``make_optimizer`` routes (objective, spec) to over a
+    ``LabeledBatch``, from static facts alone: OWL-QN whenever an L1 term
+    exists (auto-selected or explicit; with l1_weight == 0 OWL-QN degenerates
+    below plain L-BFGS, so a smooth objective never routes there), the
+    configured TRON or L-BFGS-B, else margin-space L-BFGS
+    (photon_tpu.optim.margin_lbfgs: ~2 X passes an iteration instead of the
+    black-box 2·(1+trials); measured ~3× per solve on a TPU), which is for
+    smooth unconstrained problems: a box routes to the black-box L-BFGS."""
+    if objective.l1_weight > 0.0:
+        return "owlqn"
+    if spec.optimizer == OptimizerType.TRON:
+        return "tron"
+    if spec.optimizer == OptimizerType.LBFGSB:
+        return "lbfgsb"
+    return "lbfgs_margin" if spec.box is None else "lbfgs"
+
+
+def carries_margins(objective: GLMObjective, spec: OptimizerSpec) -> bool:
+    """Whether the routed solver holds x·w through its loop, and so can start
+    from a score the caller holds and hand the new one back without a pass
+    over X. The others are black boxes over ``value_and_grad``."""
+    return routed_solver(objective, spec) == "lbfgs_margin"
+
+
 def make_optimizer(
-    objective: GLMObjective, spec: OptimizerSpec
-) -> Callable[[Array, object], OptimizeResult]:
+    objective: GLMObjective, spec: OptimizerSpec, with_score: bool = False
+) -> Callable[..., Union[OptimizeResult, Tuple[OptimizeResult, Array]]]:
     """Return solve(w0, batch) -> OptimizeResult for the given objective.
 
     OWL-QN is auto-selected when the objective carries an L1 weight
     (reference RegularizationContext L1/elastic-net routing via OWLQN.scala).
+
+    ``with_score`` makes it solve(w0, batch, start_score=None) -> (result,
+    score), ``score`` being x·w at ``result.w`` (``GLMObjective.scores``): a
+    solver that carries margins takes ``start_score`` (x·w0) in and hands its
+    own back; any other ignores it and pays ONE pass over X at the end.
     """
     config = spec.config()
+    routed = routed_solver(objective, spec)
 
-    def run(name: str, minimize: Callable[..., OptimizeResult], *args, **kwargs):
+    def solve(w0: Array, batch, start_score: Optional[Array] = None):
+        vg = lambda w: objective.value_and_grad(w, batch)
+        name = routed
+        if name == "lbfgs_margin" and not isinstance(batch, LabeledBatch):
+            name = "lbfgs"
+        score = None
         # The solver's name is the scope of its operations in a profile
         # (``fe_solve/owlqn`` under the solve cache's scope) and the label
         # its result is published under when the tracker is read.
         with jax.named_scope(name):
-            return dataclasses.replace(minimize(*args, **kwargs), optimizer=name)
-
-    def solve(w0: Array, batch) -> OptimizeResult:
-        vg = lambda w: objective.value_and_grad(w, batch)
-        # OWL-QN whenever an L1 term exists (auto-selected or explicit) —
-        # with l1_weight == 0 OWL-QN degenerates below plain L-BFGS (orthant
-        # projection still pins sign-crossing coordinates), so a smooth
-        # objective always routes to L-BFGS regardless of the spec.
-        if objective.l1_weight > 0.0:
-            return run(
-                "owlqn", minimize_owlqn, vg, w0, objective.l1_weight, config,
-                objective.l1_mask(w0),
-            )
-        if spec.optimizer == OptimizerType.TRON:
-            # Factory form: margins/curvature built once per outer iteration,
-            # shared across that iteration's CG products (2 X passes each).
-            return run(
-                "tron", minimize_tron, vg, None, w0, config, spec.max_cg_iter,
-                spec.box,
-                hvp_factory=lambda w: objective.linearized_hvp(w, batch),
-            )
-        if spec.optimizer == OptimizerType.LBFGSB:
-            assert spec.box is not None, "LBFGSB requires a box"
-            return run(
-                "lbfgsb", minimize_lbfgsb, vg, w0, spec.box[0], spec.box[1], config
-            )
-        # Smooth unconstrained GLM over a LabeledBatch: margin-space L-BFGS
-        # (photon_tpu.optim.margin_lbfgs) — ~2 X passes/iteration instead of
-        # the black-box 2·(1+trials); measured ~3× per-solve on TPU.
-        if spec.box is None and isinstance(batch, LabeledBatch):
-            return run(
-                "lbfgs_margin", minimize_lbfgs_margin, objective, batch, w0, config
-            )
-        return run("lbfgs", minimize_lbfgs, vg, w0, config, spec.box)
+            if name == "owlqn":
+                res = minimize_owlqn(
+                    vg, w0, objective.l1_weight, config, objective.l1_mask(w0)
+                )
+            elif name == "tron":
+                # Factory form: margins/curvature built once per outer
+                # iteration, shared across that iteration's CG products (2 X
+                # passes each).
+                res = minimize_tron(
+                    vg, None, w0, config, spec.max_cg_iter, spec.box,
+                    hvp_factory=lambda w: objective.linearized_hvp(w, batch),
+                )
+            elif name == "lbfgsb":
+                assert spec.box is not None, "LBFGSB requires a box"
+                res = minimize_lbfgsb(vg, w0, spec.box[0], spec.box[1], config)
+            elif name == "lbfgs_margin":
+                res = minimize_lbfgs_margin(
+                    objective, batch, w0, config,
+                    start_score=start_score, return_score=with_score,
+                )
+                if with_score:
+                    res, score = res
+            else:
+                res = minimize_lbfgs(vg, w0, config, spec.box)
+        res = dataclasses.replace(res, optimizer=name)
+        if not with_score:
+            return res
+        if score is None:
+            with jax.named_scope("score"):
+                score = objective.scores(res.w, batch)
+        return res, score
 
     return solve

@@ -31,7 +31,7 @@ affinity; those route through optim.lbfgs / optim.owlqn).
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -57,7 +57,9 @@ def minimize_lbfgs_margin(
     w0: Array,
     config: OptimizerConfig = OptimizerConfig(),
     l2_override: Optional[Array] = None,
-) -> OptimizeResult:
+    start_score: Optional[Array] = None,
+    return_score: bool = False,
+) -> Union[OptimizeResult, Tuple[OptimizeResult, Array]]:
     """L-BFGS over a GLMObjective exploiting margin affinity.
 
     Semantically equivalent to ``minimize_lbfgs(objective.value_and_grad...)``
@@ -68,6 +70,16 @@ def minimize_lbfgs_margin(
     ``l2_override`` replaces the objective's static L2 weight with a TRACED
     scalar — the hook that lets ``sweep_l2_lbfgs_margin`` vmap one program
     over a whole λ grid.
+
+    Margins cross the boundary both ways, for a caller (coordinate descent)
+    that keeps x·w beside the model. ``start_score`` is x·w0 for every sample
+    (the margins at ``w0`` less the batch's offset) where the caller holds it:
+    the solve then starts without its pass over X, and ``evals`` counts one
+    pass fewer. With ``return_score`` the result comes as ``(result, score)``,
+    ``score`` being x·w at ``result.w``: the margins the loop carried, less
+    the offset, O(n) and no pass over X. (On the fused Pallas path every
+    gradient pass yields fresh margins, the first one too, so ``start_score``
+    saves nothing there and is not read.)
     """
     if objective.l1_weight > 0.0:
         raise ValueError("margin L-BFGS is for smooth objectives; use OWL-QN for L1")
@@ -154,10 +166,14 @@ def minimize_lbfgs_margin(
         f0, g0, z0 = fused_value_grad_margins(w0)
         init_evals = 1  # one fused pass
     else:
-        z0 = objective.margins(w0, batch)
+        if start_score is None:
+            z0 = objective.margins(w0, batch)
+            init_evals = 2  # margins + gradient passes
+        else:
+            z0 = start_score + offset
+            init_evals = 1  # the gradient pass alone
         f0 = data_value(z0) + l2_value(w0)
         g0 = grad_from_margins(z0, w0)
-        init_evals = 2  # margins + gradient passes
     g0_norm = jnp.linalg.norm(g0)
 
     hist_len = config.history_len
@@ -270,7 +286,7 @@ def minimize_lbfgs_margin(
     reason = jnp.where(
         st["reason"] == REASON_NOT_CONVERGED, REASON_MAX_ITERATIONS, st["reason"]
     )
-    return OptimizeResult(
+    result = OptimizeResult(
         w=st["w"],
         value=st["f"],
         grad_norm=final_gnorm,
@@ -281,6 +297,9 @@ def minimize_lbfgs_margin(
         evals=st["evals"],
         eval_unit="x_passes",
     )
+    if return_score:
+        return result, st["z"] - offset
+    return result
 
 
 def sweep_l2_lbfgs_margin(

@@ -356,7 +356,7 @@ def test_fe_solver_rolls_back_non_finite_to_warm_start():
         GLMObjective(loss=LogisticLoss, l2_weight=0.1, intercept_index=0),
         OptimizerSpec(),
     )
-    res = solve(jnp.zeros((d,), jnp.float32), lb)
+    res, _scores = solve(jnp.zeros((d,), jnp.float32), lb)
     w = np.asarray(res.w)
     assert np.isfinite(w).all() and (w == 0.0).all()  # rolled back to w0
     assert res.convergence_reason == ConvergenceReason.DIVERGED

@@ -392,7 +392,11 @@ def test_quiet_log_builds_no_summary_and_launches_nothing_after_the_last_update(
 
 # --- (b) at INFO the log's text is what it was ----------------------------------
 
-# PR 33's tree on this file's data (seed 34), the wall times masked.
+# This file's data (seed 34), the wall times masked. The layout is PR 33's.
+# The numbers after the first fixed-effect solve are PR 37's: since then the
+# fixed effect's scores are the margins its solver carried, which differ from
+# ``compute_score``'s in the last bits, and at this size that moves a user's
+# Newton loop by an iteration (a fresh ``X @ w`` in their place moves it too).
 SUMMARY_AT_PR33 = """\
 -- coordinate 'global', CD pass 0 (wall W)
    iter    loss           |grad|
@@ -404,19 +408,19 @@ SUMMARY_AT_PR33 = """\
    reason: FUNCTION_VALUES_CONVERGED
 -- coordinate 'global', CD pass 1 (wall W)
    iter    loss           |grad|
-      0    3.225071e+02   7.273771e+01
-      1    3.065580e+02   4.210083e+01
-      2    2.992514e+02   5.106182e+00
-      3    2.991216e+02   5.403436e-01
-      4    2.991203e+02   5.353421e-02
-      5    2.991203e+02   6.355674e-03
-      6    2.991203e+02   1.058014e-03
-      7    2.991203e+02   2.368923e-04
+      0    3.225063e+02   7.274036e+01
+      1    3.065534e+02   4.209774e+01
+      2    2.992480e+02   5.105512e+00
+      3    2.991182e+02   5.402415e-01
+      4    2.991170e+02   5.354795e-02
+      5    2.991169e+02   6.353940e-03
+      6    2.991169e+02   1.054558e-03
+      7    2.991169e+02   1.054558e-03
    reason: FUNCTION_VALUES_CONVERGED
 -- coordinate 'per_user', CD pass 0 (wall W)
-   entities=12 converged=12 hit_max_iter=0 quarantined=0 iters(mean=5.9, max=16)
+   entities=12 converged=12 hit_max_iter=0 quarantined=0 iters(mean=5.8, max=16)
 -- coordinate 'per_user', CD pass 1 (wall W)
-   entities=12 converged=12 hit_max_iter=0 quarantined=0 iters(mean=3.2, max=4)"""
+   entities=12 converged=12 hit_max_iter=0 quarantined=0 iters(mean=3.6, max=6)"""
 
 _FLOAT = re.compile(r"\d\.\d{6}e[+-]\d\d")
 
@@ -471,10 +475,8 @@ FIT_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(FIT_CASES))
-def test_second_fit_reads_nothing_back_from_the_device(
-    case, glmix, monkeypatch, caplog
-):
+def estimator_of(case):
+    """``(estimator, optimization config, the fixed effect's solver)``."""
     from photon_tpu.estimators.config import (
         FixedEffectCoordinateConfig,
         GameOptimizationConfig,
@@ -482,10 +484,8 @@ def test_second_fit_reads_nothing_back_from_the_device(
         RegularizationConfig,
     )
     from photon_tpu.estimators.game_estimator import GameEstimator
-    from photon_tpu.obs.metrics import registry
 
     task, (weight, alpha), optimizer = FIT_CASES[case]
-    batch = glmix[0]
     estimator = GameEstimator(
         task=task,
         coordinate_configs=[
@@ -500,6 +500,17 @@ def test_second_fit_reads_nothing_back_from_the_device(
         "global": RegularizationConfig(weight=weight, alpha=alpha),
         "per_user": RegularizationConfig(weight=0.5),
     })
+    return estimator, opt, optimizer
+
+
+@pytest.mark.parametrize("case", sorted(FIT_CASES))
+def test_second_fit_reads_nothing_back_from_the_device(
+    case, glmix, monkeypatch, caplog
+):
+    from photon_tpu.obs.metrics import registry
+
+    estimator, opt, optimizer = estimator_of(case)
+    batch = glmix[0]
     registry().reset()
     with caplog.at_level(logging.WARNING, logger="photon_tpu"):
         (first,) = estimator.fit(batch, optimization_configs=[opt])
@@ -528,3 +539,61 @@ def test_second_fit_reads_nothing_back_from_the_device(
     for a, b in zip(jax.tree_util.tree_leaves(first.model),
                     jax.tree_util.tree_leaves(second.model), strict=True):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# --- (e) the fixed effect's update launches its solve and no score --------------
+
+
+@pytest.mark.parametrize("case", sorted(FIT_CASES))
+def test_warm_fit_fe_update_launches_no_eager_score(case, glmix, monkeypatch, caplog):
+    """The fixed effect's scores come out of its solve program: between the
+    entry and the return of its update a warm fit launches no eager multiply
+    and no reduction (``compute_score``'s two), and the two counters of the
+    seam say, from static facts and without a device read, where each solve's
+    starting margins and each new score came from."""
+    from photon_tpu.obs.metrics import registry
+
+    estimator, opt, optimizer = estimator_of(case)
+    batch = glmix[0]
+    with caplog.at_level(logging.WARNING, logger="photon_tpu"):
+        estimator.fit(batch, optimization_configs=[opt])
+        registry().reset()
+        with compiles() as seen, host_reads(monkeypatch) as read:
+            real_update = FixedEffectCoordinate.update
+
+            def update(self, *args, **kwargs):
+                seen.mark("FE UPDATE")
+                try:
+                    return real_update(self, *args, **kwargs)
+                finally:
+                    seen.mark("FE DONE")
+
+            monkeypatch.setattr(FixedEffectCoordinate, "update", update)
+            (second,) = estimator.fit(batch, optimization_configs=[opt])
+            jax.block_until_ready(jax.tree_util.tree_leaves(second.model))
+    assert read == []
+    assert seen.names.count("FE UPDATE") == seen.names.count("FE DONE") == 2
+    inside, is_inside = [], False
+    for name in seen.names:
+        if name in ("FE UPDATE", "FE DONE"):
+            is_inside = name == "FE UPDATE"
+        elif is_inside:
+            inside.append(name)
+    # One solve program a pass (compiled once: the second pass hits it), the
+    # residual's add, the zeros of the start; no multiply, no reduction.
+    assert inside.count("jit(traced)") == 1, inside
+    assert not [n for n in inside if "multiply" in n or "reduce" in n], inside
+
+    def counted(name, source):
+        found = registry().find(name, coordinate="global", source=source)
+        return 0 if found is None else found.value
+
+    if optimizer == "lbfgs_margin":
+        want = dict(solver_margins=2, fused_pass=0, zero=1, prior_score=1, recomputed=0)
+    else:
+        want = dict(solver_margins=0, fused_pass=2, zero=0, prior_score=0, recomputed=2)
+    got = {s: counted("fe_score_source_total", s)
+           for s in ("solver_margins", "fused_pass")}
+    got.update({s: counted("fe_start_margins_total", s)
+                for s in ("zero", "prior_score", "recomputed")})
+    assert got == want
