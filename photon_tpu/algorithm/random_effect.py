@@ -97,11 +97,45 @@ class RandomEffectTrackerStats:
     # (T,) int32 training rows of each entity, where the coordinate knows
     # them in tracker order (a full resident pass); None otherwise.
     samples: Optional[Array] = None
+    # Which coordinate's pass this is and the allocated lanes of each block
+    # it dispatched, in tracker order: what the lane-iteration counters are
+    # published under and cut by when the tracker is read. Empty where no
+    # coordinate made it (a merge of shards).
+    coordinate: str = dataclasses.field(default="", metadata=dict(static=True))
+    block_lanes: Tuple[int, ...] = dataclasses.field(
+        default=(), metadata=dict(static=True)
+    )
 
     @staticmethod
     def empty() -> "RandomEffectTrackerStats":
         z = jnp.zeros((0,), jnp.int32)
         return RandomEffectTrackerStats(z, z, jnp.zeros((0,), bool))
+
+    def _publish_lane_iterations(self, valid: np.ndarray, iters: np.ndarray) -> None:
+        """What lockstep costs, into the registry, once a tracker: a block's
+        Newton loop runs until its slowest entity stops, so every real lane
+        of it is RUN for that many iterations whatever it USED itself.
+        ``re_lane_iterations_used_total`` is Σ over entities of their own
+        iterations, ``re_lane_iterations_run_total`` Σ over blocks of real
+        lanes × the block's longest; from the host copy a read already made."""
+        if self.__dict__.get("_published") or not self.coordinate:
+            return
+        object.__setattr__(self, "_published", True)
+        if sum(self.block_lanes) != iters.size or not iters.size:
+            return
+        from photon_tpu.obs.metrics import registry
+
+        starts = np.cumsum((0,) + self.block_lanes[:-1])
+        run = np.maximum.reduceat(iters, starts) * np.add.reduceat(
+            valid.astype(np.int64), starts
+        )
+        labels = dict(coordinate=self.coordinate)
+        registry().counter("re_lane_iterations_used_total", **labels).inc(
+            int(iters.sum())
+        )
+        registry().counter("re_lane_iterations_run_total", **labels).inc(
+            int(run.sum())
+        )
 
     def _aggregates(self) -> dict:
         """The seven aggregates from one transfer. Counts and the mean's
@@ -111,6 +145,7 @@ class RandomEffectTrackerStats:
         valid = h.valid
         iters = np.where(valid, h.iterations, 0).astype(np.int64)
         entities = int(valid.sum())
+        self._publish_lane_iterations(valid, iters)
 
         def count(*codes) -> int:
             return int((np.isin(h.reasons, codes) & valid).sum())
@@ -340,10 +375,11 @@ def _merge_block_results(coefs: Array, entity_idx, ws, iterations, reasons):
     a coordinate with the active set on, whose block count changes from pass
     to pass, keeps the per-block scatters on every pass."""
     E, d = coefs.shape
-    for idx, w in zip(entity_idx, ws):
-        coefs = coefs.at[jnp.where(idx >= 0, idx, E)].set(
-            w[:, :d].astype(coefs.dtype), mode="drop"
-        )
+    with jax.named_scope("re_table_scatter"):
+        for idx, w in zip(entity_idx, ws):
+            coefs = coefs.at[jnp.where(idx >= 0, idx, E)].set(
+                w[:, :d].astype(coefs.dtype), mode="drop"
+            )
     return (
         coefs,
         jnp.concatenate([jnp.ravel(i) for i in iterations]).astype(jnp.int32),
@@ -898,7 +934,8 @@ class RandomEffectCoordinate(Coordinate):
                     w[:, :d].astype(coefs.dtype), mode="drop"
                 )
             stats = self._tracker_stats(
-                [(b.entity_idx, it, rs) for b, _w, it, rs in results]
+                [(b.entity_idx, it, rs) for b, _w, it, rs in results],
+                self.coordinate_id,
             )
         else:
             # Without it a pass dispatches the dataset's blocks in order, so
@@ -911,7 +948,9 @@ class RandomEffectCoordinate(Coordinate):
                 [rs for *_, rs in results],
             )
             stats = RandomEffectTrackerStats(
-                iters, reasons, valid, samples=self.dataset.lane_samples
+                iters, reasons, valid, samples=self.dataset.lane_samples,
+                coordinate=self.coordinate_id,
+                block_lanes=tuple(b.num_entities for b, *_ in results),
             )
 
         variances = None
@@ -1110,7 +1149,7 @@ class RandomEffectCoordinate(Coordinate):
             coefs_out, self.dataset.config.re_type,
             self.dataset.config.feature_shard, self.task, None,
         )
-        return model, self._tracker_stats(results_host)
+        return model, self._tracker_stats(results_host, self.coordinate_id)
 
     def _train_projected(
         self, total_offset: Array, initial_model
@@ -1227,7 +1266,7 @@ class RandomEffectCoordinate(Coordinate):
                 else None
             ),
         )
-        return model, self._tracker_stats(parts)
+        return model, self._tracker_stats(parts, self.coordinate_id)
 
     def _initial_block_coefs(self, block, block_index: int, initial_model) -> Array:
         """Warm-start coefficients in block space from either model form.
@@ -1286,7 +1325,7 @@ class RandomEffectCoordinate(Coordinate):
         return variances
 
     @staticmethod
-    def _tracker_stats(parts) -> RandomEffectTrackerStats:
+    def _tracker_stats(parts, coordinate: str = "") -> RandomEffectTrackerStats:
         """Assemble the on-device tracker from per-block
         ``(entity_idx, iterations, reasons)`` triples — concatenations only,
         NO device→host transfer (aggregates materialize in ``summary()``)."""
@@ -1299,6 +1338,8 @@ class RandomEffectCoordinate(Coordinate):
             iterations=iters.astype(jnp.int32),
             reasons=reasons.astype(jnp.int32),
             valid=valid,
+            coordinate=coordinate,
+            block_lanes=tuple(int(np.size(e)) for e, _i, _r in parts),
         )
 
     def score(self, model, batch: GameBatch) -> Array:

@@ -299,6 +299,22 @@ class GameEstimator:
                     registry().gauge("re_block_geometries", **labels).set(
                         len({b.features.shape for b in ds.blocks})
                     )
+                    # What the population is: entities that hold rows, those
+                    # of them with at least as many rows as coefficients
+                    # (the rest are under-determined: only the penalty
+                    # bounds them), and the widest block's lanes.
+                    rows = np.asarray(
+                        ds.lane_samples if ds.blocks else np.zeros((0,), np.int32)
+                    )
+                    registry().gauge("re_entities", **labels).set(
+                        int(np.sum(rows > 0))
+                    )
+                    registry().gauge("re_entities_rows_ge_dim", **labels).set(
+                        int(np.sum(rows >= ds.dim))
+                    )
+                    registry().gauge("re_lanes_max", **labels).set(
+                        max((b.num_entities for b in ds.blocks), default=0)
+                    )
         self._prepared_for = batch
 
     def _group_entities(self, cfg, eids, feats, label_np, weight_np, uid_np):

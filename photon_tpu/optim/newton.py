@@ -29,6 +29,13 @@ at ``f32[3072,16,16]`` it took 5.16 ms inside a loop and was 42–50 % of a
 GLMix fit, where the unrolled steps take 0.016 ms; both land 4e-7 to 6e-7
 from a float64 solve (chip runs of PR 32, PERF.md §6).
 
+Two more static sizes shape the loop's body (PR 38, a block of 163,840
+users of up to 4 rows): an entity with fewer than ``ROWS_ON_MXU_MIN`` rows
+takes its three products over the rows as multiply-and-reduce, which XLA
+lays out with the entities minor, and from ``SPD_LANE_CHUNK`` lanes the
+column steps run a chunk of lanes at a time, so that their intermediates
+stay in the chip's fast memory.
+
 Damping follows the Levenberg accept/reject pattern (the scalar analogue of
 TRON's trust-region radius update, TRON.scala:93-94): a rejected step keeps
 the iterate and multiplies the damping by 10; an accepted step shrinks it.
@@ -51,6 +58,7 @@ from photon_tpu.optim.common import (
     OptimizeResult,
     OptimizerConfig,
     REASON_DIVERGED,
+    REASON_FUNCTION_VALUES_CONVERGED,
     REASON_MAX_ITERATIONS,
     REASON_NOT_CONVERGED,
     check_convergence,
@@ -82,6 +90,32 @@ SPD_UNROLL_MAX_DIM = 32
 # PR 32). The library call costs 1.4 µs a matrix: 6 to 110 µs an iteration
 # up to 72 lanes against the steps' 6, 4 ms of a 0.37 s fit (PERF.md §6).
 SPD_UNROLL_MIN_LANES = 128
+
+# Fewest rows of one entity for which the three products over its rows (the
+# margins X·w, the gradient Xᵀ·dz and the Hessian Xᵀ·diag(d2)·X) are matrix
+# products. Under it the contraction is a fraction of one pass of the matrix
+# unit, which gains nothing, and the layout costs: under the entity ``vmap``
+# XLA keeps a ``dot_general``'s batch axis major, so a block of 163,840
+# users of up to 4 rows wrote its Hessians as f32[163840,16,16] with the 16
+# columns padded to a 128-lane tile, 1.34 GB an iteration where 168 MB are
+# data, and carried its (163840, 4) and (163840, 16) vectors 32 and 8 times
+# padded. Written as multiply and reduce the same sums are laid out with the
+# entities minor (compiled for the v5e: 5.0 GB of outputs a Newton iteration
+# of that block before, 2.7 GB after; PERF.md §6, PR 38). Exact float32,
+# where the matrix unit rounds its operands. No block of the cells that
+# existed before has fewer than 96 rows a lane.
+ROWS_ON_MXU_MIN = 64
+
+# Lanes the unrolled column steps take at a time. Each step rewrites what is
+# left of every system, ~1,500 floats a lane over the 16 steps at d = 16:
+# for all 163,840 lanes of a block at once every intermediate is 20–160 MB
+# and goes through HBM (1.4 GB of outputs a solve); 4,096 lanes at a time
+# they are 0.5–4 MB and the compiler keeps every one of them in fast memory
+# (compiled for the v5e: each ``slice_subtract_fusion`` output in memory
+# space 1). The same operations on every lane, so the same bits. A lane
+# count it does not divide (the plan's are multiples of 1/16 of a power of
+# two, so from 65,536 lanes all are divided) is solved whole.
+SPD_LANE_CHUNK = 4096
 
 
 @jax.jit
@@ -144,7 +178,8 @@ def _solve_by_lanes_vmap(axis_size, in_batched, A, b):
     tile of its own and the entities one after another, where ``(d, d, E)``
     lays the entities along the lanes. An outer ``vmap`` of this
     (``batched_tuning`` maps λ over the entity map) puts its axis in front
-    of every step, so the lanes stay minor."""
+    of every step, so the lanes stay minor. Over ``SPD_LANE_CHUNK`` lanes the
+    steps take that many at a time, sliced along the lane axis."""
     if spd_solve_lowering(A.shape[-1], axis_size) == "library":
         in_axes = tuple(0 if batched else None for batched in in_batched)
         return jax.vmap(_solve_library, in_axes)(A, b), True
@@ -154,7 +189,19 @@ def _solve_by_lanes_vmap(axis_size, in_batched, A, b):
             return jnp.moveaxis(x, 0, -1)
         return jnp.broadcast_to(x[..., None], x.shape + (axis_size,))
 
-    x = _solve_columns(last(A, in_batched[0]), last(b, in_batched[1]))
+    A, b = last(A, in_batched[0]), last(b, in_batched[1])
+    if axis_size > SPD_LANE_CHUNK and axis_size % SPD_LANE_CHUNK == 0:
+        def chunk(i, x):
+            at = i * SPD_LANE_CHUNK
+            part = _solve_columns(
+                lax.dynamic_slice_in_dim(A, at, SPD_LANE_CHUNK, axis=2),
+                lax.dynamic_slice_in_dim(b, at, SPD_LANE_CHUNK, axis=1),
+            )
+            return lax.dynamic_update_slice_in_dim(x, part, at, axis=1)
+
+        x = lax.fori_loop(0, axis_size // SPD_LANE_CHUNK, chunk, jnp.zeros_like(b))
+    else:
+        x = _solve_columns(A, b)
     return jnp.moveaxis(x, -1, 0), True
 
 
@@ -209,6 +256,36 @@ def minimize_newton(
     dtype = w0.dtype
     m_iter, tol = config.max_iter, config.tol
 
+    # The three products over the rows. From ROWS_ON_MXU_MIN rows they are
+    # matrix products; under it, the same sums written as multiply and
+    # reduce (exact float32, no matrix unit).
+    if X.shape[0] >= ROWS_ON_MXU_MIN:
+        def margins(w):
+            return X @ w + offset
+
+        def gradient(dz):
+            return X.T @ dz
+
+        def hessian(d2):
+            return jnp.einsum("nd,n,ne->de", X, d2, X)
+
+        def hessian_diagonal(d2, H):
+            return jnp.diagonal(H)
+    else:
+        def margins(w):
+            return jnp.sum(X * w[None, :], axis=1) + offset
+
+        def gradient(dz):
+            return jnp.sum(X * dz[:, None], axis=0)
+
+        def hessian(d2):
+            return jnp.sum((X * d2[:, None])[:, :, None] * X[:, None, :], axis=0)
+
+        def hessian_diagonal(d2, H):
+            # The same products in the same order as H's own diagonal, with
+            # no gather out of the (entities, d, d) array under the vmap.
+            return jnp.sum((X * d2[:, None]) * X, axis=0)
+
     def _l2_mask(w: Array) -> Array:
         if objective.intercept_index is None:
             return w
@@ -229,7 +306,7 @@ def minimize_newton(
         if objective.intercept_index is not None:
             lam_diag = lam_diag.at[objective.intercept_index].set(0.0)
 
-    z0 = X @ w0 + offset
+    z0 = margins(w0)
     f0 = data_value(z0) + l2_value(w0)
 
     hist_len = config.history_len
@@ -255,8 +332,9 @@ def minimize_newton(
         # --- pass 1: gradient + Hessian from the carried margins ---
         dz = weight * loss.dz(z, label)
         d2 = weight * loss.dzz(z, label)
-        g = X.T @ dz + (l2 * _l2_mask(w) if has_l2 else 0.0)
-        H = jnp.einsum("nd,n,ne->de", X, d2, X) + jnp.diag(lam_diag)
+        g = gradient(dz) + (l2 * _l2_mask(w) if has_l2 else 0.0)
+        H0 = hessian(d2)
+        H = H0 + jnp.diag(lam_diag)
         gnorm = jnp.linalg.norm(g)
         g0_norm = jnp.where(st["it"] == 0, gnorm, st["g0_norm"])
 
@@ -267,7 +345,7 @@ def minimize_newton(
         # l2 = 0) still becomes positive-definite under damping instead of
         # failing the factorisation forever — the dead direction then gets
         # step p_j = −g_j/(μ·floor) = 0 since g_j = 0 too.
-        diag_h = jnp.diagonal(H)
+        diag_h = hessian_diagonal(d2, H0) + lam_diag
         floor = 1e-7 * jnp.maximum(jnp.max(diag_h), 1.0)
         Hd = H + st["mu"] * jnp.diag(jnp.maximum(diag_h, floor))
         p = -spd_solve(Hd, g)
@@ -280,7 +358,7 @@ def minimize_newton(
         # what globalizes pure Newton on exp-like losses (Poisson) without
         # burning a full iteration per rejected step.
         w_try = w + p
-        z_try = X @ w_try + offset
+        z_try = margins(w_try)
         u = z_try - z
         ts = jnp.asarray([1.0, 0.5, 0.25, 0.125, 1 / 16, 1 / 32, 1 / 64], dtype)
 
@@ -289,8 +367,14 @@ def minimize_newton(
 
         fs = jax.vmap(f_at)(ts)
         fs = jnp.where(jnp.isnan(fs), jnp.inf, fs)  # failed solve → reject
+        # The best trial's value and step with no index into ``fs`` or
+        # ``ts``: under the entity vmap each index is a gather of one element
+        # a lane, and the one out of ``fs`` took 1.35 of the 4.65 ms of an
+        # iteration over 163,840 lanes (chip run, PR 38). The same numbers:
+        # the minimum is the element argmin points at, the sum has one term.
         ib = jnp.argmin(fs)
-        f_best, t_best = fs[ib], ts[ib]
+        f_best = jnp.min(fs)
+        t_best = jnp.sum(jnp.where(jnp.arange(ts.shape[0]) == ib, ts, 0.0))
         # <= so ties at f32 resolution near the optimum still step (the
         # gradient keeps contracting).
         accept = f_best <= f
@@ -315,6 +399,21 @@ def minimize_newton(
         # precisely "can't improve" (a genuinely bad rejected step has a
         # large |f_best − f| and keeps iterating with boosted damping).
         reason = check_convergence(f_best, f, gnorm, g0_norm, tol, it, m_iter)
+        # A REJECTED step whose best trial is above f by no more than the
+        # dtype resolves f cannot be improved on either. The value test
+        # misses it wherever one ulp of f is over tol·f (float32, tol 1e-7:
+        # every f whose mantissa is under 1.19, a quarter of all values),
+        # and no later step can end it: the trials are judged from fresh
+        # margins X·w against the CARRIED f, whose margins were summed step
+        # by step, so even p = 0 under any damping reads that one ulp above,
+        # and the loop ran to max_iter (one 16-row user in 4,096 held its
+        # whole block for 100 iterations; PERF.md §6, PR 38).
+        stalled = ~accept & (f_best - f <= 2.0 * jnp.finfo(dtype).eps * jnp.abs(f))
+        reason = jnp.where(
+            stalled & (reason == REASON_NOT_CONVERGED),
+            jnp.int32(REASON_FUNCTION_VALUES_CONVERGED),
+            reason,
+        )
         # Divergence guard: a non-finite carried objective can never be
         # improved (every trial compares False against NaN, so the reject
         # branch keeps the iterate forever). Flag DIVERGED and stop; the

@@ -397,6 +397,9 @@ def test_quiet_log_builds_no_summary_and_launches_nothing_after_the_last_update(
 # fixed effect's scores are the margins its solver carried, which differ from
 # ``compute_score``'s in the last bits, and at this size that moves a user's
 # Newton loop by an iteration (a fresh ``X @ w`` in their place moves it too).
+# The last line is PR 38's: a user whose rejected step sat one ulp above its
+# objective ended two iterations later, once the damping had flattened the
+# step (mean 3.6, max 6); the Newton loop now ends such a user at the reject.
 SUMMARY_AT_PR33 = """\
 -- coordinate 'global', CD pass 0 (wall W)
    iter    loss           |grad|
@@ -420,7 +423,7 @@ SUMMARY_AT_PR33 = """\
 -- coordinate 'per_user', CD pass 0 (wall W)
    entities=12 converged=12 hit_max_iter=0 quarantined=0 iters(mean=5.8, max=16)
 -- coordinate 'per_user', CD pass 1 (wall W)
-   entities=12 converged=12 hit_max_iter=0 quarantined=0 iters(mean=3.6, max=6)"""
+   entities=12 converged=12 hit_max_iter=0 quarantined=0 iters(mean=3.4, max=4)"""
 
 _FLOAT = re.compile(r"\d\.\d{6}e[+-]\d\d")
 

@@ -14,6 +14,8 @@ from photon_tpu.ops.objective import GLMObjective
 from photon_tpu.optim.common import OptimizerConfig
 from photon_tpu.optim.lbfgs import minimize_lbfgs
 from photon_tpu.optim.newton import (
+    ROWS_ON_MXU_MIN,
+    SPD_LANE_CHUNK,
     SPD_UNROLL_MAX_DIM,
     SPD_UNROLL_MIN_LANES,
     minimize_newton,
@@ -264,6 +266,80 @@ def test_newton_vmapped_entities_lands_on_the_float64_optimum():
         assert np.linalg.norm(grad) < 1e-10
         got = np.asarray(w_batch[e], np.float64)
         assert np.linalg.norm(got - w) <= 5e-4 * np.linalg.norm(w)
+
+
+def test_spd_solve_in_lane_chunks_is_the_unchunked_solve_bit_for_bit():
+    """Over ``SPD_LANE_CHUNK`` lanes the column steps run a chunk of lanes
+    at a time (each chunk's intermediates fit the chip's fast memory): the
+    same operations on every lane, so the same bits; a lane count the chunk
+    does not divide stays whole."""
+    d = 8
+    rng = np.random.default_rng(11)
+    for lanes in (2 * SPD_LANE_CHUNK, 2 * SPD_LANE_CHUNK + 128):
+        M = rng.normal(size=(lanes, d, d)).astype(np.float32)
+        A = jnp.asarray(M @ M.transpose(0, 2, 1) + 0.5 * np.eye(d, dtype=np.float32))
+        b = jnp.asarray(rng.normal(size=(lanes, d)).astype(np.float32))
+        got = jax.jit(jax.vmap(spd_solve))(A, b)
+        halves = [jax.jit(jax.vmap(spd_solve))(A[a:a + SPD_LANE_CHUNK],
+                                                b[a:a + SPD_LANE_CHUNK])
+                  for a in (0, SPD_LANE_CHUNK)]
+        assert jnp.array_equal(got[:2 * SPD_LANE_CHUNK], jnp.concatenate(halves))
+        resid = jnp.einsum("lde,le->ld", A, got) - b
+        assert float(jnp.max(jnp.abs(resid))) < 1e-3
+
+
+@pytest.mark.parametrize("rows", [1, 3, ROWS_ON_MXU_MIN - 1, ROWS_ON_MXU_MIN])
+def test_newton_on_either_side_of_the_row_count_that_chooses_the_products(rows):
+    """Under ``ROWS_ON_MXU_MIN`` rows the three products over the rows are
+    multiply-and-reduce, from it matrix products: the same optimum, to what
+    float32 leaves, for users with fewer rows than coefficients too."""
+    d = 16
+    X, y, _wt, off = _problem(rows, d, seed=rows)
+    obj = GLMObjective(loss=LogisticLoss, l2_weight=1.0)
+    cfg = OptimizerConfig(max_iter=50, tol=1e-9, track_history=False)
+    res = jax.jit(lambda X, y, off: minimize_newton(
+        obj, LabeledBatch(y, X, off, jnp.ones_like(y)), jnp.zeros(d, jnp.float32), cfg
+    ))(jnp.asarray(X), jnp.asarray(y), jnp.asarray(off))
+    w = np.zeros(d)
+    X64, y64, off64 = (a.astype(np.float64) for a in (X, y, off))
+    for _ in range(60):   # a damped float64 Newton iteration
+        p = 1 / (1 + np.exp(-(X64 @ w + off64)))
+        grad = X64.T @ (p - y64) + w
+        hess = X64.T @ (X64 * (p * (1 - p))[:, None]) + np.eye(d)
+        w = w - 0.5 * np.linalg.solve(hess, grad)
+    assert np.linalg.norm(grad) < 1e-8
+    assert np.linalg.norm(np.asarray(res.w, np.float64) - w) <= 1e-3 * max(np.linalg.norm(w), 1e-2)
+
+
+def test_a_reject_within_an_ulp_of_the_objective_ends_the_loop():
+    """A 16-row user of the few-rows population (``newton_stall_user.npz``:
+    its rows, labels and the fixed effect's scores as the block solver got
+    them in a 4,096-user rehearsal of ``fit.glmix2-fewrows``). At iteration
+    5 its objective is 4.4569006; the next trial reads one ulp above it, a
+    relative 1.07e-7 and so over ``tol`` 1e-7, and every later trial does
+    too (fresh margins against the carried objective): the loop ran to
+    ``max_iter`` and, in lockstep, held its whole block there. It ends at the
+    reject now, on the same iterate."""
+    import os
+
+    from photon_tpu.optim.common import (
+        REASON_FUNCTION_VALUES_CONVERGED,
+        REASON_GRADIENT_CONVERGED,
+    )
+
+    user = np.load(os.path.join(os.path.dirname(__file__), "newton_stall_user.npz"))
+    lanes = SPD_UNROLL_MIN_LANES    # the unrolled solve's rounding, as a large block has it
+    stack = lambda a: jnp.broadcast_to(jnp.asarray(a), (lanes,) + a.shape)
+    obj = GLMObjective(loss=LogisticLoss, l2_weight=1.0)
+    cfg = OptimizerConfig(max_iter=100, tol=1e-7, track_history=False)
+    res = jax.jit(jax.vmap(lambda X, y, off: minimize_newton(
+        obj, LabeledBatch(y, X, off, jnp.ones_like(y)), jnp.zeros(16, jnp.float32), cfg
+    )))(stack(user["features"]), stack(user["label"]), stack(user["offset"]))
+    assert int(jnp.max(res.iterations)) <= 8
+    assert set(np.asarray(res.reason_code).tolist()) <= {
+        REASON_FUNCTION_VALUES_CONVERGED, REASON_GRADIENT_CONVERGED}
+    assert float(res.value[0]) == pytest.approx(4.4569006, rel=1e-6)
+    assert float(res.grad_norm[0]) < 1e-3
 
 
 def test_newton_scale_normalization():

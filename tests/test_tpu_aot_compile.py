@@ -98,9 +98,10 @@ def test_fixed_effect_kernels_compile_for_v5e(v5e, n, d, dtype):
     "lanes,n_max",
     [
         # The blocks the fit cells dispatch (PERF.md §4): fit.glmix2's two,
-        # fit.glmix3's items, and the ends of fit.glmix2-zipf's plan.
+        # fit.glmix3's items, the ends of fit.glmix2-zipf's plan, and the
+        # widest block of fit.glmix2-fewrows (users of up to 4 rows).
         (2304, 512), (2048, 768), (144, 8192), (128, 12288),
-        (3840, 96), (1, 524288),
+        (3840, 96), (1, 524288), (163840, 4),
     ],
 )
 def test_random_effect_block_solve_compiles_for_v5e(v5e, lanes, n_max):
