@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from photon_tpu.algorithm.coordinate import Coordinate
-from photon_tpu.data.game_data import GameBatch
+from photon_tpu.data.game_data import GameBatch, RowLayout
 from photon_tpu.models.game import GameModel
 from photon_tpu.obs.metrics import registry
 from photon_tpu.obs.trace import span
@@ -136,6 +136,7 @@ class CoordinateDescent:
         checkpoint_keep_last: Optional[int] = None,
         emitter=None,  # utils.events.EventEmitter; optimization-log events
         profile: bool = True,
+        layout: RowLayout = RowLayout(),
     ) -> CoordinateDescentResult:
         """Descend; with validation data, tracks the best model seen across
         iterations by the primary metric (descendWithValidation role).
@@ -162,6 +163,10 @@ class CoordinateDescent:
         ``checkpoint_every`` iterations and training RESUMES from the latest
         checkpoint found there — mid-training recovery the reference lacks
         (its warm start is model-only, SURVEY.md §5).
+        A checkpoint keeps its score vectors in the order of the rows as
+        given: ``layout`` is the order ``batch`` was laid out in where the
+        caller laid it out (``GameEstimator``), so a run laid out otherwise,
+        or not at all, resumes them.
         ``checkpoint_keep_last`` caps how many step files survive (the
         writer prunes the oldest after each publish; on a full disk it also
         prunes before retrying). A save that still fails with ENOSPC after
@@ -246,8 +251,11 @@ class CoordinateDescent:
                     )
                 with span("cd/resume_restore"):
                     models = state["models"]
-                    scores = state["scores"]
-                    total_scores = state["total_scores"]
+                    scores = {
+                        cid: layout.from_original(s)
+                        for cid, s in state["scores"].items()
+                    }
+                    total_scores = layout.from_original(state["total_scores"])
                     metric_history = state["metric_history"]
                     best_metric = state["best_metric"]
                     best_model = state["best_model"]
@@ -389,8 +397,11 @@ class CoordinateDescent:
                             checkpoint_dir,
                             dict(
                                 models=models,
-                                scores=scores,
-                                total_scores=total_scores,
+                                scores={
+                                    cid: layout.to_original(s)
+                                    for cid, s in scores.items()
+                                },
+                                total_scores=layout.to_original(total_scores),
                                 metric_history=metric_history,
                                 best_metric=best_metric,
                                 best_model=best_model,

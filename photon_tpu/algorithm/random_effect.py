@@ -359,12 +359,19 @@ def _dense_warm_start(coefs: Array, block: EntityBlock) -> Array:
 
 
 @jax.jit
-def _block_inputs(block: EntityBlock, total_offset: Array, coefs: Array):
+def _block_inputs(block: EntityBlock, runs, total_offset: Array, coefs: Array):
     """What a dense block's solve reads, in ONE launch: its residual offsets
-    gathered from the flat (n,) vector and its warm start gathered from the
-    (E, d) table. Eager, the same work was six launches a block, and a
-    heavy-tailed plan dispatches twenty blocks a pass."""
-    return block.gather_offsets(total_offset), _dense_warm_start(coefs, block)
+    from the flat (n,) vector and its warm start gathered from the (E, d)
+    table. Eager, the same work was six launches a block, and a heavy-tailed
+    plan dispatches twenty blocks a pass. ``runs`` is ``block.runs`` (not a
+    leaf of the block): with them the offsets are read one window a lane,
+    without them gathered one by one."""
+    offs = (
+        block.gather_offsets(total_offset)
+        if runs is None
+        else runs.offsets(total_offset, block.n_max)
+    )
+    return offs, _dense_warm_start(coefs, block)
 
 
 @jax.jit
@@ -886,7 +893,7 @@ class RandomEffectCoordinate(Coordinate):
         pending = []
         with span("re_dispatch_blocks"):
             for block, obj, mask, sb, sr in entries:
-                offs, w0 = _block_inputs(block, total_offset, coefs)
+                offs, w0 = _block_inputs(block, block.runs, total_offset, coefs)
                 offs = faults.poison("solve.re_block", offs)
                 solver = self.solve_cache.block_solver(
                     obj, self.optimizer_spec, self._config,

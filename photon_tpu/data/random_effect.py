@@ -237,7 +237,55 @@ class RandomEffectDataConfig:
     subspace_projection: Optional[bool] = None
 
 
+# A block whose lanes are each one contiguous run of the batch reads its
+# residual offsets as one window of n_max a lane (``LaneRuns.offsets``) where
+# n_max is at least this, and element by element (``gather_offsets``) below
+# it. On a TPU v5e a window costs ~0.8 us whatever its length from 24 to 768
+# rows and an element of the scalar gather ~7.2 ns, so windows win from ~110
+# rows: 2304 x 512 lanes 8.45 -> 1.95 ms, 2048 x 768 11.26 -> 1.82, one lane
+# of 524,288 3.76 -> 0.20; 3840 x 96 2.66 -> 3.05 and 49,152 x 24 8.57 ->
+# 38.65 lose (PERF.md §6).
+RUN_WINDOW_MIN_ROWS = 128
+
+
 @jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class LaneRuns:
+    """Each lane's rows as ONE contiguous run of the batch: ``start`` (E,)
+    int32 its first row, ``count`` (E,) int32 its rows (0 on padding
+    lanes)."""
+
+    start: Array
+    count: Array
+
+    def offsets(self, offsets: Array, n_max: int) -> Array:
+        """``EntityBlock.gather_offsets`` of such a block, bit for bit: one
+        window of ``n_max`` a lane, the slots past the lane's rows zeroed.
+        The source is padded by ``n_max`` because XLA clamps a window's start
+        so the window fits its operand, which would shift a run that ends
+        within ``n_max`` of the batch's last row."""
+        src = jnp.pad(offsets, (0, n_max))
+        win = jax.vmap(lambda s: jax.lax.dynamic_slice(src, (s,), (n_max,)))(
+            self.start
+        )
+        slot = jax.lax.broadcasted_iota(jnp.int32, win.shape, 1)
+        return jnp.where(slot < self.count[:, None], win, 0.0)
+
+
+def lane_runs(sample_index: np.ndarray, counts: np.ndarray) -> Optional[LaneRuns]:
+    """The block's ``LaneRuns`` where it reads its offsets as runs: every
+    lane's rows are consecutive rows of the batch, in order, and the block
+    is ``RUN_WINDOW_MIN_ROWS`` slots deep or more. None otherwise."""
+    if sample_index.shape[1] < RUN_WINDOW_MIN_ROWS:
+        return None
+    start = np.where(counts > 0, sample_index[:, 0], 0).astype(np.int32)
+    slot = np.arange(sample_index.shape[1])
+    runs = np.where(slot < counts[:, None], start[:, None] + slot, -1)
+    if not np.array_equal(runs, sample_index):
+        return None
+    return LaneRuns(jnp.asarray(start), jnp.asarray(counts.astype(np.int32)))
+
+
 @dataclasses.dataclass(frozen=True)
 class EntityBlock:
     """One fixed-shape block of per-entity problems (vmap unit).
@@ -252,6 +300,12 @@ class EntityBlock:
     train_mask: (E,) bool — False for entities filtered by the lower bound
       (they keep a zero model; reference filterActiveData:550-570) and for
       shape-bucket padding rows.
+    runs: the block's ``LaneRuns`` where it reads its offsets as runs (see
+      ``lane_runs``), else None. Not a leaf of the pytree: a solver traced on
+      a block with runs serves a compacted block without them, and a block
+      rebuilt from its leaves (``jax.device_put``, the out-of-core store)
+      reads its offsets by ``sample_index``, which is always valid; so does
+      a copy by ``dataclasses.replace``. Set by the fill.
     """
 
     entity_idx: Array
@@ -264,6 +318,9 @@ class EntityBlock:
     # column j corresponds to global column col_map[j]. None = identity
     # (block dim == shard dim).
     col_map: Optional[Array] = None
+    runs: Optional[LaneRuns] = dataclasses.field(
+        default=None, init=False, repr=False, compare=False
+    )
 
     @property
     def num_entities(self) -> int:
@@ -298,6 +355,16 @@ class EntityBlock:
         array (addScoresToOffsets role — a gather, not a join)."""
         safe = jnp.maximum(self.sample_index, 0)
         return jnp.where(self.sample_index >= 0, offsets[safe], 0.0)
+
+
+jax.tree_util.register_dataclass(
+    EntityBlock,
+    data_fields=[
+        "entity_idx", "features", "label", "weight", "sample_index",
+        "train_mask", "col_map",
+    ],
+    meta_fields=[],
+)
 
 
 @dataclasses.dataclass
@@ -349,6 +416,118 @@ class RandomEffectDataset:
         return jnp.asarray(entity_block), jnp.asarray(entity_row), inv_maps
 
 
+def _shard_input(features, config: RandomEffectDataConfig):
+    """``(sp_indices, sp_values, dense, n, d, project, feat_dtype)`` of a
+    shard given dense or as a host padded-sparse triple."""
+    if isinstance(features, tuple):
+        sp_indices, sp_values, d = features
+        sp_indices = np.asarray(sp_indices)
+        sp_values = np.asarray(sp_values)
+        project = True if config.subspace_projection is None else config.subspace_projection
+        if not project:
+            raise ValueError("sparse shard input requires subspace projection")
+        return (sp_indices, sp_values, None, sp_indices.shape[0], d, project,
+                sp_values.dtype)
+    features = np.asarray(features)
+    n, d = features.shape
+    return (None, None, features, n, d, bool(config.subspace_projection),
+            features.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class EntityGrouping:
+    """A coordinate's rows grouped by entity and planned into blocks, before
+    any block is filled: ``entities`` holds (entity id, its active rows) in
+    id order, the rows as the stable sort and the reservoir left them;
+    ``plans`` the block geometry over their counts."""
+
+    entities: List[Tuple[int, np.ndarray]]
+    counts: np.ndarray
+    plans: List[BlockPlan]
+    d: int
+    project: bool
+    itemsize: int
+
+    def run_rows(self) -> np.ndarray:
+        """Every row a block holds, each entity's rows together and in their
+        order, entities in the order of their first row. A batch laid out in
+        this order gives every lane one contiguous run of rows. The order
+        follows which rows share an entity, not what the entities are
+        called, so the other coordinates' sums over the rows (the fixed
+        effect's) see the same order however the ids are assigned."""
+        if not self.entities:
+            return np.zeros((0,), np.int64)
+        first = np.array([rows.min() for _eid, rows in self.entities])
+        return np.concatenate([self.entities[i][1] for i in np.argsort(first)])
+
+    def window_share(self) -> float:
+        """The share of the planned slots that sit in blocks deep enough to
+        read their offsets as windows (``RUN_WINDOW_MIN_ROWS``) once every
+        lane is a run."""
+        slots = np.array([p.lanes * p.n_max for p in self.plans], np.int64)
+        deep = np.array([p.n_max >= RUN_WINDOW_MIN_ROWS for p in self.plans])
+        return float(slots[deep].sum() / slots.sum()) if slots.sum() else 0.0
+
+    def block_bytes(self) -> int:
+        """Device bytes the filled blocks take: features (at the shard's
+        width, an upper bound for a projected block), label, weight and row
+        index a slot."""
+        return int(sum(
+            p.lanes * p.n_max * (self.d * self.itemsize + 12) for p in self.plans
+        ))
+
+
+def group_entity_rows(
+    entity_ids: np.ndarray,  # (n,) dense int32 entity index per sample
+    features,  # (n, d) dense np array OR host sparse (indices, values, dim)
+    config: RandomEffectDataConfig,
+    uid: Optional[np.ndarray] = None,
+    slab_budget: Optional[int] = None,
+) -> EntityGrouping:
+    """The grouping half of :func:`build_random_effect_dataset`: rows sorted
+    by entity, the reservoir cap, and the block plan."""
+    _spi, _spv, _dense, n, d, project, feat_dtype = _shard_input(features, config)
+    uid = np.arange(n, dtype=np.int64) if uid is None else uid.astype(np.int64)
+
+    # Group sample rows by entity (sorted for determinism).
+    order = np.argsort(entity_ids, kind="stable")
+    sorted_eids = entity_ids[order]
+    uniq, starts = np.unique(sorted_eids, return_index=True)
+    groups = np.split(order, starts[1:])
+
+    # Drop the group of negative (unknown) entity ids if present.
+    entities: List[Tuple[int, np.ndarray]] = [
+        (int(eid), rows) for eid, rows in zip(uniq, groups) if eid >= 0
+    ]
+
+    # Reservoir-sample active data per entity (deterministic key on uid).
+    ub = config.active_upper_bound
+    if ub is not None:
+        capped = []
+        for eid, rows in entities:
+            if len(rows) > ub:
+                keys = _byteswap64(uid[rows])
+                rows = rows[np.argsort(keys, kind="stable")[:ub]]
+            capped.append((eid, rows))
+        entities = capped
+
+    # Block geometry, planned from the row counts.
+    counts = np.array([len(rows) for _, rows in entities], np.int64)
+    with span("plan"):
+        # A projected block's width is its content's (known only once it is
+        # grouped), so the byte budget holds dense blocks alone.
+        d_alloc = bucket_dim(d) if config.shape_bucketing else d
+        plans = plan_blocks(
+            counts,
+            0 if project else d_alloc * np.dtype(feat_dtype).itemsize,
+            bucketed=config.shape_bucketing,
+            slab_budget=slab_budget,
+        )
+    return EntityGrouping(
+        entities, counts, plans, d, project, np.dtype(feat_dtype).itemsize
+    )
+
+
 def build_random_effect_dataset(
     entity_ids: np.ndarray,  # (n,) dense int32 entity index per sample
     features,  # (n, d) dense np array OR host sparse (indices, values, dim)
@@ -383,63 +562,36 @@ def build_random_effect_dataset(
     ``slab_budget`` (bytes) is rule 4 of the block plan above: the caller
     that owns the device gives it, and None cuts no level.
     """
-    sp_indices = sp_values = None
-    if isinstance(features, tuple):
-        sp_indices, sp_values, d = features
-        sp_indices = np.asarray(sp_indices)
-        sp_values = np.asarray(sp_values)
-        n = sp_indices.shape[0]
-        project = True if config.subspace_projection is None else config.subspace_projection
-        if not project:
-            raise ValueError("sparse shard input requires subspace projection")
-        feat_dtype = sp_values.dtype
-    else:
-        features = np.asarray(features)
-        n, d = features.shape
-        project = bool(config.subspace_projection)
-        feat_dtype = features.dtype
-    uid = np.arange(n, dtype=np.int64) if uid is None else uid.astype(np.int64)
+    grouping = group_entity_rows(entity_ids, features, config, uid, slab_budget)
+    return fill_entity_blocks(
+        grouping, features, label, weight, num_entities, config,
+        existing_model_mask,
+    )
 
-    # Group sample rows by entity (sorted for determinism).
-    order = np.argsort(entity_ids, kind="stable")
-    sorted_eids = entity_ids[order]
-    uniq, starts = np.unique(sorted_eids, return_index=True)
-    groups = np.split(order, starts[1:])
 
-    # Drop the group of negative (unknown) entity ids if present.
-    entities: List[Tuple[int, np.ndarray]] = [
-        (int(eid), rows) for eid, rows in zip(uniq, groups) if eid >= 0
-    ]
+def fill_entity_blocks(
+    grouping: EntityGrouping,
+    features,
+    label: np.ndarray,
+    weight: np.ndarray,
+    num_entities: int,
+    config: RandomEffectDataConfig,
+    existing_model_mask: Optional[np.ndarray] = None,
+    row_of: Optional[np.ndarray] = None,
+) -> RandomEffectDataset:
+    """The fill half of :func:`build_random_effect_dataset`: the planned
+    blocks from the shard's host arrays. ``row_of`` ((n,) int) is the row of
+    the batch the blocks are trained on that each row of these arrays became
+    (the batch laid out in another order); ``sample_index`` names those rows.
+    A block whose lanes are each one contiguous run of them carries the
+    runs (``EntityBlock.runs``)."""
+    sp_indices, sp_values, features, _n, d, project, feat_dtype = _shard_input(
+        features, config
+    )
+    entities, counts, plans = grouping.entities, grouping.counts, grouping.plans
     if not entities:
         return RandomEffectDataset(config, [], num_entities, d)
-
-    # Reservoir-sample active data per entity (deterministic key on uid).
-    ub = config.active_upper_bound
-    if ub is not None:
-        capped = []
-        for eid, rows in entities:
-            if len(rows) > ub:
-                keys = _byteswap64(uid[rows])
-                rows = rows[np.argsort(keys, kind="stable")[:ub]]
-            capped.append((eid, rows))
-        entities = capped
-
     lb = config.active_lower_bound or 0
-
-    # Block geometry, planned from the row counts.
-    counts = np.array([len(rows) for _, rows in entities])
-    if counts.size == 0:
-        return RandomEffectDataset(config, [], num_entities, d)
-    with span("plan"):
-        # A projected block's width is its content's (known only once it is
-        # grouped), so the byte budget holds dense blocks alone.
-        d_alloc = bucket_dim(d) if config.shape_bucketing else d
-        plans = plan_blocks(
-            counts,
-            0 if project else d_alloc * np.dtype(feat_dtype).itemsize,
-            bucketed=config.shape_bucketing,
-            slab_budget=slab_budget,
-        )
     blocks: List[EntityBlock] = []
     lane_samples = []
     lane_valid = []
@@ -506,24 +658,24 @@ def build_random_effect_dataset(
                     feat[j, :m, :d] = features[rows]
                 lab[j, :m] = label[rows]
                 wt[j, :m] = weight[rows]
-                sidx[j, :m] = rows
+                sidx[j, :m] = rows if row_of is None else row_of[rows]
                 eidx[j] = eid
                 tmask[j] = m >= lb or (
                     existing_model_mask is not None
                     and not bool(existing_model_mask[eid])
                 )
             lane_valid.append(eidx >= 0)
-            blocks.append(
-                EntityBlock(
-                    entity_idx=jnp.asarray(eidx),
-                    features=jnp.asarray(feat),
-                    label=jnp.asarray(lab),
-                    weight=jnp.asarray(wt),
-                    sample_index=jnp.asarray(sidx),
-                    train_mask=jnp.asarray(tmask),
-                    col_map=None if col_map is None else jnp.asarray(col_map, jnp.int32),
-                )
+            block = EntityBlock(
+                entity_idx=jnp.asarray(eidx),
+                features=jnp.asarray(feat),
+                label=jnp.asarray(lab),
+                weight=jnp.asarray(wt),
+                sample_index=jnp.asarray(sidx),
+                train_mask=jnp.asarray(tmask),
+                col_map=None if col_map is None else jnp.asarray(col_map, jnp.int32),
             )
+            object.__setattr__(block, "runs", lane_runs(sidx, lane_samples[-1]))
+            blocks.append(block)
     return RandomEffectDataset(
         config, blocks, num_entities, d,
         lane_samples=jnp.asarray(np.concatenate(lane_samples)),

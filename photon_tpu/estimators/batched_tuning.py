@@ -109,8 +109,10 @@ def build_batched_evaluator(
     if re_cfg.optimizer not in (OptimizerType.LBFGS, OptimizerType.NEWTON):
         return _decline("random-effect optimizer is not LBFGS/NEWTON")
 
-    # Datasets: unprojected RE dataset (any block count).
-    estimator._prepare_datasets(batch)
+    # Datasets: unprojected RE dataset (any block count), whose blocks index
+    # the batch the estimator trains on (laid out in runs of one
+    # coordinate's entities, where the device holds the copy).
+    batch = estimator._prepare_datasets(batch)
     ds = estimator._re_datasets.get(re_cfg.coordinate_id)
     if ds is None or ds.projected:
         return _decline("projected random-effect dataset")

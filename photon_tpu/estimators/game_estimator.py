@@ -20,17 +20,21 @@ import logging
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from photon_tpu.algorithm.coordinate import Coordinate
 from photon_tpu.algorithm.coordinate_descent import CoordinateDescent
 from photon_tpu.algorithm.fixed_effect import FixedEffectCoordinate
 from photon_tpu.algorithm.random_effect import RandomEffectCoordinate
-from photon_tpu.data.game_data import GameBatch
+from photon_tpu.data.batch import SparseFeatures
+from photon_tpu.data.game_data import GameBatch, RowLayout
 from photon_tpu.data.normalization import NormalizationContext
 from photon_tpu.data.random_effect import (
+    EntityGrouping,
     RandomEffectDataConfig,
-    build_random_effect_dataset,
+    fill_entity_blocks,
+    group_entity_rows,
     slab_budget_of,
 )
 from photon_tpu.estimators.config import (
@@ -59,12 +63,82 @@ logger = logging.getLogger(__name__)
 CoordinateConfig = Union[FixedEffectCoordinateConfig, RandomEffectCoordinateConfig]
 
 
+def _device_memory() -> dict:
+    """``memory_stats()`` of the device the blocks are placed on; empty where
+    the backend reports none, as the CPU does."""
+    return jax.local_devices()[0].memory_stats() or {}
+
+
 def _device_slab_budget() -> Optional[int]:
     """The block plan's byte budget (data/random_effect.py, rule 4) for the
     device the blocks are placed on; None, so no level is cut, where the
     backend reports no memory limit, as the CPU does."""
-    limit = (jax.local_devices()[0].memory_stats() or {}).get("bytes_limit")
+    limit = _device_memory().get("bytes_limit")
     return slab_budget_of(limit) if limit else None
+
+
+# What a fit holds on the device besides its batch and its blocks, in
+# float32s. A row and column of the widest random coordinate: its update's
+# eager score (the coefficients gathered to (n, d) and their product) and
+# its residual and warm-start gathers, read at 1.06 GB over resident for
+# n = 2^22, d = 16 on a TPU v5e (3.95 a row and column); twice that is
+# reserved. A row: the descent's score vectors and the fixed-effect solve's.
+# A sparse shard's transpose product also holds its (n, k) entries twice.
+FIT_FLOATS_A_ROW_AND_COLUMN = 8
+FIT_FLOATS_A_ROW = 16
+
+# The batch is laid out only where the layout coordinate's blocks deep
+# enough to read windows hold at least this share of its slots. Below it the
+# copy costs the batch's bytes for little: with 6 % of the slots in such
+# blocks the block inputs fell 98.8 -> 94.5 ms of a 634 ms fit, for 4.9 GB.
+LAYOUT_MIN_WINDOW_SHARE = 0.5
+
+
+def _layout_bytes(batch: GameBatch, groupings: Dict[str, EntityGrouping],
+                  limit: int) -> int:
+    """Device bytes a laid-out fit needs on top of what is in use before it:
+    the batch once more, the blocks still to be filled, one block's slab
+    budget and the fit's own working set."""
+    widest = max((g.d for g in groupings.values()), default=0)
+    sparse = max(
+        (2 * f.values.shape[1] for f in batch.features.values()
+         if isinstance(f, SparseFeatures)),
+        default=0,
+    )
+    floats = FIT_FLOATS_A_ROW + max(FIT_FLOATS_A_ROW_AND_COLUMN * widest, sparse)
+    return (
+        sum(a.nbytes for a in jax.tree_util.tree_leaves(batch))
+        + sum(g.block_bytes() for g in groupings.values())
+        + slab_budget_of(limit)
+        + 4 * batch.n * floats
+    )
+
+
+def _row_layout(batch: GameBatch, groupings: Dict[str, EntityGrouping],
+                 coordinate: str) -> Optional[np.ndarray]:
+    """The order to lay ``batch``'s rows out in so that every lane of
+    ``coordinate``'s blocks is one run of rows: its entities' rows
+    (``EntityGrouping.run_rows``), then the rows no block holds (unknown
+    ids, rows a cap left out) in their order.
+    None where the layout would not pay (``LAYOUT_MIN_WINDOW_SHARE``) or the
+    device cannot hold the copy: every array of the batch must sit on one
+    device whose memory limit leaves ``_layout_bytes`` free."""
+    grouping = groupings[coordinate]
+    if grouping.window_share() < LAYOUT_MIN_WINDOW_SHARE:
+        return None
+    leaves = jax.tree_util.tree_leaves(batch)
+    if not all(isinstance(a, jax.Array) and len(a.devices()) == 1 for a in leaves):
+        return None
+    memory = _device_memory()
+    limit = memory.get("bytes_limit")
+    if not limit:
+        return None
+    if limit - memory.get("bytes_in_use", 0) < _layout_bytes(batch, groupings, limit):
+        return None
+    held = grouping.run_rows()
+    rest = np.ones((batch.n,), bool)
+    rest[held] = False
+    return np.concatenate([held, np.flatnonzero(rest)])
 
 
 def _existing_entity_mask(prev_model) -> np.ndarray:
@@ -202,7 +276,10 @@ class GameEstimator:
                     normalization=self.normalization.get(cfg.feature_shard),
                 )
                 sampler = (
-                    down_sampler_for_task(self.task, cfg.down_sampling_rate)
+                    dataclasses.replace(
+                        down_sampler_for_task(self.task, cfg.down_sampling_rate),
+                        layout=self._layout,
+                    )
                     if cfg.down_sampling_rate is not None and cfg.down_sampling_rate < 1.0
                     else None
                 )
@@ -249,16 +326,27 @@ class GameEstimator:
                 raise TypeError(f"unknown coordinate config {type(cfg)}")
         return coords
 
-    def _prepare_datasets(self, batch: GameBatch) -> None:
+    def _prepare_datasets(self, batch: GameBatch) -> GameBatch:
         """Random-effect grouping happens once per fit() — the λ sweep
         reuses the blocks (the reference rebuilds per config; we don't).
         Repeated fits on the SAME batch (hyperparameter tuning calls fit
-        once per candidate) reuse the previous grouping."""
-        if getattr(self, "_prepared_for", None) is batch:
-            return
-        self._re_datasets = {}
-        from photon_tpu.data.batch import SparseFeatures
+        once per candidate) reuse the previous grouping.
 
+        Returns the batch the datasets index, which every coordinate trains
+        on: where the device holds a second copy, ``batch`` laid out so that
+        each entity of the first random coordinate whose blocks are dense has
+        its rows together (``_row_layout``), and that coordinate's blocks
+        read their residuals as runs (``self._layout``); else ``batch``
+        itself. Nothing a fit returns is indexed by row."""
+        if getattr(self, "_prepared_for", None) is batch:
+            return self._prepared_batch
+        self._re_datasets = {}
+        self._prepared_for = self._prepared_batch = None
+        self._layout = RowLayout()
+        re_cfgs = [
+            c for c in self.coordinate_configs
+            if isinstance(c, RandomEffectCoordinateConfig)
+        ]
         with span("prepare"):
             with span("host_copy"):
                 # Sparse (wide) shards pass through as host triples — the
@@ -277,82 +365,118 @@ class GameEstimator:
                 uid_np = None if batch.uid is None else np.asarray(batch.uid)
                 eids_np = {
                     cfg.re_type: np.asarray(batch.entity_ids[cfg.re_type])
-                    for cfg in self.coordinate_configs
-                    if isinstance(cfg, RandomEffectCoordinateConfig)
+                    for cfg in re_cfgs
                 }
             with span("group"):
-                for cfg in self.coordinate_configs:
-                    if not isinstance(cfg, RandomEffectCoordinateConfig):
-                        continue
-                    # Children ``plan`` and ``fill`` open in the builder.
+                # Children ``plan`` and ``fill`` open in the builder.
+                groupings: Dict[str, EntityGrouping] = {}
+                for cfg in re_cfgs:
                     with span(cfg.coordinate_id):
-                        ds = self._group_entities(
-                            cfg, eids_np[cfg.re_type],
-                            feats_np[cfg.feature_shard],
-                            label_np, weight_np, uid_np,
+                        groupings[cfg.coordinate_id] = group_entity_rows(
+                            eids_np[cfg.re_type], feats_np[cfg.feature_shard],
+                            self._data_config(cfg), uid_np,
+                            _device_slab_budget(),
+                        )
+                runs_of = next(
+                    (cid for cid, g in groupings.items()
+                     if g.entities and not g.project),
+                    None,
+                )
+                order = (
+                    None if runs_of is None
+                    else _row_layout(batch, groupings, runs_of)
+                )
+                row_of = None
+                if order is not None:
+                    row_of = np.empty_like(order)
+                    row_of[order] = np.arange(order.size)
+                for cfg in re_cfgs:
+                    with span(cfg.coordinate_id):
+                        ds = fill_entity_blocks(
+                            groupings[cfg.coordinate_id],
+                            feats_np[cfg.feature_shard], label_np, weight_np,
+                            self._entity_count(cfg, eids_np[cfg.re_type]),
+                            self._data_config(cfg),
+                            self._existing_mask(cfg, eids_np[cfg.re_type]),
+                            row_of,
                         )
                     self._re_datasets[cfg.coordinate_id] = ds
-                    # What the plan costs a pass: one dispatch a block, one
-                    # solver program a distinct (lanes, n_max, d).
-                    labels = dict(coordinate=cfg.coordinate_id)
-                    registry().gauge("re_blocks", **labels).set(len(ds.blocks))
-                    registry().gauge("re_block_geometries", **labels).set(
-                        len({b.features.shape for b in ds.blocks})
+                    self._publish_plan(
+                        cfg.coordinate_id, ds,
+                        laid_out=order is not None and cfg.coordinate_id == runs_of,
                     )
-                    # What the population is: entities that hold rows, those
-                    # of them with at least as many rows as coefficients
-                    # (the rest are under-determined: only the penalty
-                    # bounds them), and the widest block's lanes.
-                    rows = np.asarray(
-                        ds.lane_samples if ds.blocks else np.zeros((0,), np.int32)
-                    )
-                    registry().gauge("re_entities", **labels).set(
-                        int(np.sum(rows > 0))
-                    )
-                    registry().gauge("re_entities_rows_ge_dim", **labels).set(
-                        int(np.sum(rows >= ds.dim))
-                    )
-                    registry().gauge("re_lanes_max", **labels).set(
-                        max((b.num_entities for b in ds.blocks), default=0)
-                    )
+            if order is not None:
+                with span("layout"):
+                    self._layout = RowLayout(jnp.asarray(order.astype(np.int32)))
+                    run_batch = self._layout.apply(batch)
+            else:
+                run_batch = batch
         self._prepared_for = batch
+        self._prepared_batch = run_batch
+        return run_batch
 
-    def _group_entities(self, cfg, eids, feats, label_np, weight_np, uid_np):
-        """One random-effect coordinate's entity blocks from host arrays."""
-        E = self.num_entities.get(
+    @staticmethod
+    def _publish_plan(coordinate: str, ds, laid_out: bool) -> None:
+        """What the plan costs a pass and what the population is, into the
+        registry at dataset build."""
+        labels = dict(coordinate=coordinate)
+        # One dispatch a block, one solver program a distinct (lanes, n_max,
+        # d); of the blocks, those that gather their residuals one by one
+        # (the rest read them as runs); and whether the batch is laid out in
+        # runs of this coordinate's entities.
+        registry().gauge("re_blocks", **labels).set(len(ds.blocks))
+        registry().gauge("re_block_geometries", **labels).set(
+            len({b.features.shape for b in ds.blocks})
+        )
+        registry().gauge("re_row_gather_blocks", **labels).set(
+            sum(b.runs is None for b in ds.blocks)
+        )
+        registry().gauge("batch_laid_out", **labels).set(int(laid_out))
+        # Entities that hold rows, those of them with at least as many rows
+        # as coefficients (the rest are under-determined: only the penalty
+        # bounds them), and the widest block's lanes.
+        rows = np.asarray(
+            ds.lane_samples if ds.blocks else np.zeros((0,), np.int32)
+        )
+        registry().gauge("re_entities", **labels).set(int(np.sum(rows > 0)))
+        registry().gauge("re_entities_rows_ge_dim", **labels).set(
+            int(np.sum(rows >= ds.dim))
+        )
+        registry().gauge("re_lanes_max", **labels).set(
+            max((b.num_entities for b in ds.blocks), default=0)
+        )
+
+    @staticmethod
+    def _data_config(cfg) -> RandomEffectDataConfig:
+        return RandomEffectDataConfig(
+            re_type=cfg.re_type,
+            feature_shard=cfg.feature_shard,
+            active_upper_bound=cfg.active_upper_bound,
+            active_lower_bound=cfg.active_lower_bound,
+            features_to_samples_ratio=cfg.features_to_samples_ratio,
+        )
+
+    def _entity_count(self, cfg, eids: np.ndarray) -> int:
+        return self.num_entities.get(
             cfg.re_type, int(eids.max()) + 1 if eids.size else 0
         )
-        existing = None
-        if self.ignore_threshold_for_new_models:
-            # Entities with an existing model in the warm-start GameModel;
-            # ids outside it bypass the bound. Presence comes from the
-            # loader's record-membership mask when available (L1-zeroed
-            # models still count as existing, matching the reference's
-            # key-presence semantics); nonzero rows are the fallback for
-            # in-memory models.
-            existing = np.zeros((E,), bool)
-            prev_model = self.warm_start_model.get(cfg.coordinate_id)
-            if prev_model is not None:
-                existing_src = _existing_entity_mask(prev_model)
-                k = min(E, existing_src.shape[0])
-                existing[:k] = existing_src[:k]
-        return build_random_effect_dataset(
-            eids,
-            feats,
-            label_np,
-            weight_np,
-            E,
-            RandomEffectDataConfig(
-                re_type=cfg.re_type,
-                feature_shard=cfg.feature_shard,
-                active_upper_bound=cfg.active_upper_bound,
-                active_lower_bound=cfg.active_lower_bound,
-                features_to_samples_ratio=cfg.features_to_samples_ratio,
-            ),
-            uid=uid_np,
-            existing_model_mask=existing,
-            slab_budget=_device_slab_budget(),
-        )
+
+    def _existing_mask(self, cfg, eids: np.ndarray) -> Optional[np.ndarray]:
+        """Entities with an existing model in the warm-start GameModel, where
+        new ones bypass the lower bound; None otherwise. Presence comes from
+        the loader's record-membership mask when available (L1-zeroed models
+        still count as existing, matching the reference's key-presence
+        semantics); nonzero rows are the fallback for in-memory models."""
+        if not self.ignore_threshold_for_new_models:
+            return None
+        E = self._entity_count(cfg, eids)
+        existing = np.zeros((E,), bool)
+        prev_model = self.warm_start_model.get(cfg.coordinate_id)
+        if prev_model is not None:
+            existing_src = _existing_entity_mask(prev_model)
+            k = min(E, existing_src.shape[0])
+            existing[:k] = existing_src[:k]
+        return existing
 
     # --- fit ---
 
@@ -376,7 +500,7 @@ class GameEstimator:
         already-finished config replays from its final checkpoint without
         recomputation, so a preempted λ-sweep continues where it stopped."""
         with Timed("game-estimator/prepare-datasets"):
-            self._prepare_datasets(batch)
+            batch = self._prepare_datasets(batch)
 
         configs = (
             list(optimization_configs)
@@ -412,6 +536,7 @@ class GameEstimator:
                     ),
                     checkpoint_every=checkpoint_every,
                     checkpoint_keep_last=checkpoint_keep_last,
+                    layout=self._layout,
                     # Fingerprint the λ-sweep point: resuming against a
                     # changed grid/sequence fails loudly instead of serving a
                     # stale model from the same cfg index.

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from photon_tpu.data.batch import LabeledBatch
+from photon_tpu.data.game_data import RowLayout
 from photon_tpu.types import TaskType
 
 Array = jax.Array
@@ -30,10 +31,14 @@ class DownSampler:
 
     rate: float
     seed: int = 0
+    # The order of the batch it samples (``GameEstimator`` lays a batch out
+    # in entity runs): the draw is made in the order given, so a seed keeps
+    # the same rows.
+    layout: RowLayout = RowLayout()
 
     def _keep(self, n: int, salt: int) -> Array:
         key = jax.random.fold_in(jax.random.PRNGKey(self.seed), salt)
-        return jax.random.uniform(key, (n,)) < self.rate
+        return self.layout.from_original(jax.random.uniform(key, (n,))) < self.rate
 
     def apply(self, batch: LabeledBatch) -> LabeledBatch:
         keep = self._keep(batch.n, 0)

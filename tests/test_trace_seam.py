@@ -136,9 +136,11 @@ def test_fit_opens_the_coordinate_update_with_its_children_in_order(fit_log, cid
 def test_fit_opens_prepare_with_host_copy_and_group(fit_log):
     names = [n for n, _, _ in fit_log]
     (prepare,) = [n for n in names if n.endswith("/prepare")]
+    # Every coordinate is planned before any is filled: the fill needs the
+    # batch's layout, which the plans decide.
     assert [n[len(prepare):] for n in names if n.startswith(prepare + "/")] == [
         "/host_copy", "/group", "/group/per_user", "/group/per_user/plan",
-        "/group/per_user/fill"]
+        "/group/per_user", "/group/per_user/fill"]
     # the solver's spans reach the profile through the same seam
     assert any(n.endswith("/solve/fe_solve") for n in names)
     assert any(n.endswith("/solve/re_dispatch_blocks") for n in names)
