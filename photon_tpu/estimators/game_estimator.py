@@ -49,6 +49,7 @@ from photon_tpu.models.game import (
     ProjectedRandomEffectModel,
     RandomEffectModel,
 )
+from photon_tpu.obs.host import start_sentinel
 from photon_tpu.obs.metrics import registry
 from photon_tpu.obs.trace import span
 from photon_tpu.ops.losses import loss_for_task
@@ -499,6 +500,7 @@ class GameEstimator:
         under ``<dir>/cfg_<i>`` and resumes from its latest state — an
         already-finished config replays from its final checkpoint without
         recomputation, so a preempted λ-sweep continues where it stopped."""
+        start_sentinel()  # host pauses (collections, stalls) on the span clock
         with Timed("game-estimator/prepare-datasets"):
             batch = self._prepare_datasets(batch)
 

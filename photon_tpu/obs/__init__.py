@@ -1,6 +1,6 @@
 """Unified run telemetry: trace spans + metrics registry + JSONL run report.
 
-Three pieces, one artifact:
+Four pieces, one artifact:
 
 - :mod:`photon_tpu.obs.trace` — hierarchical host-wall spans
   (``span("cd/iter3/per-user/solve")``), thread-safe, nestable across the
@@ -9,6 +9,9 @@ Three pieces, one artifact:
 - :mod:`photon_tpu.obs.metrics` — process-global counters / gauges /
   histograms with labels; the solve cache, pipeline stages, replay cache,
   shape bucketing, and optimizers all publish here.
+- :mod:`photon_tpu.obs.host` — host pauses on the span clock: every
+  collection (``host/gc/gen<N>``) and every stall of the process
+  (``host/stall/<cause>``), from the one host sampler thread.
 - :mod:`photon_tpu.obs.report` — the run-report finalizer: spans + metrics
   + coordinate-descent tracker + environment as schema-stable JSONL
   (``--telemetry-out`` on every CLI driver) and as
@@ -28,6 +31,7 @@ from photon_tpu.obs.export import (  # noqa: F401
     maybe_install_exporter,
     uninstall_exporter,
 )
+from photon_tpu.obs.host import start_sentinel  # noqa: F401
 from photon_tpu.obs.metrics import (  # noqa: F401
     PROMETHEUS_CONTENT_TYPE,
     MetricsRegistry,
@@ -67,7 +71,8 @@ def begin_run() -> None:
     """Reset all run-scoped telemetry state: spans, registry metrics, the
     ``Timed`` phase records, and the shared solve-cache counters (compiled
     executables are kept — only the counters are run-scoped), so a second
-    driver invocation in one process starts from a clean slate."""
+    driver invocation in one process starts from a clean slate. Starts the
+    host-pause sentinel (:mod:`photon_tpu.obs.host`) for the driver."""
     from photon_tpu.algorithm.solve_cache import default_cache
     from photon_tpu.utils.timed import Timed
 
@@ -76,3 +81,4 @@ def begin_run() -> None:
     reset_registry()
     Timed.reset()
     default_cache().reset_stats()
+    start_sentinel()

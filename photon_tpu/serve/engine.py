@@ -59,6 +59,7 @@ from photon_tpu.estimators.game_transformer import GameTransformer
 from photon_tpu.models.game import GameModel
 from photon_tpu.obs.metrics import registry
 from photon_tpu.obs.export import exporter_health
+from photon_tpu.obs.host import start_sentinel
 from photon_tpu.obs.report import telemetry_sink_health
 from photon_tpu.obs.quality import QualityConfig, QualityPlane, task_name
 from photon_tpu.obs.slo import SLOTracker
@@ -231,6 +232,7 @@ class ServingEngine:
         model_version: str = "0",
         partition: Optional[StorePartition] = None,
     ):
+        start_sentinel()  # host pauses (collections, stalls) on the span clock
         self.config = config or ServeConfig()
         self.max_batch = bucket_dim(int(self.config.max_batch_size))
         # Fleet shard ownership: every generation's store is built with the

@@ -24,8 +24,9 @@ Concretely:
   training loop); the replay spool falls back to the legacy re-stream path
   and removes its partial file; the checkpoint writer prunes older steps
   (keep-last-K) and retries before giving up, never leaving a tmp file.
-- **Host RSS pressure**: a cgroup-aware sampling thread
-  (:class:`RssWatchdog`) publishes a pressure level that allocating layers
+- **Host RSS pressure**: a cgroup-aware sampler (:class:`RssWatchdog`, a job
+  of the process's one host sampler thread in :mod:`photon_tpu.obs.host`)
+  publishes a pressure level that allocating layers
   poll — pipeline queue depths and the serving admission cap tighten at
   *soft* pressure; at *hard* pressure the training loop's pass-boundary
   check raises a clean, actionable :class:`HostMemoryPressureError` instead
@@ -237,8 +238,9 @@ def _read_rss_bytes() -> Optional[int]:
 
 
 class RssWatchdog:
-    """Samples host RSS against a limit (env override → cgroup) on a daemon
-    thread and publishes a pressure level other layers poll.
+    """Samples host RSS against a limit (env override → cgroup) on the
+    process's one host sampler thread (:mod:`photon_tpu.obs.host`) and
+    publishes a pressure level other layers poll.
 
     - ``level()`` → LEVEL_OK / LEVEL_SOFT / LEVEL_HARD (lock-free read).
     - ``check(site)`` → raises :class:`HostMemoryPressureError` at hard
@@ -277,8 +279,7 @@ class RssWatchdog:
         self.interval_s = interval_s
         self._level = LEVEL_OK
         self._last_rss = 0
-        self._stop = threading.Event()
-        self._thread: Optional[threading.Thread] = None
+        self._sampler = self._job = None
 
     # -- sampling ----------------------------------------------------------
 
@@ -317,27 +318,27 @@ class RssWatchdog:
             )
         return level
 
-    def _loop(self) -> None:
-        while not self._stop.wait(self.interval_s):
-            try:
-                self.sample()
-            except Exception:  # the watchdog must never kill its host
-                logger.exception("rss watchdog sample failed")
+    def _sample_logged(self) -> None:
+        try:
+            self.sample()
+        except Exception:  # the watchdog must never kill its host
+            logger.exception("rss watchdog sample failed")
 
     def start(self) -> "RssWatchdog":
-        if self._thread is None or not self._thread.is_alive():
-            self._stop.clear()
-            self._thread = threading.Thread(
-                target=self._loop, name="rss-watchdog", daemon=True)
-            self._thread.start()
+        """Sample every ``interval_s`` on the host sampler thread, the first
+        time one interval from now."""
+        if self._job is None:
+            from photon_tpu.obs.host import start_sentinel
+
+            self._sampler = start_sentinel()
+            self._job = self._sampler.every(self.interval_s, self._sample_logged)
         return self
 
     def stop(self) -> None:
-        self._stop.set()
-        t = self._thread
-        if t is not None:
-            t.join(timeout=5.0)
-            self._thread = None
+        sampler, job = self._sampler, self._job
+        self._sampler = self._job = None
+        if job is not None:
+            sampler.cancel(job)
 
     # -- polling API -------------------------------------------------------
 

@@ -24,6 +24,12 @@ from photon_tpu.utils.events import EventEmitter, setup_event
 from photon_tpu.utils.timed import Timed
 
 
+def _own(spans):
+    """The spans a test opened: the ring also holds the process's host
+    pauses (``host/gc/*``, ``host/stall/*``), recorded whenever they come."""
+    return [s for s in spans if not s.name.startswith("host/")]
+
+
 @pytest.fixture(autouse=True)
 def _fresh_run():
     begin_run()
@@ -43,7 +49,7 @@ def test_span_nesting_same_thread():
             assert p2 == "cd/iter0"
             with span("per-user/solve") as p3:
                 assert p3 == "cd/iter0/per-user/solve"
-    names = {s.name for s in get_spans()}
+    names = {s.name for s in _own(get_spans())}
     assert names == {"cd", "cd/iter0", "cd/iter0/per-user/solve"}
     by_name = {s.name: s for s in get_spans()}
     assert by_name["cd/iter0"].parent == "cd"
@@ -54,7 +60,7 @@ def test_span_records_on_exception():
     with pytest.raises(RuntimeError):
         with span("failing"):
             raise RuntimeError("boom")
-    assert [s.name for s in get_spans()] == ["failing"]
+    assert [s.name for s in _own(get_spans())] == ["failing"]
 
 
 def test_span_explicit_parent_across_threads():
@@ -294,7 +300,7 @@ def test_begin_run_resets_all_state():
     with Timed("stale-phase"):
         pass
     begin_run()
-    assert get_spans() == []
+    assert _own(get_spans()) == []
     assert registry().find("stale_total") is None
     with Timed.records_lock():
         assert Timed.records == {}

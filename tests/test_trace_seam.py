@@ -44,7 +44,9 @@ class Recorder:
     exit with the thread it happened on."""
 
     log = []
-    lock = threading.Lock()
+    # Reentrant: the collection hook (obs/host.py) enters an annotation from
+    # inside whatever code starts a collection, this class's own included.
+    lock = threading.RLock()
 
     def __init__(self, name):
         self.name = name
@@ -251,8 +253,11 @@ def test_instruments_nothing_read_are_gone(name):
 
 def test_record_stays_host_only(recorder):
     obs_trace.record_span("external", 0.25)
-    assert recorder.log == []
-    assert obs_trace.get_spans()[-1].name.endswith("external")
+    # A collection meanwhile holds its own annotation and leaves its own
+    # root span (obs/host.py); the recorded span opened none.
+    assert [e for e in recorder.log if not e[1].startswith("photon/host/")] == []
+    own = [s for s in obs_trace.get_spans() if not s.name.startswith("host/")]
+    assert own[-1].name.endswith("external")
 
 
 def test_without_jax_spans_are_recorded_and_nothing_is_imported():
