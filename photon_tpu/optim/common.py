@@ -86,7 +86,7 @@ class OptimizeResult:
     # Work counter for throughput accounting. Its unit is ``eval_unit``:
     # black-box solvers (LBFGS/OWL-QN/LBFGS-B/TRON) count objective
     # evaluations including line-search trials ("objective_evals", each = 2
-    # feature-matrix passes); margin-space L-BFGS and Newton count
+    # feature-matrix passes); margin-space L-BFGS and OWL-QN and Newton count
     # feature-matrix passes directly ("x_passes"). Consumers aggregating
     # across solvers must check the unit (bench.py normalizes to passes).
     evals: Array = dataclasses.field(default_factory=lambda: jnp.zeros((), jnp.int32))
@@ -99,7 +99,12 @@ class OptimizeResult:
     nonzeros: Array = dataclasses.field(
         default_factory=lambda: jnp.full((), -1, jnp.int32)
     )
-    # Which solver the factory routed to ("owlqn", "lbfgs_margin", ...) and
+    # Line-search trials of an OWL-QN solve, summed over its iterations: all
+    # of them, and those answered from carried margins without a read of X
+    # (``optim/margin_owlqn.py``). None where the solver counts no trials.
+    trials: Optional[Array] = None
+    margin_trials: Optional[Array] = None
+    # Which solver the factory routed to ("owlqn_margin", "lbfgs_margin", ...) and
     # which coordinate it solved: the labels this result is published under
     # when it is read. Empty where no factory or coordinate made it.
     optimizer: str = dataclasses.field(default="", metadata=dict(static=True))
@@ -176,8 +181,9 @@ class OptimizeResult:
 
 def _publish(host: OptimizeResult) -> None:
     """One solve's work into the registry, from the host copy the tracker's
-    readers already made: iterations and evaluations as counters, the count
-    of non-zero coefficients as a gauge (the last solve's)."""
+    readers already made: iterations, evaluations and (OWL-QN) line-search
+    trials as counters, the count of non-zero coefficients as a gauge (the
+    last solve's)."""
     from photon_tpu.obs.metrics import registry
 
     labels = dict(coordinate=host.coordinate, optimizer=host.optimizer)
@@ -187,6 +193,13 @@ def _publish(host: OptimizeResult) -> None:
     registry().counter(
         "fe_solver_evals_total", unit=host.eval_unit, **labels
     ).inc(int(host.evals))
+    if host.trials is not None:
+        registry().counter("fe_line_search_trials_total", **labels).inc(
+            int(host.trials)
+        )
+        registry().counter("fe_line_search_margin_trials_total", **labels).inc(
+            int(host.margin_trials)
+        )
     if int(host.nonzeros) >= 0:
         registry().gauge(
             "fe_nonzero_coefficients", coordinate=host.coordinate

@@ -18,11 +18,19 @@ from photon_tpu.ops.objective import GLMObjective
 from photon_tpu.optim.common import OptimizeResult, OptimizerConfig
 from photon_tpu.optim.lbfgs import minimize_lbfgs, minimize_lbfgsb
 from photon_tpu.optim.margin_lbfgs import minimize_lbfgs_margin
+from photon_tpu.optim.margin_owlqn import minimize_owlqn_margin
 from photon_tpu.optim.owlqn import minimize_owlqn
 from photon_tpu.optim.tron import TRON_DEFAULT_CONFIG, minimize_tron
 from photon_tpu.types import OptimizerType
 
 Array = jax.Array
+
+# The solvers that carry margins over a ``LabeledBatch``, and the black-box
+# form each gives way to over any other batch.
+_MARGIN_SOLVERS = {
+    "lbfgs_margin": (minimize_lbfgs_margin, "lbfgs"),
+    "owlqn_margin": (minimize_owlqn_margin, "owlqn"),
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,15 +59,18 @@ class OptimizerSpec:
 
 def routed_solver(objective: GLMObjective, spec: OptimizerSpec) -> str:
     """The solver ``make_optimizer`` routes (objective, spec) to over a
-    ``LabeledBatch``, from static facts alone: OWL-QN whenever an L1 term
-    exists (auto-selected or explicit; with l1_weight == 0 OWL-QN degenerates
-    below plain L-BFGS, so a smooth objective never routes there), the
-    configured TRON or L-BFGS-B, else margin-space L-BFGS
-    (photon_tpu.optim.margin_lbfgs: ~2 X passes an iteration instead of the
-    black-box 2·(1+trials); measured ~3× per solve on a TPU), which is for
-    smooth unconstrained problems: a box routes to the black-box L-BFGS."""
+    ``LabeledBatch``, from static facts alone: margin-space OWL-QN whenever
+    an L1 term exists (auto-selected or explicit; with l1_weight == 0 OWL-QN
+    degenerates below plain L-BFGS, so a smooth objective never routes
+    there; photon_tpu.optim.margin_owlqn: one X pass a line search and one
+    gradient pass an iteration), the configured TRON or L-BFGS-B, else
+    margin-space L-BFGS (photon_tpu.optim.margin_lbfgs: ~2 X passes an
+    iteration instead of the black-box 2·(1+trials); measured ~3× per solve
+    on a TPU), which is for smooth unconstrained problems: a box routes to
+    the black-box L-BFGS. Over any other batch the two margin solvers give
+    way to their black-box forms, ``owlqn`` and ``lbfgs``."""
     if objective.l1_weight > 0.0:
-        return "owlqn"
+        return "owlqn_margin"
     if spec.optimizer == OptimizerType.TRON:
         return "tron"
     if spec.optimizer == OptimizerType.LBFGSB:
@@ -71,7 +82,7 @@ def carries_margins(objective: GLMObjective, spec: OptimizerSpec) -> bool:
     """Whether the routed solver holds x·w through its loop, and so can start
     from a score the caller holds and hand the new one back without a pass
     over X. The others are black boxes over ``value_and_grad``."""
-    return routed_solver(objective, spec) == "lbfgs_margin"
+    return routed_solver(objective, spec) in _MARGIN_SOLVERS
 
 
 def make_optimizer(
@@ -80,7 +91,8 @@ def make_optimizer(
     """Return solve(w0, batch) -> OptimizeResult for the given objective.
 
     OWL-QN is auto-selected when the objective carries an L1 weight
-    (reference RegularizationContext L1/elastic-net routing via OWLQN.scala).
+    (reference RegularizationContext L1/elastic-net routing via OWLQN.scala),
+    in margin space over a ``LabeledBatch``.
 
     ``with_score`` makes it solve(w0, batch, start_score=None) -> (result,
     score), ``score`` being x·w at ``result.w`` (``GLMObjective.scores``): a
@@ -93,11 +105,11 @@ def make_optimizer(
     def solve(w0: Array, batch, start_score: Optional[Array] = None):
         vg = lambda w: objective.value_and_grad(w, batch)
         name = routed
-        if name == "lbfgs_margin" and not isinstance(batch, LabeledBatch):
-            name = "lbfgs"
+        if name in _MARGIN_SOLVERS and not isinstance(batch, LabeledBatch):
+            name = _MARGIN_SOLVERS[name][1]
         score = None
         # The solver's name is the scope of its operations in a profile
-        # (``fe_solve/owlqn`` under the solve cache's scope) and the label
+        # (``fe_solve/owlqn_margin`` under the solve cache's scope) and the label
         # its result is published under when the tracker is read.
         with jax.named_scope(name):
             if name == "owlqn":
@@ -115,8 +127,8 @@ def make_optimizer(
             elif name == "lbfgsb":
                 assert spec.box is not None, "LBFGSB requires a box"
                 res = minimize_lbfgsb(vg, w0, spec.box[0], spec.box[1], config)
-            elif name == "lbfgs_margin":
-                res = minimize_lbfgs_margin(
+            elif name in _MARGIN_SOLVERS:
+                res = _MARGIN_SOLVERS[name][0](
                     objective, batch, w0, config,
                     start_score=start_score, return_score=with_score,
                 )

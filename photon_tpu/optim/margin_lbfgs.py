@@ -23,10 +23,12 @@ under optimizer tolerances). One L-BFGS iteration therefore costs exactly
 TWO X passes (u = X·p and the new gradient Xᵀ·dz) instead of the black-box
 2·(1 + #trials). Normalization stays folded: with factors f and shifts s,
 u = X·(f∘p) − (s·(f∘p)) is still affine in α (photon_tpu.data.normalization
-algebra), and the gradient chain-rules back through f.
+algebra), and the gradient chain-rules back through f. The margin algebra
+is ``optim/margins.py``'s, shared with margin-space OWL-QN.
 
-Smooth objectives only (no box constraints / L1 — projections break the
-affinity; those route through optim.lbfgs / optim.owlqn).
+Smooth objectives only: a box routes through optim.lbfgs, an L1 term through
+optim.margin_owlqn (whose orthant projection its search answers with clip
+columns).
 """
 
 from __future__ import annotations
@@ -36,7 +38,7 @@ from typing import Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
-from photon_tpu.data.batch import LabeledBatch, SparseFeatures
+from photon_tpu.data.batch import LabeledBatch
 from photon_tpu.ops.objective import GLMObjective
 from photon_tpu.optim.common import (
     OptimizeResult,
@@ -47,6 +49,7 @@ from photon_tpu.optim.common import (
 )
 from photon_tpu.optim.lbfgs import two_loop_direction
 from photon_tpu.optim.linesearch import strong_wolfe
+from photon_tpu.optim.margins import GLMMargins
 
 Array = jax.Array
 
@@ -84,96 +87,13 @@ def minimize_lbfgs_margin(
     if objective.l1_weight > 0.0:
         raise ValueError("margin L-BFGS is for smooth objectives; use OWL-QN for L1")
 
-    loss = objective.loss
-    l2 = objective.l2_weight if l2_override is None else l2_override
-    has_l2 = l2_override is not None or objective.l2_weight != 0.0
-    norm = objective.normalization
-    factors = None if norm is None or norm.is_identity else norm.factors
-    shifts = None if norm is None or norm.is_identity else norm.shifts
-    label, weight, offset = batch.label, batch.weight, batch.offset
-    feats = batch.features
-    # Fused Pallas gradient pass: one X read yields value + gradient + FRESH
-    # margins (ops/pallas_glm), replacing the separate Xᵀ·dz pass and the
-    # incremental z += α·u update. Same 2-X-passes/iter, but the carried
-    # margins are exact every iteration — which is what makes bfloat16 X
-    # safe (no accumulated drift), halving the bandwidth-bound HBM traffic.
-    # Caller contract (ops/objective._can_fuse): dense unsharded features,
-    # no shift normalization, d within the VMEM tile budget.
-    use_fused = objective.use_pallas and objective._can_fuse(batch)
-
-    def matvec(p: Array) -> Array:
-        """u = d(margins)/dα along direction p (normalization folded)."""
-        ep = p if factors is None else p * factors
-        if isinstance(feats, SparseFeatures):
-            u = feats.matvec(ep)
-        elif feats.dtype == jnp.bfloat16:
-            # bf16 X stream with f32 accumulation on the MXU; the bf16
-            # rounding of the direction only perturbs the line-search
-            # parametrization (the accepted w stays f32, and the fused
-            # gradient pass refreshes margins exactly from it).
-            u = jnp.dot(feats, ep.astype(jnp.bfloat16),
-                        preferred_element_type=jnp.float32)
-        else:
-            u = feats @ ep
-        if shifts is not None:
-            u = u - jnp.dot(shifts, ep)
-        return u
-
-    def fused_value_grad_margins(w: Array):
-        """One X pass: value, gradient, and fresh margins at w."""
-        from photon_tpu.ops.pallas_glm import fused_data_value_and_grad
-
-        ew = w if factors is None else w * factors
-        val, g, z = fused_data_value_and_grad(
-            loss, ew, feats, label, offset, weight, return_margins=True
-        )
-        if factors is not None:
-            g = g * factors
-        if has_l2:
-            g = g + l2 * _l2_mask(w)
-        return val + l2_value(w), g, z
-
-    def grad_from_margins(z: Array, w: Array) -> Array:
-        dz = weight * loss.dz(z, label)
-        g = feats.rmatvec(dz) if isinstance(feats, SparseFeatures) else feats.T @ dz
-        if shifts is not None:
-            g = g - jnp.sum(dz) * shifts
-        if factors is not None:
-            g = g * factors
-        if has_l2:
-            g = g + l2 * _l2_mask(w)
-        return g
-
-    def _l2_mask(w: Array) -> Array:
-        if objective.intercept_index is None:
-            return w
-        return w.at[objective.intercept_index].set(0.0)
-
-    def data_value(z: Array) -> Array:
-        return jnp.sum(weight * loss.value(z, label))
-
-    def l2_value(w: Array) -> Array:
-        if not has_l2:
-            return jnp.zeros((), w0.dtype)
-        wm = _l2_mask(w)
-        return 0.5 * l2 * jnp.dot(wm, wm)
+    mg = GLMMargins(objective, batch, l2_override)
 
     m, max_iter, tol = config.memory, config.max_iter, config.tol
     d = w0.shape[0]
     dtype = w0.dtype
 
-    if use_fused:
-        f0, g0, z0 = fused_value_grad_margins(w0)
-        init_evals = 1  # one fused pass
-    else:
-        if start_score is None:
-            z0 = objective.margins(w0, batch)
-            init_evals = 2  # margins + gradient passes
-        else:
-            z0 = start_score + offset
-            init_evals = 1  # the gradient pass alone
-        f0 = data_value(z0) + l2_value(w0)
-        g0 = grad_from_margins(z0, w0)
+    f0, g0, z0, init_evals = mg.start(w0, start_score)
     g0_norm = jnp.linalg.norm(g0)
 
     hist_len = config.history_len
@@ -207,20 +127,20 @@ def minimize_lbfgs_margin(
         p = jnp.where(bad_dir, -g, p)
         dg0 = jnp.where(bad_dir, -jnp.dot(g, g), dg0)
 
-        u = matvec(p)  # the ONE X pass for this whole line search
+        u = mg.matvec(p)  # the ONE X pass for this whole line search
         # L2 along the path: quadratic with analytic coefficients.
-        if has_l2:
-            wm, pm = _l2_mask(w), _l2_mask(p)
-            l2_a = l2 * jnp.dot(wm, pm)
-            l2_b = l2 * jnp.dot(pm, pm)
+        if mg.has_l2:
+            wm, pm = mg.l2_mask(w), mg.l2_mask(p)
+            l2_a = mg.l2 * jnp.dot(wm, pm)
+            l2_b = mg.l2 * jnp.dot(pm, pm)
         else:
             l2_a = l2_b = jnp.zeros((), dtype)
-        f_l2 = l2_value(w)
+        f_l2 = mg.l2_value(w)
 
         def ls_fg(a):
             za = z + a * u
-            dza = weight * loss.dz(za, label)
-            val = data_value(za) + f_l2 + a * l2_a + 0.5 * a * a * l2_b
+            dza = mg.weight * mg.loss.dz(za, mg.label)
+            val = mg.data_value(za) + f_l2 + a * l2_a + 0.5 * a * a * l2_b
             deriv = jnp.dot(u, dza) + l2_a + a * l2_b
             return val, deriv
 
@@ -234,14 +154,14 @@ def minimize_lbfgs_margin(
         )
 
         w_new = w + ls.alpha * p
-        if use_fused:
+        if mg.use_fused:
             # Second X pass: fused value+grad+margins at w_new — carried
             # margins refreshed exactly, no incremental drift.
-            f_new, g_new, z_new = fused_value_grad_margins(w_new)
+            f_new, g_new, z_new = mg.fused_value_grad_margins(w_new)
         else:
             z_new = z + ls.alpha * u  # incremental margin update — no X pass
-            f_new = data_value(z_new) + l2_value(w_new)
-            g_new = grad_from_margins(z_new, w_new)  # second X pass
+            f_new = mg.data_value(z_new) + mg.l2_value(w_new)
+            g_new = mg.grad_from_margins(z_new, w_new)  # second X pass
 
         s = w_new - w
         y = g_new - g
@@ -298,7 +218,7 @@ def minimize_lbfgs_margin(
         eval_unit="x_passes",
     )
     if return_score:
-        return result, st["z"] - offset
+        return result, st["z"] - batch.offset
     return result
 
 

@@ -163,11 +163,38 @@ def _box():
 
 
 ROUTES = {
-    "owlqn": (dict(l1_weight=2.0), lambda: OptimizerSpec()),
     "tron": ({}, lambda: OptimizerSpec(optimizer=OptimizerType.TRON)),
     "lbfgsb": ({}, lambda: OptimizerSpec(optimizer=OptimizerType.LBFGSB, box=_box())),
     "lbfgs": ({}, lambda: OptimizerSpec(box=_box())),
 }
+
+
+@pytest.mark.parametrize("norm", ["identity", "factors_shifts"])
+def test_an_l1_solver_hands_back_its_margins(norm):
+    """An L1 term routes to margin-space OWL-QN, which starts from the held
+    scores and hands its margins back like margin-space L-BFGS."""
+    X, y, residual = design()
+    batch = game_batch(X, y)
+    objective = GLMObjective(
+        loss=LogisticLoss, l2_weight=1.0, l1_weight=2.0, intercept_index=0,
+        normalization=normalization(norm, X),
+    )
+    spec = OptimizerSpec()
+    assert routed_solver(objective, spec) == "owlqn_margin"
+    assert carries_margins(objective, spec)
+    coord = fixed_coordinate(objective, spec)
+    registry().reset()
+    model, diag, scores = coord.update(batch, jnp.asarray(residual))
+    assert diag.optimizer == "owlqn_margin"
+    assert_scores_of(model, batch, scores)
+    again, _diag, scores2 = coord.update(batch, jnp.asarray(-residual), model, scores)
+    assert_scores_of(again, batch, scores2)
+    assert int(diag.iterations) > 1
+    assert counted("fe_score_source_total", "solver_margins") == 2
+    assert counted("fe_score_source_total", "fused_pass") == 0
+    assert counted("fe_start_margins_total", "zero") == 1
+    assert counted("fe_start_margins_total", "prior_score") == 1
+    assert coord.solve_cache.stats.traces == 1  # held scores from zero, then the model's
 
 
 @pytest.mark.parametrize("norm", ["identity", "factors_shifts"])

@@ -471,10 +471,11 @@ def test_profile_event_payload_is_the_tracker_summary(glmix):
 # The fixed effect's solver and its penalty by configuration: L2 alone routes
 # to margin-space L-BFGS; an elastic net (benchmark/configs/
 # glmix2-poisson-enet.json's shape: a Poisson loss, weight and alpha) to
-# OWL-QN, whose counters and gauge are published when a tracker is READ.
+# margin-space OWL-QN, whose counters and gauge are published when a tracker
+# is READ. Both carry margins across the solve's boundary.
 FIT_CASES = {
     "logistic_l2": (TaskType.LOGISTIC_REGRESSION, (1.0, 0.0), "lbfgs_margin"),
-    "poisson_elastic_net": (TaskType.POISSON_REGRESSION, (16.0, 0.5), "owlqn"),
+    "poisson_elastic_net": (TaskType.POISSON_REGRESSION, (16.0, 0.5), "owlqn_margin"),
 }
 
 
@@ -556,7 +557,7 @@ def test_warm_fit_fe_update_launches_no_eager_score(case, glmix, monkeypatch, ca
     starting margins and each new score came from."""
     from photon_tpu.obs.metrics import registry
 
-    estimator, opt, optimizer = estimator_of(case)
+    estimator, opt, _optimizer = estimator_of(case)
     batch = glmix[0]
     with caplog.at_level(logging.WARNING, logger="photon_tpu"):
         estimator.fit(batch, optimization_configs=[opt])
@@ -591,10 +592,7 @@ def test_warm_fit_fe_update_launches_no_eager_score(case, glmix, monkeypatch, ca
         found = registry().find(name, coordinate="global", source=source)
         return 0 if found is None else found.value
 
-    if optimizer == "lbfgs_margin":
-        want = dict(solver_margins=2, fused_pass=0, zero=1, prior_score=1, recomputed=0)
-    else:
-        want = dict(solver_margins=0, fused_pass=2, zero=0, prior_score=0, recomputed=2)
+    want = dict(solver_margins=2, fused_pass=0, zero=1, prior_score=1, recomputed=0)
     got = {s: counted("fe_score_source_total", s)
            for s in ("solver_margins", "fused_pass")}
     got.update({s: counted("fe_start_margins_total", s)

@@ -5,9 +5,9 @@ What is held here, on the CPU at a small size: ``GameEstimator.fit`` against
 the benchmark's proximal-Newton reference (which imports nothing of the
 program); that reference's fixed-effect block against a brute-force search
 round its answer; a trial that overflows ``exp`` in float32 is a rejected
-trial in OWL-QN's backtracking and in the Newton sweep; the tracker's unit
-of work follows the solver; the solver's counters and the gauge of non-zero
-coefficients appear when a tracker is read.
+trial in OWL-QN's backtracking (both its searches) and in the Newton sweep;
+the tracker's unit of work follows the solver; the solver's counters and the
+gauge of non-zero coefficients appear when a tracker is read.
 """
 
 import dataclasses
@@ -31,6 +31,7 @@ from photon_tpu.optim.common import (
     OptimizerConfig,
 )
 from photon_tpu.optim.factory import OptimizerSpec, make_optimizer
+from photon_tpu.optim.margin_owlqn import minimize_owlqn_margin
 from photon_tpu.optim.newton import minimize_newton
 from photon_tpu.optim.owlqn import minimize_owlqn
 
@@ -144,31 +145,45 @@ def wide_poisson(n=512, scale=200.0, seed=3):
                         jnp.ones(n, jnp.float32))
 
 
-def test_owlqn_first_trial_overflows_and_the_solve_still_converges():
+def owlqn(path, objective, batch, w0, config=OptimizerConfig()):
+    """OWL-QN by either search: the black box over ``value_and_grad`` or the
+    margin path (what ``make_optimizer`` routes a ``LabeledBatch`` to)."""
+    if path == "owlqn":
+        return jax.jit(lambda w: minimize_owlqn(
+            lambda v: objective.value_and_grad(v, batch), w,
+            objective.l1_weight, config, objective.l1_mask(w)))(w0)
+    return jax.jit(lambda w: minimize_owlqn_margin(objective, batch, w, config))(w0)
+
+
+@pytest.mark.parametrize("path", ["owlqn", "owlqn_margin"])
+def test_owlqn_first_trial_overflows_and_the_solve_still_converges(path):
     batch = wide_poisson()
     objective = GLMObjective(loss=PoissonLoss, l2_weight=0.5, l1_weight=0.5,
                              intercept_index=0)
     w0 = jnp.zeros((2,), jnp.float32)
     first = objective.full_value(jnp.asarray([0.0, 1.0], jnp.float32), batch)
     assert not np.isfinite(float(first))   # the unit step does overflow
-    res = jax.jit(make_optimizer(objective, OptimizerSpec()))(w0, batch)
-    assert res.optimizer == "owlqn"
+    if path == "owlqn_margin":   # the route a LabeledBatch takes
+        res = jax.jit(make_optimizer(objective, OptimizerSpec()))(w0, batch)
+        assert res.optimizer == path
+    else:
+        res = owlqn(path, objective, batch, w0)
     assert np.all(np.isfinite(np.asarray(res.w))) and np.isfinite(float(res.value))
     assert int(res.reason_code) != REASON_DIVERGED and res.converged
-    assert int(res.evals) > int(res.iterations) + 1   # trials were rejected
+    assert int(res.trials) > int(res.iterations)   # trials were rejected
     want = reference.solve_fixed(batch.features, batch.label, batch.offset, 0.5, 0.5, 0)
     # the feature's scale of 200 leaves the stopping rule 2e-4 from the optimum
     np.testing.assert_allclose(np.asarray(res.w), np.asarray(want), atol=1e-3)
 
 
-def test_owlqn_search_without_an_accepted_trial_keeps_the_iterate():
+@pytest.mark.parametrize("path", ["owlqn", "owlqn_margin"])
+def test_owlqn_search_without_an_accepted_trial_keeps_the_iterate(path):
     """Two trials, both at inf: the parent took the last one for a step."""
     batch = wide_poisson()
     objective = GLMObjective(loss=PoissonLoss, l1_weight=0.5, intercept_index=0)
     w0 = jnp.asarray([-0.5, 0.0], jnp.float32)
     config = OptimizerConfig(max_line_search_evals=2)
-    res = minimize_owlqn(lambda w: objective.value_and_grad(w, batch), w0, 0.5,
-                         config, objective.l1_mask(w0))
+    res = owlqn(path, objective, batch, w0, config)
     np.testing.assert_array_equal(np.asarray(res.w), np.asarray(w0))
     assert np.isfinite(float(res.value)) and np.isfinite(float(res.grad_norm))
     assert int(res.reason_code) == REASON_OBJECTIVE_NOT_IMPROVING
@@ -198,7 +213,7 @@ def test_newton_sweep_whose_every_trial_overflows_rejects_and_recovers():
 
 
 @pytest.mark.parametrize("l1,unit,optimizer", [
-    (4.0, "objective_evals", "owlqn"), (0.0, "x_passes", "lbfgs_margin")])
+    (4.0, "x_passes", "owlqn_margin"), (0.0, "x_passes", "lbfgs_margin")])
 def test_fixed_effect_tracker_unit_follows_the_solver(l1, unit, optimizer):
     batch = wide_poisson(scale=1.0)
     objective = GLMObjective(loss=PoissonLoss, l2_weight=1.0, l1_weight=l1,
@@ -214,14 +229,14 @@ def test_counters_and_gauge_are_published_when_the_trackers_are_read(fitted):
     registry().reset()
     # the fixture's trackers may have been read by an earlier test: read copies
     fresh = [dataclasses.replace(d) for d in tracker["global"]]
-    labels = dict(coordinate="global", optimizer="owlqn")
+    labels = dict(coordinate="global", optimizer="owlqn_margin")
     assert registry().find("fe_solver_iterations_total", **labels) is None
     diags = [d.diagnostics_dict() for d in fresh]
     for d in fresh:
         d.summary()   # a second read publishes nothing more
     assert registry().find("fe_solver_iterations_total", **labels).value == sum(
         d["iterations"] for d in diags)
-    assert registry().find("fe_solver_evals_total", unit="objective_evals",
+    assert registry().find("fe_solver_evals_total", unit="x_passes",
                            **labels).value == sum(d["evals"] for d in diags)
     gauge = registry().find("fe_nonzero_coefficients", coordinate="global")
     assert gauge.value == np.count_nonzero(np.asarray(model["global"]))
