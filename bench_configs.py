@@ -24,7 +24,8 @@ Configs (BASELINE.md "Benchmark configs to stand up"):
   2. Linear regression + L2 via TRON (trust-region Newton, ≤20 CG H·v per
      outer iteration; reference optimization/TRON.scala:148-329). evals
      counts f/g evaluations AND CG H·v products (each ≈ 2 X passes, the
-     same unit) — trial traffic is in the model, per VERDICT r2.
+     same unit), from the solver's iterations and CG steps — trial traffic
+     is in the model, per VERDICT r2.
   3. Poisson elastic-net via OWL-QN (reference OWLQN.scala:39-70), L1+L2.
      CPU baseline: scipy L-BFGS-B on the split-variable (w⁺, w⁻)
      formulation — the standard smooth reformulation of the L1 term.
@@ -265,7 +266,9 @@ def run_tron_linear() -> dict:
             cfg,
             hvp_factory=lambda w: obj.linearized_hvp(w, b),
         )
-        return res.w, res.evals
+        # f/g or H·v evaluations: the start, then per outer iteration the
+        # trial, ρ's product and one product a CG step.
+        return res.w, 1 + 2 * res.iterations + res.cg_steps
 
     _progress("config 2: compiling + warm-up")
     w, ev = solve(jnp.zeros(_TRON_D, jnp.float32), batch)

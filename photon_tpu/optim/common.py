@@ -84,11 +84,12 @@ class OptimizeResult:
     loss_history: Array
     grad_norm_history: Array
     # Work counter for throughput accounting. Its unit is ``eval_unit``:
-    # black-box solvers (LBFGS/OWL-QN/LBFGS-B/TRON) count objective
-    # evaluations including line-search trials ("objective_evals", each = 2
-    # feature-matrix passes); margin-space L-BFGS and OWL-QN and Newton count
-    # feature-matrix passes directly ("x_passes"). Consumers aggregating
-    # across solvers must check the unit (bench.py normalizes to passes).
+    # black-box solvers (LBFGS/OWL-QN/LBFGS-B) count objective evaluations
+    # including line-search trials ("objective_evals", each = 2
+    # feature-matrix passes); margin-space L-BFGS and OWL-QN, Newton and
+    # TRON count feature-matrix passes directly ("x_passes"). Consumers
+    # aggregating across solvers must check the unit (bench.py normalizes
+    # to passes).
     evals: Array = dataclasses.field(default_factory=lambda: jnp.zeros((), jnp.int32))
     eval_unit: str = dataclasses.field(
         default="objective_evals", metadata=dict(static=True)
@@ -104,6 +105,11 @@ class OptimizeResult:
     # (``optim/margin_owlqn.py``). None where the solver counts no trials.
     trials: Optional[Array] = None
     margin_trials: Optional[Array] = None
+    # A TRON solve's conjugate-gradient steps summed over its outer
+    # iterations, and the outer iterations whose step the trust region
+    # refused (``optim/tron.py``). None where the solver has neither.
+    cg_steps: Optional[Array] = None
+    rejected_steps: Optional[Array] = None
     # Which solver the factory routed to ("owlqn_margin", "lbfgs_margin", ...) and
     # which coordinate it solved: the labels this result is published under
     # when it is read. Empty where no factory or coordinate made it.
@@ -146,7 +152,7 @@ class OptimizeResult:
         arithmetic. Still a read the dispatch loop must not make — call it
         at run-report finalize."""
         h = self._on_host()
-        return dict(
+        out = dict(
             type="fixed_effect",
             iterations=int(h.iterations),
             value=float(h.value),
@@ -156,6 +162,10 @@ class OptimizeResult:
             evals=int(h.evals),
             eval_unit=self.eval_unit,
         )
+        if h.cg_steps is not None:
+            out.update(cg_steps=int(h.cg_steps),
+                       rejected_steps=int(h.rejected_steps))
+        return out
 
     def summary(self) -> str:
         """Human-readable per-iteration table (tracker toSummaryString):
@@ -181,9 +191,9 @@ class OptimizeResult:
 
 def _publish(host: OptimizeResult) -> None:
     """One solve's work into the registry, from the host copy the tracker's
-    readers already made: iterations, evaluations and (OWL-QN) line-search
-    trials as counters, the count of non-zero coefficients as a gauge (the
-    last solve's)."""
+    readers already made: iterations, evaluations, (OWL-QN) line-search
+    trials and (TRON) CG and rejected steps as counters, the count of
+    non-zero coefficients as a gauge (the last solve's)."""
     from photon_tpu.obs.metrics import registry
 
     labels = dict(coordinate=host.coordinate, optimizer=host.optimizer)
@@ -200,6 +210,13 @@ def _publish(host: OptimizeResult) -> None:
         registry().counter("fe_line_search_margin_trials_total", **labels).inc(
             int(host.margin_trials)
         )
+    if host.cg_steps is not None:
+        registry().counter(
+            "fe_tron_cg_steps_total", coordinate=host.coordinate
+        ).inc(int(host.cg_steps))
+        registry().counter(
+            "fe_tron_rejected_steps_total", coordinate=host.coordinate
+        ).inc(int(host.rejected_steps))
     if int(host.nonzeros) >= 0:
         registry().gauge(
             "fe_nonzero_coefficients", coordinate=host.coordinate

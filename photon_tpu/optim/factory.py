@@ -118,8 +118,8 @@ def make_optimizer(
                 )
             elif name == "tron":
                 # Factory form: margins/curvature built once per outer
-                # iteration, shared across that iteration's CG products (2 X
-                # passes each).
+                # iteration (1 X pass), shared across that iteration's CG
+                # products (2 X passes each).
                 res = minimize_tron(
                     vg, None, w0, config, spec.max_cg_iter, spec.box,
                     hvp_factory=lambda w: objective.linearized_hvp(w, batch),
@@ -142,6 +142,9 @@ def make_optimizer(
         if score is None:
             with jax.named_scope("score"):
                 score = objective.scores(res.w, batch)
+            if res.eval_unit == "x_passes":
+                # The score pass is the program's too.
+                res = dataclasses.replace(res, evals=res.evals + 1)
         return res, score
 
     return solve

@@ -18,6 +18,18 @@ eta2=0.75 and shrink/grow factors sigma1=0.25, sigma3=4 (standard published
 values). Unlike LIBLINEAR's exact radius schedule, the middle band
 (eta1 <= rho < eta2) keeps the radius unchanged — the textbook TR update —
 which avoids the geometric shrink that stalls runs whose rho hovers there.
+
+Work is counted in passes over X (``eval_unit="x_passes"``), one a
+matrix-vector product of the factory form over a dense X: 2 for the
+starting value and gradient; per outer iteration 1 for the margins the H·v
+factory linearizes at, 2 per CG product (a forward and a transpose pass), 2
+for the trial's value and gradient and 2 for ρ's product. The trial's and
+ρ's products are independent of each other, so a compiler may serve both
+forward passes with one read of X and both transposes with another (XLA
+does on a TPU v5e). ``cg_steps`` and ``rejected_steps`` come out of the
+loop beside it. Inside the program the CG loop runs under the scope ``cg``
+and the trial's evaluation (its value, gradient and ρ's product) under
+``trial``.
 """
 
 from __future__ import annotations
@@ -42,6 +54,10 @@ Hvp = Callable[[Array, Array], Array]
 
 ETA0, ETA1, ETA2 = 1e-4, 0.25, 0.75
 SIGMA1, SIGMA3 = 0.25, 4.0
+
+# Passes over X: a value and gradient, a Hessian-vector product, and the
+# margins an H·v factory linearizes at.
+VG_PASSES, HVP_PASSES, BUILD_PASSES = 2, 2, 1
 
 TRON_DEFAULT_CONFIG = OptimizerConfig(max_iter=15, tol=1e-5)
 
@@ -116,6 +132,11 @@ def minimize_tron(
         multipliers) is shared across all ≤max_cg_iter CG products of that
         iteration instead of recomputed inside each one
         (GLMObjective.linearized_hvp halves the X traffic this way).
+
+    ``result.evals`` counts the factory form's passes over a dense X (the
+    module's docstring); a jvp-of-grad ``hvp`` and the fused kernels pay
+    other numbers of passes for the same work, which ``iterations`` and
+    ``cg_steps`` state whatever the form.
     """
     if hvp_factory is None:
         if hvp is None:
@@ -133,7 +154,9 @@ def minimize_tron(
     state0 = dict(
         w=w0, f=f0, g=g0, delta=delta0,
         it=jnp.int32(0), reason=jnp.int32(REASON_NOT_CONVERGED),
-        evals=jnp.int32(1),
+        evals=jnp.int32(VG_PASSES),
+        cg_steps=jnp.int32(0),
+        rejected=jnp.int32(0),
         loss_hist=jnp.full((hist_len,), f0, dtype),
         gnorm_hist=jnp.full((hist_len,), g0_norm, dtype),
     )
@@ -146,14 +169,16 @@ def minimize_tron(
         gnorm = jnp.linalg.norm(g)
         cg_tol = 0.1 * gnorm
         hv = hvp_factory(w)  # one build per outer iteration
-        s, _hit, cg_iters = _truncated_cg(hv, g, delta, max_cg_iter, cg_tol)
+        with jax.named_scope("cg"):
+            s, _hit, cg_iters = _truncated_cg(hv, g, delta, max_cg_iter, cg_tol)
 
         w_trial = project_to_box(w + s, box)
         s_eff = w_trial - w
-        f_trial, g_trial = value_and_grad(w_trial)
-
-        # Predicted reduction from the quadratic model (on the effective step).
-        Hs = hv(s_eff)
+        with jax.named_scope("trial"):
+            f_trial, g_trial = value_and_grad(w_trial)
+            # Predicted reduction from the quadratic model (on the effective
+            # step).
+            Hs = hv(s_eff)
         pred = -(jnp.dot(g, s_eff) + 0.5 * jnp.dot(s_eff, Hs))
         actual = f - f_trial
         rho = actual / jnp.maximum(pred, 1e-30)
@@ -189,14 +214,15 @@ def minimize_tron(
                 jnp.int32(REASON_NOT_CONVERGED),
             ),
         )
-        # Work accounting: 1 value_and_grad at the trial point, plus one H·v
-        # per CG iteration and one for the ρ denominator — an H·v (jvp of
-        # grad) streams the data the same ~2 passes a value_and_grad does,
-        # so both count as one "objective_evals" unit (TRON.scala:287-326:
-        # each of these was a treeAggregate round).
+        # Passes over X: the margins the factory linearized at, one H·v a
+        # CG step, the trial's value and gradient, and ρ's H·v
+        # (TRON.scala:287-326: each product was a treeAggregate round).
+        passes = BUILD_PASSES + HVP_PASSES * (cg_iters + 1) + VG_PASSES
         return dict(
             w=w_new, f=f_new, g=g_new, delta=delta_new, it=it, reason=reason,
-            evals=st["evals"] + 2 + cg_iters,
+            evals=st["evals"] + passes,
+            cg_steps=st["cg_steps"] + cg_iters,
+            rejected=st["rejected"] + (~accept).astype(jnp.int32),
             loss_hist=st["loss_hist"].at[jnp.minimum(it, config.history_len - 1)].set(f_new),
             gnorm_hist=st["gnorm_hist"].at[jnp.minimum(it, config.history_len - 1)].set(gn),
         )
@@ -212,5 +238,6 @@ def minimize_tron(
         w=st["w"], value=st["f"], grad_norm=jnp.linalg.norm(st["g"]),
         iterations=st["it"], reason_code=reason,
         loss_history=loss_hist, grad_norm_history=gnorm_hist,
-        evals=st["evals"],
+        evals=st["evals"], eval_unit="x_passes",
+        cg_steps=st["cg_steps"], rejected_steps=st["rejected"],
     )

@@ -142,10 +142,20 @@ def test_legacy_glm_driver_libsvm(tmp_path):
             "--output-dir", str(out),
             "--regularization-weights", "0.1,1,10",
             "--optimizer", "TRON",
+            "--telemetry-out", str(tmp_path / "run.jsonl"),
         ]
     )
     summary = train_glm.run(args)
     assert summary["stage"] == "VALIDATED"
+    # Each λ's TRON solve reports its passes over X: the start, 5 an outer
+    # iteration, 2 a CG product, and the solve program's score pass.
+    rows = [r["diagnostics"] for r in map(
+        json.loads, (tmp_path / "run.jsonl").read_text().splitlines())
+        if r["record"] == "coordinate_descent"]
+    assert len(rows) == 3
+    for d in rows:
+        assert d["eval_unit"] == "x_passes"
+        assert d["evals"] == 3 + 5 * d["iterations"] + 2 * d["cg_steps"]
     assert len(summary["models"]) == 3
     # Best model by AUC present + text model files written.
     assert any(f.startswith("model-lambda-") for f in os.listdir(out))

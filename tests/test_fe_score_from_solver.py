@@ -221,6 +221,13 @@ def test_a_black_box_solver_scores_in_its_own_program(route, norm):
     assert counted("fe_score_source_total", "solver_margins") == 0
     assert counted("fe_start_margins_total", "recomputed") == 2
     assert coord.solve_cache.stats.traces == 1  # the held scores are not passed
+    d = diag.diagnostics_dict()
+    if route == "tron":
+        # passes over X, the program's closing score pass among them
+        assert d["eval_unit"] == "x_passes"
+        assert d["evals"] == 3 + 5 * d["iterations"] + 2 * d["cg_steps"]
+    else:
+        assert d["eval_unit"] == "objective_evals" and "cg_steps" not in d
 
 
 def test_the_fused_pallas_path_hands_back_its_fresh_margins():

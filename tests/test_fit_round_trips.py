@@ -472,10 +472,14 @@ def test_profile_event_payload_is_the_tracker_summary(glmix):
 # to margin-space L-BFGS; an elastic net (benchmark/configs/
 # glmix2-poisson-enet.json's shape: a Poisson loss, weight and alpha) to
 # margin-space OWL-QN, whose counters and gauge are published when a tracker
-# is READ. Both carry margins across the solve's boundary.
+# is READ. Both carry margins across the solve's boundary. TRON on a squared
+# loss (benchmark/configs/glmix2-linear-tron.json's shape) carries none: its
+# program ends in one score pass, and its CG and rejected steps are
+# published when a tracker is read.
 FIT_CASES = {
     "logistic_l2": (TaskType.LOGISTIC_REGRESSION, (1.0, 0.0), "lbfgs_margin"),
     "poisson_elastic_net": (TaskType.POISSON_REGRESSION, (16.0, 0.5), "owlqn_margin"),
+    "linear_tron": (TaskType.LINEAR_REGRESSION, (1.0, 0.0), "tron"),
 }
 
 
@@ -488,12 +492,14 @@ def estimator_of(case):
         RegularizationConfig,
     )
     from photon_tpu.estimators.game_estimator import GameEstimator
+    from photon_tpu.types import OptimizerType
 
     task, (weight, alpha), optimizer = FIT_CASES[case]
+    configured = OptimizerType.TRON if optimizer == "tron" else OptimizerType.LBFGS
     estimator = GameEstimator(
         task=task,
         coordinate_configs=[
-            FixedEffectCoordinateConfig("global", "global"),
+            FixedEffectCoordinateConfig("global", "global", optimizer=configured),
             RandomEffectCoordinateConfig("per_user", "userId", "per_user"),
         ],
         num_iterations=2,
@@ -533,6 +539,7 @@ def test_second_fit_reads_nothing_back_from_the_device(
     # with the tracker's one transfer, when somebody reads it.
     labels = dict(coordinate="global", optimizer=optimizer)
     assert registry().find("fe_solver_iterations_total", **labels) is None
+    assert registry().find("fe_tron_cg_steps_total", coordinate="global") is None
     with device_gets(monkeypatch) as gets:
         diags = [d.diagnostics_dict() for d in second.tracker["global"]]
         [d.summary() for d in second.tracker["global"]]  # the copy is kept
@@ -540,6 +547,13 @@ def test_second_fit_reads_nothing_back_from_the_device(
     assert registry().find("fe_solver_iterations_total", **labels).value == sum(
         d["iterations"] for d in diags
     )
+    cg = registry().find("fe_tron_cg_steps_total", coordinate="global")
+    rejected = registry().find("fe_tron_rejected_steps_total", coordinate="global")
+    if optimizer == "tron":
+        assert cg.value == sum(d["cg_steps"] for d in diags) > 0
+        assert rejected.value == sum(d["rejected_steps"] for d in diags)
+    else:
+        assert cg is None and rejected is None
     for a, b in zip(jax.tree_util.tree_leaves(first.model),
                     jax.tree_util.tree_leaves(second.model), strict=True):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -592,7 +606,12 @@ def test_warm_fit_fe_update_launches_no_eager_score(case, glmix, monkeypatch, ca
         found = registry().find(name, coordinate="global", source=source)
         return 0 if found is None else found.value
 
-    want = dict(solver_margins=2, fused_pass=0, zero=1, prior_score=1, recomputed=0)
+    if FIT_CASES[case][2] == "tron":
+        want = dict(solver_margins=0, fused_pass=2, zero=0, prior_score=0,
+                    recomputed=2)
+    else:
+        want = dict(solver_margins=2, fused_pass=0, zero=1, prior_score=1,
+                    recomputed=0)
     got = {s: counted("fe_score_source_total", s)
            for s in ("solver_margins", "fused_pass")}
     got.update({s: counted("fe_start_margins_total", s)

@@ -107,6 +107,8 @@ def test_tron_logistic_matches_lbfgs():
     res = minimize_tron(vg, hvp, jnp.zeros(X.shape[1], jnp.float32))
     w_ref, f_ref = scipy_logistic_opt(X, y, 0.1)
     assert float(res.value) <= f_ref + 1e-2
+    assert res.eval_unit == "x_passes"
+    assert int(res.evals) == 2 + 5 * int(res.iterations) + 2 * int(res.cg_steps)
 
 
 def test_tron_poisson():
@@ -123,6 +125,66 @@ def test_tron_poisson():
     )
     g = np.asarray(obj.grad(res.w, batch))
     assert np.linalg.norm(g) < 1e-2 * max(1.0, np.linalg.norm(np.asarray(obj.grad(jnp.zeros(d), batch))))
+    assert res.eval_unit == "x_passes" and int(res.cg_steps) >= int(res.iterations)
+
+
+def _counting_tron(problem):
+    """A TRON solve whose value-and-gradient, H·v factory and products each
+    add the passes over X they make to a host count as they run, through
+    the factory form ``GLMObjective.linearized_hvp``."""
+    n, d = 256, 8
+    local = np.random.default_rng(7)
+    if problem == "poisson_steep":
+        # Features of scale 3 from a start at zero: exp's curvature grows
+        # faster than the quadratic model says, and the first steps are refused.
+        X = local.normal(scale=3.0, size=(n, d)).astype(np.float32)
+        y = local.poisson(np.exp(X @ local.normal(scale=0.3, size=d))).astype(np.float32)
+        obj = GLMObjective(loss=PoissonLoss, l2_weight=1.0)
+    else:
+        X = local.normal(size=(n, d)).astype(np.float32)
+        y = (X @ local.normal(size=d) + local.normal(size=n)).astype(np.float32)
+        obj = GLMObjective(loss=SquaredLoss, l2_weight=1.0)
+    batch = LabeledBatch(jnp.asarray(y), jnp.asarray(X))
+    passes = []
+
+    def count(k):
+        jax.debug.callback(lambda: passes.append(k))
+
+    def vg(w):
+        count(2)
+        return obj.value_and_grad(w, batch)
+
+    def factory(w):
+        count(1)
+        hv = obj.linearized_hvp(w, batch)
+
+        def product(v):
+            count(2)
+            return hv(v)
+
+        return product
+
+    res = jax.jit(lambda w0: minimize_tron(vg, None, w0, hvp_factory=factory))(
+        jnp.zeros(d, jnp.float32))
+    jax.effects_barrier()
+    return res, sum(passes)
+
+
+@pytest.mark.parametrize("problem", ["squared", "poisson_steep"])
+def test_tron_passes_are_its_iterations_and_cg_steps(problem):
+    """The reported passes are the passes the solve made: 2 for the start,
+    then per outer iteration the linearized margins, 2 a CG product, 2 for
+    the trial and 2 for ρ's product."""
+    res, made = _counting_tron(problem)
+    its, cg = int(res.iterations), int(res.cg_steps)
+    assert res.eval_unit == "x_passes" and its >= 1 and cg >= its
+    assert int(res.evals) == made == 2 + 5 * its + 2 * cg
+    rejected = int(res.rejected_steps)
+    assert 0 <= rejected < its
+    if problem == "poisson_steep":
+        assert rejected >= 1
+    diag = res.diagnostics_dict()
+    assert (diag["cg_steps"], diag["rejected_steps"]) == (cg, rejected)
 
 
 def test_lbfgsb_respects_box():
