@@ -248,11 +248,13 @@ def run_tron_linear() -> dict:
     X, y = _linear_data()
     batch = LabeledBatch(jnp.asarray(y), jnp.asarray(X))
     jax.block_until_ready(batch.features)
-    # use_pallas: value/grad rides the fused one-pass kernel and each CG
-    # product the fused one-pass HVP (fused_data_hvp via linearized_hvp).
+    # use_pallas: value/grad rides the fused one-pass kernel; each CG
+    # product reads X once where the batch qualifies (one_read_hvp, asked
+    # of the concrete batch before the trace).
     obj = GLMObjective(
         loss=SquaredLoss, l2_weight=1.0, intercept_index=0, use_pallas=True
     )
+    one_read = obj.one_read_hvp(batch)
     cfg = OptimizerConfig(max_iter=15, tol=1e-5, track_history=False)
 
     # ``b`` rides as a jit argument: closing over it would bake the ~2 GB
@@ -264,7 +266,7 @@ def run_tron_linear() -> dict:
             None,
             w0,
             cfg,
-            hvp_factory=lambda w: obj.linearized_hvp(w, b),
+            hvp_factory=lambda w: obj.linearized_hvp(w, b, one_read=one_read),
         )
         # f/g or H·v evaluations: the start, then per outer iteration the
         # trial, ρ's product and one product a CG step.

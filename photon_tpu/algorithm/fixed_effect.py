@@ -128,7 +128,8 @@ class FixedEffectCoordinate(Coordinate):
         registry().counter(
             "fe_score_source_total", coordinate=self.coordinate_id, source=source
         ).inc()
-        solve = self.solve_cache.fe_solver(self.objective, self.optimizer_spec)
+        solve = self.solve_cache.fe_solver(
+            self.objective, self.optimizer_spec, lb)
         # Host-wall span of the dispatch (the solve itself runs async on
         # device; nothing here blocks).
         with span("fe_solve"):

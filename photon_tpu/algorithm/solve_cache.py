@@ -327,7 +327,7 @@ class SolveCache:
 
         return call
 
-    def fe_solver(self, objective, spec) -> Callable:
+    def fe_solver(self, objective, spec, batch=None) -> Callable:
         """Jitted fixed-effect solve ``(w0, labeled_batch, start_score=None)
         -> (OptimizeResult, score)`` for one (objective, spec). ``score`` is
         x·w at the result for every sample (what the model made of it scores
@@ -337,14 +337,28 @@ class SolveCache:
         where the caller holds it, which a margin-carrying solver starts
         from. The batch is a traced argument, so the one cache entry serves
         every batch of the same structure; w0 is NOT donated here
-        (fixed-effect warm starts alias live model buffers)."""
-        key = ("fe", self._objective_key(objective), self._spec_key(spec))
+        (fixed-effect warm starts alias live model buffers).
+
+        ``batch``, the concrete batch the solve will run on, decides before
+        any trace whether TRON's Hessian-vector products read X once
+        (``GLMObjective.one_read_hvp``: inside the trace a sharded batch
+        cannot be told from one on a single device); the decision is part
+        of the key. Without it every product reads X twice."""
+        from photon_tpu.optim.factory import make_optimizer, routed_solver
+
+        one_read = (
+            batch is not None
+            and routed_solver(objective, spec) == "tron"
+            and objective.one_read_hvp(batch)
+        )
+        key = ("fe", self._objective_key(objective), self._spec_key(spec),
+               one_read)
 
         def build():
             from photon_tpu.optim.common import REASON_DIVERGED
-            from photon_tpu.optim.factory import make_optimizer
 
-            solve = make_optimizer(objective, spec, with_score=True)
+            solve = make_optimizer(objective, spec, with_score=True,
+                                   one_read_hvp=one_read)
             stats = self.stats
 
             def traced(w0, lb, start_score=None):

@@ -475,7 +475,7 @@ def run(args) -> Dict:
         # λ solves route through the shared compiled-solver cache — same
         # semantics as make_optimizer, but retraces and hits are accounted
         # (and a repeated λ config reuses one executable).
-        solve = default_cache().fe_solver(objective, spec)
+        solve = default_cache().fe_solver(objective, spec, train)
         w0_lam = w
         t0 = time.monotonic()
         with span(f"glm/lambda{lam:g}"):

@@ -34,6 +34,12 @@ import jax.numpy as jnp
 from photon_tpu.data.batch import LabeledBatch, SparseFeatures, features_dot
 from photon_tpu.data.normalization import NormalizationContext
 from photon_tpu.ops.losses import PointwiseLoss
+from photon_tpu.ops.pallas_glm import (
+    MAX_FUSED_DIM,
+    data_hvp_operator,
+    fused_data_value_and_grad,
+    pallas_available,
+)
 
 Array = jax.Array
 
@@ -60,11 +66,8 @@ class GLMObjective:
     # Route dense value_and_grad through the fused Pallas kernel (one HBM
     # pass over X instead of XLA's two; photon_tpu.ops.pallas_glm). Falls
     # back automatically where the kernel doesn't apply (sparse features,
-    # shift normalization, very wide dims). Since the round-4 FE bandwidth
-    # A/B (bench --fe-bandwidth-ab) there is exactly one fused lowering —
-    # tall rebalanced tiles on a sequential grid, fused one-pass HVP — and
-    # it is the default for every fuse-eligible evaluation here; the
-    # losing variants were deleted from pallas_glm, not kept behind flags.
+    # shift normalization, very wide dims). Hessian-vector products do not
+    # read it: their lowering is ``linearized_hvp``'s ``one_read``.
     use_pallas: bool = dataclasses.field(default=False, metadata=dict(static=True))
 
     # ----- margins -----
@@ -124,10 +127,12 @@ class GLMObjective:
         return jax.value_and_grad(self.value)(w, batch)
 
     def _can_fuse(self, batch: LabeledBatch) -> bool:
-        if not self.use_pallas:
-            return False
-        from photon_tpu.ops.pallas_glm import MAX_FUSED_DIM
+        return self.use_pallas and self._fusible(batch)
 
+    def _fusible(self, batch: LabeledBatch) -> bool:
+        """What the fused kernels need of a batch: dense features no wider
+        than ``MAX_FUSED_DIM``, normalization without shifts, and data on
+        one device where the placement is visible (concrete arrays)."""
         feats = batch.features
         if isinstance(feats, SparseFeatures) or feats.shape[1] > MAX_FUSED_DIM:
             return False
@@ -147,9 +152,22 @@ class GLMObjective:
         norm = self.normalization
         return norm is None or norm.shifts is None
 
-    def _pallas_value_and_grad(self, w: Array, batch: LabeledBatch) -> Tuple[Array, Array]:
-        from photon_tpu.ops.pallas_glm import fused_data_value_and_grad
+    def one_read_hvp(self, batch) -> bool:
+        """Whether Hessian-vector products over ``batch`` take the one-read
+        kernel (``ops.pallas_glm.data_hvp_operator``), from static facts
+        alone: a ``LabeledBatch`` of dense features no wider than
+        ``MAX_FUSED_DIM``, on ONE device, normalization without shifts, and
+        a TPU backend. Ask it of a CONCRETE batch: a traced one (inside
+        ``jit`` or ``vmap``) hides where it lives, and a batch sharded over
+        devices must never be gathered to one, so a tracer answers False."""
+        if not (pallas_available() and isinstance(batch, LabeledBatch)):
+            return False
+        feats = batch.features
+        concrete = isinstance(feats, jax.Array) and not isinstance(
+            feats, jax.core.Tracer)
+        return concrete and self._fusible(batch)
 
+    def _pallas_value_and_grad(self, w: Array, batch: LabeledBatch) -> Tuple[Array, Array]:
         f = None if self.normalization is None else self.normalization.factors
         ew = w if f is None else w * f
         val, g = fused_data_value_and_grad(
@@ -172,7 +190,7 @@ class GLMObjective:
         no Hessian materialization (reference HessianVectorAggregator.scala)."""
         return jax.jvp(lambda u: self.grad(u, batch), (w,), (v,))[1]
 
-    def linearized_hvp(self, w: Array, batch: LabeledBatch):
+    def linearized_hvp(self, w: Array, batch: LabeledBatch, one_read: bool = False):
         """Build ``v -> H(w)·v`` with all w-dependent state computed ONCE.
 
         The GLM Hessian at fixed ``w`` is H = Aᵀ·diag(d2)·A + λ·mask, where
@@ -180,34 +198,35 @@ class GLMObjective:
         folding included) and d2 = weight·loss''(z, y) depends on w only
         through the margins z. The jvp-of-grad form recomputes z and the
         gradient inside every product (~4 X passes); here z/d2 are cached
-        so each product is exactly one forward and one transpose pass —
-        the same per-outer-iteration caching the reference's
+        so each product is one forward and one transpose pass, two reads
+        of X — the same per-outer-iteration caching the reference's
         HessianVectorAggregator gets from broadcasting the fixed
         coefficients once per CG solve (HessianVectorAggregator.scala).
         Inner solvers (TRON's truncated CG) should prefer this via
         ``minimize_tron(hvp_factory=...)``.
 
-        With ``use_pallas`` (and a fusible batch) each product runs the
-        one-pass fused kernel (ops.pallas_glm.fused_data_hvp): forward and
-        transpose matvec share a single HBM read of each X tile.
+        ``one_read`` (a caller that asked ``one_read_hvp`` of the concrete
+        batch: the fixed effect's TRON route of ``optim/factory.py``) runs
+        each product on ``ops.pallas_glm.data_hvp_operator`` instead: the
+        forward and transpose products share ONE read of each X tile.
+        Every other caller (the vmapped per-entity TRON, sparse, wide or
+        sharded batches) keeps the two-pass form.
         """
-        if self._can_fuse(batch):
-            from photon_tpu.ops.pallas_glm import fused_data_hvp
-
+        if one_read:
             z = self.margins(w, batch)
             d2 = batch.weight * self.loss.dzz(z, batch.label)
+            data_hvp = data_hvp_operator(batch.features, d2)
             f = None if self.normalization is None else self.normalization.factors
 
-            def hv_fused(v: Array) -> Array:
-                ev = v if f is None else v * f
-                out = fused_data_hvp(ev, batch.features, d2)
+            def hv_one_read(v: Array) -> Array:
+                out = data_hvp(v if f is None else v * f)
                 if f is not None:
                     out = out * f
                 if self.l2_weight != 0.0:
                     out = out + self.l2_weight * self._l2_mask(v)
                 return out.astype(v.dtype)
 
-            return hv_fused
+            return hv_one_read
 
         mfun = lambda ww: self.margins(ww, batch)  # noqa: E731
         z, lin = jax.linearize(mfun, w)

@@ -26,21 +26,24 @@ shard_map path (photon_tpu.parallel.feature_sharded) applies.
 L2/normalization are folded by the wrapper (effective-coefficient algebra,
 photon_tpu.data.normalization), keeping the kernel a pure data-loss pass.
 
-Round-4 FE bandwidth verdict (bench ``--fe-bandwidth-ab``, BENCH_FULL.md):
-this file now holds exactly ONE lowering per entry point. The three
-round-4 candidates all survive as PARTS of it — tall rebalanced tiles
-(``_tile_geometry``), the fused one-pass HVP (``_hvp_kernel``), and the
-explicit sequential-grid declaration (``_SEQUENTIAL_GRID``, a correctness
-requirement on megacore parts, not a tunable) — while the losing
-alternatives were deleted rather than gated: the short-tile per-call
-``tile_n`` override is gone from both public signatures, and the
-linearize/transpose HVP in ops/objective.py remains only as the
-ineligibility fallback (sparse/wide/sharded), never a competing lowering
-for fuse-eligible batches.
+One lowering per entry point: tall rebalanced tiles (``_tile_geometry``)
+on a grid declared sequential (``_SEQUENTIAL_GRID``, a correctness
+requirement on megacore parts, not a tunable), with no per-call ``tile_n``
+override. The data-Hessian product (``data_hvp_operator``) is the one-read
+form of the fixed effect's TRON products wherever the batch qualifies
+(``GLMObjective.one_read_hvp``); the linearize/transpose product in
+ops/objective.py serves every other caller. On a TPU v5e, at 2^22 × 256
+float32, it takes 5.75 ms a product (5.70 inside TRON's CG loop), against
+11.42 ms for XLA's forward and transpose passes and 5.24 ms for one read
+of X at 819 GB/s, and reads 1.2e-7 from a float64 product. It multiplies
+and adds in float32 on the VPU: the same products on the MXU at default
+precision take as long but round X to bfloat16 (1.6e-3 from float64), and
+at HIGHEST they take 7.49 ms. Taller tiles, a raised VMEM limit or an
+unrolled slab loop move none of it.
 
 VMEM layout (PR 21, the first compile for a real v5e): every per-sample
-vector (label, offset, weight, d2, the margins output) crosses the kernel
-boundary as a LANE-DENSE row block ``(1, tile_n)``, and the margins are
+vector (label, offset, weight, the margins output; d2 in rows of 128)
+crosses the kernel boundary as a LANE-DENSE row block, and the margins are
 computed in that orientation (``w_row · X_tileᵀ``). A ``(tile_n, 1)``
 column block is laid out 128 lanes wide in VMEM — 512 bytes per sample per
 buffer — which at tall tiles cost ten times the X tile itself and made the
@@ -52,7 +55,7 @@ ahead-of-time compile in tests/test_tpu_aot_compile.py keeps that true.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -198,23 +201,80 @@ def _kernel(loss: PointwiseLoss, w_ref, x_ref, y_ref, off_ref, wt_ref,
     )
 
 
-def _hvp_kernel(v_ref, x_ref, d2_ref, out_ref):
-    """One-pass GLM data-Hessian product: per row tile,
-    u = v·X_tileᵀ (MXU), then out += (d2 ∘ u)·X_tile (MXU) — the tile is
-    read from HBM once for both dots. d2 = weight·loss''(z, y) is
-    precomputed by the caller at the current outer iterate."""
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+# The one-read product's inner loop reads X in slabs of _SUB rows (one
+# lane-dense row of d2) by at most _CHUNK features, all on the VPU in
+# float32: every product is an exact float32 multiply and every sum a
+# float32 add, as in XLA's own multiply-and-reduce fusions.
+_SUB = 128
+_CHUNK = 512
 
-    x = x_ref[...]
-    u = jax.lax.dot_general(
-        v_ref[...], x, _CONTRACT_FEATURES, preferred_element_type=jnp.float32
-    )
-    out_ref[...] += jax.lax.dot_general(
-        d2_ref[...] * u, x, _CONTRACT_SAMPLES,
-        preferred_element_type=jnp.float32,
-    )
+
+def _hvp_kernel(n_tiles, last_rows, v_ref, x_ref, d2_ref, out_ref, acc_ref):
+    """One-read GLM data-Hessian product, per row tile of X: for each slab
+    of _SUB rows, u = X_slab·v (multiply, then a lane reduction: one float
+    a row), t = d2 ∘ u, acc += X_slabᵀ·t (multiply, then sums over the
+    rows). The tile is read from HBM once for both products; the slab is
+    read from VMEM twice. ``acc`` keeps eight partial rows across the grid
+    and folds them into ``out`` at the last step.
+
+    ``d2_ref`` holds the tile's d2 as lane-dense rows of _SUB, one a slab;
+    their transpose puts slab s's d2 in column s, which a lane mask and a
+    lane reduction turn into the slab's column. The last tile of a grid
+    that does not divide n reads rows past the end of X, whose contents
+    are undefined: there ``last_rows`` masks them to zero before any
+    product, so no padded copy of X is ever needed."""
+    i = pl.program_id(0)
+    d = x_ref.shape[1]
+    chunks = [(c, min(_CHUNK, d - c)) for c in range(0, d, _CHUNK)]
+
+    @pl.when(i == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    d2t = d2_ref[...].T  # (_SUB, slabs): column s holds slab s's d2
+    lane = jax.lax.broadcasted_iota(jnp.int32, d2t.shape, 1)
+
+    def run(n_slabs: int, limit: Optional[int]):
+        def slab(s, carry):
+            r0 = pl.multiple_of(s * _SUB, _SUB)
+            if limit is not None:
+                row = jax.lax.broadcasted_iota(jnp.int32, (_SUB, 1), 0) + r0
+                keep = row < limit
+
+            def load(c, w):
+                x = x_ref[pl.ds(r0, _SUB), pl.ds(c, w)].astype(jnp.float32)
+                return x if limit is None else jnp.where(keep, x, 0.0)
+
+            u = sum(
+                jnp.sum(load(c, w) * v_ref[:, pl.ds(c, w)], axis=1,
+                        keepdims=True)
+                for c, w in chunks
+            )
+            d2s = jnp.sum(jnp.where(lane == s, d2t, 0.0), axis=1, keepdims=True)
+            t = u * d2s
+            for c, w in chunks:
+                acc_ref[:, pl.ds(c, w)] += jnp.sum(
+                    (t * load(c, w)).reshape(_SUB // 8, 8, w), axis=0
+                )
+            return carry
+
+        jax.lax.fori_loop(0, n_slabs, slab, 0)
+
+    slabs = x_ref.shape[0] // _SUB
+    if last_rows == x_ref.shape[0]:
+        run(slabs, None)
+    else:
+        @pl.when(i < n_tiles - 1)
+        def _full():
+            run(slabs, None)
+
+        @pl.when(i == n_tiles - 1)
+        def _last():
+            run(-(-last_rows // _SUB), last_rows)
+
+    @pl.when(i == n_tiles - 1)
+    def _fold():
+        out_ref[...] = jnp.sum(acc_ref[...], axis=0, keepdims=True)
 
 
 def _row_blocks(n_tiles: int, tile_n: int):
@@ -227,55 +287,74 @@ def _row_blocks(n_tiles: int, tile_n: int):
     return as_rows, pl.BlockSpec((None, 1, tile_n), lambda i: (i, 0, 0))
 
 
+def data_hvp_operator(
+    X: Array, d2: Array, interpret: Optional[bool] = None,
+) -> Callable[[Array], Array]:
+    """``v -> Xᵀ·diag(d2)·X·v`` in ONE read of ``X`` a product, where XLA
+    pays two (the forward and the transpose matrix-vector products): the
+    data term of a GLM Hessian-vector product at fixed margins, for
+    TRON's conjugate-gradient steps (``GLMObjective.linearized_hvp(...,
+    one_read=True)``). ``d2`` is laid out once here, so the operator's
+    products share it.
+
+    Arithmetic is float32 on the VPU (``_hvp_kernel``): a bfloat16 X is
+    widened to float32 before any product, and no MXU pass rounds an
+    operand. X is never padded or copied: the last tile of a grid that
+    does not divide n masks the rows past the end; only d2 (n floats) is
+    padded to whole tiles. Tile geometry follows ``DEFAULT_TILE_N`` (read
+    at call time; tests vary it by monkeypatching), in heights of whole
+    _SUB-row slabs.
+    """
+    n, d = X.shape
+    _check_fused_width(d, "data_hvp_operator")
+    if interpret is None:
+        interpret = not pallas_available()
+    d_pad = _round_up(max(d, 1), _LANE)
+    fixed = _VMEM_FIXED + 8 * d_pad * 4 + 4 * _SUB * min(d_pad, _CHUNK) * 4
+    tile_n, n_pad = _tile_geometry(
+        n, DEFAULT_TILE_N, x_row_bytes(d_pad, X.dtype) + ROW_VEC_BYTES,
+        fixed_bytes=fixed,
+    )
+    n_tiles = n_pad // tile_n
+    slabs = tile_n // _SUB
+    rows = _round_up(slabs, 8)
+    # d2 as lane-dense rows of _SUB, ``rows`` of them a tile (zero past n).
+    d2 = jnp.pad(d2.astype(jnp.float32), (0, n_pad - n))
+    d2 = d2.reshape(n_tiles, slabs, _SUB)
+    if rows != slabs:
+        d2 = jnp.pad(d2, ((0, 0), (0, rows - slabs), (0, 0)))
+    d2 = d2.reshape(n_tiles * rows, _SUB)
+    resident = pl.BlockSpec((1, d), lambda i: (0, 0))
+    call = pl.pallas_call(
+        functools.partial(_hvp_kernel, n_tiles, n - (n_tiles - 1) * tile_n),
+        grid=(n_tiles,),
+        in_specs=[
+            resident,                                     # v
+            pl.BlockSpec((tile_n, d), lambda i: (i, 0)),  # X row tile
+            pl.BlockSpec((rows, _SUB), lambda i: (i, 0)),  # d2 rows
+        ],
+        out_specs=resident,
+        out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((8, d), jnp.float32)],
+        compiler_params=None if interpret else _SEQUENTIAL_GRID,
+        interpret=interpret,
+    )
+
+    def product(v: Array) -> Array:
+        return call(v.astype(jnp.float32)[None, :], X, d2)[0]
+
+    return product
+
+
 def fused_data_hvp(
     v: Array,
     X: Array,
     d2: Array,
     interpret: Optional[bool] = None,
 ) -> Array:
-    """Xᵀ·diag(d2)·X·v in ONE pass over ``X`` (vs two XLA passes for the
-    forward and transpose matvecs). The data term of a GLM Hessian-vector
-    product at fixed margins; pairs with GLMObjective.linearized_hvp,
-    which caches d2 once per outer iteration
-    (HessianVectorAggregator.scala role). Padding is exact (zero rows /
-    columns contribute nothing).
-
-    Tile geometry is fixed by ``DEFAULT_TILE_N`` (module constant, read at
-    call time) — the round-4 FE bandwidth A/B kept the fused one-pass HVP
-    as the only HVP lowering and retired the per-call tile-height override
-    with the losing short-tile variants (BENCH_FULL.md, bench
-    ``--fe-bandwidth-ab``). Tests vary geometry by monkeypatching
-    ``pallas_glm.DEFAULT_TILE_N``.
-    """
-    n, d = X.shape
-    _check_fused_width(d, "fused_data_hvp")
-    if interpret is None:
-        interpret = not pallas_available()
-    d_pad = _round_up(max(d, 1), _LANE)
-    tile_n, n_pad = _tile_geometry(
-        n, DEFAULT_TILE_N, x_row_bytes(d_pad, X.dtype) + ROW_VEC_BYTES
-    )
-    if n_pad != n or d_pad != d:
-        X = jnp.pad(X, ((0, n_pad - n), (0, d_pad - d)))
-        d2 = jnp.pad(d2, (0, n_pad - n))
-        v = jnp.pad(v, (0, d_pad - d))
-    n_tiles = n_pad // tile_n
-    as_rows, row_spec = _row_blocks(n_tiles, tile_n)
-    resident = pl.BlockSpec((1, d_pad), lambda i: (0, 0))
-    out = pl.pallas_call(
-        _hvp_kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            resident,                                         # v
-            pl.BlockSpec((tile_n, d_pad), lambda i: (i, 0)),  # X row tile
-            row_spec,                                         # d2
-        ],
-        out_specs=resident,
-        out_shape=jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
-        compiler_params=None if interpret else _SEQUENTIAL_GRID,
-        interpret=interpret,
-    )(v.astype(X.dtype)[None, :], X, as_rows(d2))
-    return out[0, :d]
+    """Xᵀ·diag(d2)·X·v in ONE read of ``X``: ``data_hvp_operator``'s
+    product, for a single vector."""
+    return data_hvp_operator(X, d2, interpret)(v)
 
 
 def fused_data_value_and_grad(

@@ -110,6 +110,10 @@ class OptimizeResult:
     # refused (``optim/tron.py``). None where the solver has neither.
     cg_steps: Optional[Array] = None
     rejected_steps: Optional[Array] = None
+    # Reads of X one of a TRON solve's Hessian-vector products took: 1 on
+    # the one-read kernel, 2 for a forward and a transpose pass; 0 where
+    # the solver makes no products.
+    hvp_passes: int = dataclasses.field(default=0, metadata=dict(static=True))
     # Which solver the factory routed to ("owlqn_margin", "lbfgs_margin", ...) and
     # which coordinate it solved: the labels this result is published under
     # when it is read. Empty where no factory or coordinate made it.
@@ -192,7 +196,8 @@ class OptimizeResult:
 def _publish(host: OptimizeResult) -> None:
     """One solve's work into the registry, from the host copy the tracker's
     readers already made: iterations, evaluations, (OWL-QN) line-search
-    trials and (TRON) CG and rejected steps as counters, the count of
+    trials and (TRON) CG and rejected steps and Hessian-vector products,
+    all and those on the one-read kernel, as counters, the count of
     non-zero coefficients as a gauge (the last solve's)."""
     from photon_tpu.obs.metrics import registry
 
@@ -217,6 +222,15 @@ def _publish(host: OptimizeResult) -> None:
         registry().counter(
             "fe_tron_rejected_steps_total", coordinate=host.coordinate
         ).inc(int(host.rejected_steps))
+        # A product a CG step and one for each outer iteration's ρ, all on
+        # one lowering.
+        products = int(host.cg_steps) + int(host.iterations)
+        registry().counter(
+            "fe_tron_products_total", coordinate=host.coordinate
+        ).inc(products)
+        registry().counter(
+            "fe_tron_one_read_products_total", coordinate=host.coordinate
+        ).inc(products if host.hvp_passes == 1 else 0)
     if int(host.nonzeros) >= 0:
         registry().gauge(
             "fe_nonzero_coefficients", coordinate=host.coordinate

@@ -86,7 +86,8 @@ def carries_margins(objective: GLMObjective, spec: OptimizerSpec) -> bool:
 
 
 def make_optimizer(
-    objective: GLMObjective, spec: OptimizerSpec, with_score: bool = False
+    objective: GLMObjective, spec: OptimizerSpec, with_score: bool = False,
+    one_read_hvp: bool = False,
 ) -> Callable[..., Union[OptimizeResult, Tuple[OptimizeResult, Array]]]:
     """Return solve(w0, batch) -> OptimizeResult for the given objective.
 
@@ -98,6 +99,11 @@ def make_optimizer(
     score), ``score`` being x·w at ``result.w`` (``GLMObjective.scores``): a
     solver that carries margins takes ``start_score`` (x·w0) in and hands its
     own back; any other ignores it and pays ONE pass over X at the end.
+
+    ``one_read_hvp`` (``GLMObjective.one_read_hvp`` asked of the concrete
+    batch, which the solve cache does before it traces) puts the ``tron``
+    route's conjugate-gradient products on the one-read kernel; without it
+    each reads X twice.
     """
     config = spec.config()
     routed = routed_solver(objective, spec)
@@ -119,10 +125,12 @@ def make_optimizer(
             elif name == "tron":
                 # Factory form: margins/curvature built once per outer
                 # iteration (1 X pass), shared across that iteration's CG
-                # products (2 X passes each).
+                # products (1 or 2 X passes each, by the lowering).
                 res = minimize_tron(
                     vg, None, w0, config, spec.max_cg_iter, spec.box,
-                    hvp_factory=lambda w: objective.linearized_hvp(w, batch),
+                    hvp_factory=lambda w: objective.linearized_hvp(
+                        w, batch, one_read=one_read_hvp),
+                    hvp_passes=1 if one_read_hvp else 2,
                 )
             elif name == "lbfgsb":
                 assert spec.box is not None, "LBFGSB requires a box"

@@ -6,8 +6,8 @@ constants (TRON.scala:93-94), inner truncated CG solving the TR subproblem
 with Hessian-vector products (truncatedConjugateGradientMethod:272-329);
 defaults maxIter=15, tol=1e-5, ≤20 CG iterations (TRON.scala:251-256).
 
-TPU-first design: the Hessian-vector product is a forward-over-reverse JVP of
-the (sharded) objective — one fused XLA pass per CG step, no Hessian ever
+TPU-first design: the Hessian-vector product comes from a factory built once
+an outer iteration (``GLMObjective.linearized_hvp``), no Hessian ever
 materialized. The whole outer/inner loop nest is ``lax.while_loop``s inside a
 single jitted program, so the ≤20 H·v products per outer iteration that cost
 the reference ≤20 treeAggregate rounds (TRON.scala:287-326) cost zero host
@@ -19,17 +19,20 @@ values). Unlike LIBLINEAR's exact radius schedule, the middle band
 (eta1 <= rho < eta2) keeps the radius unchanged — the textbook TR update —
 which avoids the geometric shrink that stalls runs whose rho hovers there.
 
-Work is counted in passes over X (``eval_unit="x_passes"``), one a
-matrix-vector product of the factory form over a dense X: 2 for the
+Work is counted in passes over X (``eval_unit="x_passes"``): 2 for the
 starting value and gradient; per outer iteration 1 for the margins the H·v
-factory linearizes at, 2 per CG product (a forward and a transpose pass), 2
-for the trial's value and gradient and 2 for ρ's product. The trial's and
-ρ's products are independent of each other, so a compiler may serve both
-forward passes with one read of X and both transposes with another (XLA
-does on a TPU v5e). ``cg_steps`` and ``rejected_steps`` come out of the
-loop beside it. Inside the program the CG loop runs under the scope ``cg``
-and the trial's evaluation (its value, gradient and ρ's product) under
-``trial``.
+factory linearizes at, ``hvp_passes`` a product (each CG step's and ρ's:
+2, a forward and a transpose pass, on the two-pass form; 1 on the one-read
+kernel the fixed effect's route takes where the batch qualifies), and 2 for
+the trial's value and gradient. A compiler may serve independent passes
+with one read of X, and drop a pass nothing reads: compiled for a TPU
+v5e, the squared loss's margins feed no curvature and go, and the
+transposes of the trial's gradient and of ρ's two-pass product share one
+read, so outside CG the two-pass form's 5 passes an iteration cost 3
+reads, and so do the one-read form's 4. ``cg_steps`` and
+``rejected_steps`` come out of the loop beside it. Inside the program the
+CG loop runs under the scope ``cg`` and the trial's evaluation (its value,
+gradient and ρ's product) under ``trial``.
 """
 
 from __future__ import annotations
@@ -55,8 +58,8 @@ Hvp = Callable[[Array, Array], Array]
 ETA0, ETA1, ETA2 = 1e-4, 0.25, 0.75
 SIGMA1, SIGMA3 = 0.25, 4.0
 
-# Passes over X: a value and gradient, a Hessian-vector product, and the
-# margins an H·v factory linearizes at.
+# Passes over X: a value and gradient, a Hessian-vector product of the
+# two-pass form, and the margins an H·v factory linearizes at.
 VG_PASSES, HVP_PASSES, BUILD_PASSES = 2, 2, 1
 
 TRON_DEFAULT_CONFIG = OptimizerConfig(max_iter=15, tol=1e-5)
@@ -119,6 +122,7 @@ def minimize_tron(
     max_cg_iter: int = 20,
     box: Optional[Tuple[Array, Array]] = None,
     hvp_factory: Optional[Callable[[Array], Callable[[Array], Array]]] = None,
+    hvp_passes: int = HVP_PASSES,
 ) -> OptimizeResult:
     """Trust-region Newton minimization.
 
@@ -131,11 +135,13 @@ def minimize_tron(
         per outer iteration, so w-dependent state (margins, curvature
         multipliers) is shared across all ≤max_cg_iter CG products of that
         iteration instead of recomputed inside each one
-        (GLMObjective.linearized_hvp halves the X traffic this way).
+        (GLMObjective.linearized_hvp).
 
-    ``result.evals`` counts the factory form's passes over a dense X (the
-    module's docstring); a jvp-of-grad ``hvp`` and the fused kernels pay
-    other numbers of passes for the same work, which ``iterations`` and
+    ``hvp_passes`` is what one product of ``hvp_factory``'s form reads of
+    X: 2 for ``GLMObjective.linearized_hvp``'s two-pass form, 1 for its
+    one-read kernel. ``result.evals`` counts the passes over a dense X
+    with it (the module's docstring); a jvp-of-grad ``hvp`` pays another
+    number of passes for the same work, which ``iterations`` and
     ``cg_steps`` state whatever the form.
     """
     if hvp_factory is None:
@@ -215,9 +221,10 @@ def minimize_tron(
             ),
         )
         # Passes over X: the margins the factory linearized at, one H·v a
-        # CG step, the trial's value and gradient, and ρ's H·v
-        # (TRON.scala:287-326: each product was a treeAggregate round).
-        passes = BUILD_PASSES + HVP_PASSES * (cg_iters + 1) + VG_PASSES
+        # CG step and ρ's, each ``hvp_passes``, and the trial's value and
+        # gradient (TRON.scala:287-326: each product was a treeAggregate
+        # round).
+        passes = BUILD_PASSES + hvp_passes * (cg_iters + 1) + VG_PASSES
         return dict(
             w=w_new, f=f_new, g=g_new, delta=delta_new, it=it, reason=reason,
             evals=st["evals"] + passes,
@@ -240,4 +247,5 @@ def minimize_tron(
         loss_history=loss_hist, grad_norm_history=gnorm_hist,
         evals=st["evals"], eval_unit="x_passes",
         cg_steps=st["cg_steps"], rejected_steps=st["rejected"],
+        hvp_passes=hvp_passes,
     )

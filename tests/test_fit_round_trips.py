@@ -540,6 +540,7 @@ def test_second_fit_reads_nothing_back_from_the_device(
     labels = dict(coordinate="global", optimizer=optimizer)
     assert registry().find("fe_solver_iterations_total", **labels) is None
     assert registry().find("fe_tron_cg_steps_total", coordinate="global") is None
+    assert registry().find("fe_tron_products_total", coordinate="global") is None
     with device_gets(monkeypatch) as gets:
         diags = [d.diagnostics_dict() for d in second.tracker["global"]]
         [d.summary() for d in second.tracker["global"]]  # the copy is kept
@@ -549,11 +550,19 @@ def test_second_fit_reads_nothing_back_from_the_device(
     )
     cg = registry().find("fe_tron_cg_steps_total", coordinate="global")
     rejected = registry().find("fe_tron_rejected_steps_total", coordinate="global")
+    products = registry().find("fe_tron_products_total", coordinate="global")
+    one_read = registry().find("fe_tron_one_read_products_total",
+                               coordinate="global")
     if optimizer == "tron":
         assert cg.value == sum(d["cg_steps"] for d in diags) > 0
         assert rejected.value == sum(d["rejected_steps"] for d in diags)
+        # A product a CG step and ρ's an outer iteration; off a TPU every
+        # one takes the two-pass form.
+        assert products.value == cg.value + sum(d["iterations"] for d in diags)
+        assert one_read.value == 0
     else:
         assert cg is None and rejected is None
+        assert products is None and one_read is None
     for a, b in zip(jax.tree_util.tree_leaves(first.model),
                     jax.tree_util.tree_leaves(second.model), strict=True):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -110,4 +110,8 @@ def test_cg_and_rejected_steps_are_published_once_a_tracker_is_read(fitted):
     assert registry().find("fe_solver_evals_total", unit="x_passes",
                            coordinate="global", optimizer="tron").value == sum(
         d["evals"] for d in diags)
+    products = registry().find("fe_tron_products_total", coordinate="global")
+    assert products.value == cg.value + sum(d["iterations"] for d in diags)
+    assert registry().find("fe_tron_one_read_products_total",
+                           coordinate="global").value == 0  # off a TPU
     assert np.all([d["rejected_steps"] <= d["iterations"] for d in diags])

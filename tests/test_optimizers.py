@@ -128,10 +128,11 @@ def test_tron_poisson():
     assert res.eval_unit == "x_passes" and int(res.cg_steps) >= int(res.iterations)
 
 
-def _counting_tron(problem):
+def _counting_tron(problem, one_read=False):
     """A TRON solve whose value-and-gradient, H·v factory and products each
     add the passes over X they make to a host count as they run, through
-    the factory form ``GLMObjective.linearized_hvp``."""
+    the factory form ``GLMObjective.linearized_hvp`` (its one-read kernel
+    with ``one_read``: one pass a product)."""
     n, d = 256, 8
     local = np.random.default_rng(7)
     if problem == "poisson_steep":
@@ -156,29 +157,34 @@ def _counting_tron(problem):
 
     def factory(w):
         count(1)
-        hv = obj.linearized_hvp(w, batch)
+        hv = obj.linearized_hvp(w, batch, one_read=one_read)
 
         def product(v):
-            count(2)
+            count(1 if one_read else 2)
             return hv(v)
 
         return product
 
-    res = jax.jit(lambda w0: minimize_tron(vg, None, w0, hvp_factory=factory))(
+    res = jax.jit(lambda w0: minimize_tron(
+        vg, None, w0, hvp_factory=factory, hvp_passes=1 if one_read else 2))(
         jnp.zeros(d, jnp.float32))
     jax.effects_barrier()
     return res, sum(passes)
 
 
+@pytest.mark.parametrize("one_read", [False, True], ids=["two_pass", "one_read"])
 @pytest.mark.parametrize("problem", ["squared", "poisson_steep"])
-def test_tron_passes_are_its_iterations_and_cg_steps(problem):
+def test_tron_passes_are_its_iterations_and_cg_steps(problem, one_read):
     """The reported passes are the passes the solve made: 2 for the start,
-    then per outer iteration the linearized margins, 2 a CG product, 2 for
-    the trial and 2 for ρ's product."""
-    res, made = _counting_tron(problem)
+    then per outer iteration the linearized margins, 2 for the trial, and
+    a CG product and ρ's product at 2 passes each on the two-pass form, 1
+    on the one-read kernel."""
+    res, made = _counting_tron(problem, one_read)
     its, cg = int(res.iterations), int(res.cg_steps)
     assert res.eval_unit == "x_passes" and its >= 1 and cg >= its
-    assert int(res.evals) == made == 2 + 5 * its + 2 * cg
+    per_product = 1 if one_read else 2
+    assert int(res.evals) == made == 2 + 3 * its + per_product * (cg + its)
+    assert res.hvp_passes == per_product
     rejected = int(res.rejected_steps)
     assert 0 <= rejected < its
     if problem == "poisson_steep":

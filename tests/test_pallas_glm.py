@@ -268,8 +268,8 @@ def test_interpret_mode_is_cpu_only_and_explicit():
 
 
 def test_linearized_hvp_fused_route_matches_fallback():
-    """use_pallas objective's linearized_hvp (fused kernel) == the
-    linearize/transpose fallback, with L2, intercept, and factor
+    """linearized_hvp's one-read route (the fused kernel) == the
+    linearize/transpose two-pass form, with L2, intercept, and factor
     normalization folded."""
     from photon_tpu.data.normalization import NormalizationContext
 
@@ -294,7 +294,8 @@ def test_linearized_hvp_fused_route_matches_fallback():
         obj_f = GLMObjective(use_pallas=True, **kw)
         obj_r = GLMObjective(**kw)
         assert obj_f._can_fuse(batch)
-        got = obj_f.linearized_hvp(jnp.asarray(w), batch)(jnp.asarray(v))
+        got = obj_f.linearized_hvp(jnp.asarray(w), batch, one_read=True)(
+            jnp.asarray(v))
         ref = obj_r.linearized_hvp(jnp.asarray(w), batch)(jnp.asarray(v))
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-4, atol=1e-4)
         # And against the jvp-of-grad operator for good measure.

@@ -94,6 +94,18 @@ def test_fixed_effect_kernels_compile_for_v5e(v5e, n, d, dtype):
     )
 
 
+def test_one_read_hvp_compiles_without_a_copy_of_x(v5e):
+    """Where no tile height divides n, the one-read product masks its last
+    tile: the compiled program holds no temporary the size of X."""
+    n, d = N * 2 + 37, D_FIX
+    f32 = jnp.float32
+    compiled = _compile(
+        lambda v, X, d2: fused_data_hvp(v, X, d2, interpret=False),
+        v5e, ((d,), f32), ((n, d), f32), ((n,), f32),
+    )
+    assert compiled.memory_analysis().temp_size_in_bytes < n * d * 4 // 100
+
+
 @pytest.mark.parametrize(
     "lanes,n_max",
     [
